@@ -25,7 +25,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 import jax
 import jax.numpy as jnp
-from horovod_tpu.common.compat import shard_map
+from jax import shard_map
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 

@@ -155,6 +155,13 @@ def _walk(jaxpr, env: Dict[int, FrozenSet[str]], live: Set[int],
         axes = _axes_of(eqn.params)
         out_red = (in_red | frozenset(axes)
                    if name in REDUCE_PRIMS else in_red)
+        if name == "pvary" and all(
+                getattr(v.aval, "shape", None) == ()
+                for v in eqn.invars):
+            # A scalar lifted back to varying is a vote/count being
+            # prepared for its own fold (train.py _unanimity), not a
+            # gradient: re-reducing it is the point.
+            out_red = in_red - frozenset(axes)
         subs = _sub_jaxprs(eqn)
         if subs:
             for sub, off in subs:
@@ -252,14 +259,12 @@ def _chain_internal(ops: Sequence[CollectiveOp]) -> Set[int]:
 # ---------------------------------------------------------------------------
 
 def check_axes(ops: Sequence[CollectiveOp],
-               mesh_shape: Dict[str, int],
-               allow_scalar_size1: bool = False) -> List[str]:
+               mesh_shape: Dict[str, int]) -> List[str]:
     """(a) every collective's axis names exist in the ambient mesh,
     and no reduce runs over a size-1 axis (identity wire — the r08
-    wire-gate regression class). `allow_scalar_size1` exempts scalar
-    reduces on the VMA leg, where the psum is what flips a flag's
-    varying-type and a size-1 axis' psum is type-required (and
-    wire-free)."""
+    wire-gate regression class). Scalar reduces are exempt: there the
+    psum is what flips a flag's varying-type, so a size-1 axis' psum
+    is type-required (and wire-free)."""
     msgs = []
     for op in ops:
         unknown = [a for a in op.axes if a not in mesh_shape]
@@ -271,7 +276,7 @@ def check_axes(ops: Sequence[CollectiveOp],
         if op.prim in REDUCE_PRIMS:
             size1 = [a for a in op.axes
                      if mesh_shape.get(a, 0) == 1]
-            if size1 and not (allow_scalar_size1 and op.scalar):
+            if size1 and not op.scalar:
                 msgs.append(
                     f"'{op.prim}' reduces over size-1 mesh axis "
                     f"{size1[0]!r}: identity wire (the r08 wire-gate "
@@ -443,36 +448,6 @@ def check_plan(ops: Sequence[CollectiveOp], plan,
                 f"{op.axes} on {op.dtype}{list(op.shape)} matches no "
                 f"bucket wire group of the agreed plan (digest "
                 f"{plan.digest!r})")
-    return msgs
-
-
-def check_monolithic(ops: Sequence[CollectiveOp],
-                     leaf_expect: Sequence[Tuple[Tuple[int, ...],
-                                                 str,
-                                                 FrozenSet[str]]]
-                     ) -> List[str]:
-    """(b, overlap off / legacy leg) every inexact leaf with live
-    reduce axes gets exactly one explicit per-leaf psum
-    (_sum_missing_axes), and no other non-scalar gradient reduce
-    exists."""
-    msgs: List[str] = []
-    internal = _chain_internal(ops)
-    used: Set[int] = set()
-    for shape, dtype, raxes in leaf_expect:
-        got = _match_wire(ops, shape, dtype, raxes, used, internal)
-        if got is None:
-            msgs.append(
-                f"monolithic leg: leaf {dtype}{list(shape)} expected "
-                f"a psum over {sorted(raxes)} but none was traced — "
-                f"a rank would consume an unreduced (local) gradient")
-    for op in ops:
-        if (op.prim in REDUCE_PRIMS and not op.scalar
-                and op.pos not in used and op.pos not in internal
-                and not op.dead):
-            msgs.append(
-                f"monolithic leg: unexpected non-scalar reduce "
-                f"'{op.prim}' over {op.axes} on "
-                f"{op.dtype}{list(op.shape)}")
     return msgs
 
 

@@ -65,12 +65,11 @@ def _ensure_distributed(cfg: Config) -> bool:
             initialization_timeout=int(max(cfg.start_timeout, 1)),
             shutdown_timeout_seconds=shutdown_timeout,
         )
-        # Older jax lacks the shutdown-barrier knob; dropping it only
-        # loses the tuned barrier timeout, not correctness.
-        import inspect
-        if "shutdown_timeout_seconds" not in inspect.signature(
-                jax.distributed.initialize).parameters:
-            kwargs.pop("shutdown_timeout_seconds")
+        # An earlier single-process incarnation (an elastic world that
+        # shrank to one rank) leaves a live backend behind, and JAX
+        # refuses to join a coordination service once one exists.
+        import jax.extend.backend as _xb
+        _xb.clear_backends()
         try:
             jax.distributed.initialize(**kwargs)
         except Exception:
@@ -119,6 +118,11 @@ def init(config_overrides: Optional[Dict[str, Any]] = None,
         _adasum.set_adasum_mode(cfg.adasum_mode)
         _state._owns_distributed = _ensure_distributed(cfg)
         _state.topology = detect(cfg)
+        if _state._owns_distributed:
+            from .topology import exchange_process_indices
+            exchange_process_indices(
+                _state.topology.rank, _state.topology.size,
+                max(cfg.start_timeout, 1))
         hlog.set_rank(_state.topology.rank)
         # Launch profile AFTER topology detection: the alltoall auto
         # heuristic's inputs must be IDENTICAL on every rank
@@ -304,6 +308,8 @@ def shutdown() -> None:
         _state.initialized = False
         _state.process_set_table = None
         _state.topology = None
+        from .topology import reset_process_indices
+        reset_process_indices()
         from ..ops import dispatch as _dispatch
         _dispatch.set_hierarchical(0)
         _dispatch.set_alltoall_mode("auto")

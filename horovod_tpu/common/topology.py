@@ -67,22 +67,59 @@ def detect(cfg) -> Topology:
                     cross_size=cross_size, hostname=hostname)
 
 
-def process_device(process_index: int) -> jax.Device:
-    """The representative device of a process, used for the eager
-    process-level mesh (one device per rank)."""
-    devs = [d for d in jax.devices() if d.process_index == process_index]
-    if not devs:
-        raise RuntimeError(f"no devices for process {process_index}")
-    return min(devs, key=lambda d: d.id)
+# hvd rank -> jax process index. The CPU backend numbers processes by
+# the process_id handed to jax.distributed.initialize, so there the
+# map is the identity (None). The TPU runtime numbers them by the
+# coordinates of the chips each process was given, whatever task id
+# the launcher asked for (first seen under `--per-chip` on a v5e 2x2:
+# ranks 0..3 came up as processes 0, 2, 3, 1).
+_process_of_rank: Optional[List[int]] = None
 
 
-def process_local_devices(process_index: int) -> List[jax.Device]:
-    """ALL devices owned by a process, in id order. Row material for
-    the device-spanning eager mesh (see ProcessSet.device_mesh)."""
-    devs = [d for d in jax.devices() if d.process_index == process_index]
+def exchange_process_indices(rank: int, size: int,
+                             timeout_s: float) -> None:
+    """Publish this rank's jax.process_index() through the
+    coordination service's key-value store and read every rank's.
+    Called by init() right after jax.distributed.initialize()."""
+    global _process_of_rank
+    from jax._src import distributed
+    client = distributed.global_state.client
+    if client is None or size <= 1:
+        _process_of_rank = None
+        return
+    client.key_value_set(f"hvd/process_index/{rank}",
+                         str(jax.process_index()), allow_overwrite=True)
+    _process_of_rank = [
+        int(client.blocking_key_value_get(f"hvd/process_index/{r}",
+                                          int(timeout_s * 1000)))
+        for r in range(size)]
+
+
+def reset_process_indices() -> None:
+    global _process_of_rank
+    _process_of_rank = None
+
+
+def _devices_of_rank(rank: int) -> List[jax.Device]:
+    pidx = rank if _process_of_rank is None else _process_of_rank[rank]
+    devs = [d for d in jax.devices() if d.process_index == pidx]
     if not devs:
-        raise RuntimeError(f"no devices for process {process_index}")
+        raise RuntimeError(f"no devices for rank {rank} "
+                           f"(jax process {pidx})")
     return sorted(devs, key=lambda d: d.id)
+
+
+def process_device(rank: int) -> jax.Device:
+    """The representative device of a rank's process, used for the
+    eager process-level mesh (one device per rank)."""
+    return _devices_of_rank(rank)[0]
+
+
+def process_local_devices(rank: int) -> List[jax.Device]:
+    """ALL devices owned by a rank's process, in id order. Row
+    material for the device-spanning eager mesh (see
+    ProcessSet.device_mesh)."""
+    return _devices_of_rank(rank)
 
 
 def device_matrix(ranks: List[int]):

@@ -57,6 +57,11 @@ def _int_or_none(v: Any) -> Optional[int]:
         return None
 
 
+# Snapshot key: the world size whose per-rank optimizer state (PowerSGD
+# residuals) the snapshot stacks on a leading axis.
+_PER_RANK_WORLD = "_per_rank_world"
+
+
 def _to_host(tree: Any) -> Any:
     return jax.tree_util.tree_map(
         lambda x: np.asarray(x) if isinstance(x, (jax.Array, np.ndarray))
@@ -314,18 +319,26 @@ class JaxState(ObjectState):
 
     def _write_snapshot(self) -> None:
         import horovod_tpu as hvd
+        known, trees = dict(self._saved), dict(self._tree_saved)
+        if hvd.is_initialized() and hvd.size() > 1:
+            # Only rank 0 writes, so per-rank state must reach it
+            # first (a collective: every rank commits).
+            from ..optim.distributed_optimizer import (
+                gather_per_rank_state)
+            trees = {k: _to_host(gather_per_rank_state(v))
+                     for k, v in trees.items()}
+            known[_PER_RANK_WORLD] = hvd.size()
         if hvd.is_initialized() and hvd.rank() != 0:
             return
         if self._snapshot_backend == "orbax":
-            self._orbax_save()
+            self._orbax_save(known, trees)
             self._last_save_durable = True
             return
         import os
         import pickle
         tmp = self._snapshot_path + ".tmp"
         with open(tmp, "wb") as f:
-            pickle.dump({"known": dict(self._saved),
-                         "trees": dict(self._tree_saved)}, f)
+            pickle.dump({"known": known, "trees": trees}, f)
         os.replace(tmp, self._snapshot_path)
         self._last_save_durable = True
 
@@ -387,17 +400,17 @@ class JaxState(ObjectState):
                         barrier_sync_key_prefix=f"hvdsnap{me}")))
         return self._ckpt_mgr
 
-    def _orbax_payload(self) -> Dict[str, Any]:
+    @staticmethod
+    def _orbax_payload(known, trees) -> Dict[str, Any]:
         # Non-array python attrs ride as a pickled uint8 array so one
         # StandardSave handles the whole snapshot.
         import pickle
-        known = np.frombuffer(pickle.dumps(dict(self._saved)),
+        known = np.frombuffer(pickle.dumps(known),
                               dtype=np.uint8).copy()
-        trees = {k: v for k, v in self._tree_saved.items()
-                 if v is not None}
+        trees = {k: v for k, v in trees.items() if v is not None}
         return {"known": known, "trees": trees}
 
-    def _orbax_save(self) -> None:
+    def _orbax_save(self, known, trees) -> None:
         import orbax.checkpoint as ocp
         mgr = self._orbax()
         step = (mgr.latest_step() or 0) + 1
@@ -405,7 +418,7 @@ class JaxState(ObjectState):
         # file IO runs off-thread (the round-1 verdict's missing
         # "async/off-thread write").
         mgr.save(step, args=ocp.args.StandardSave(
-            self._orbax_payload()))
+            self._orbax_payload(known, trees)))
 
     def maybe_load_snapshot(self) -> bool:
         if not self._snapshot_path:
@@ -438,6 +451,15 @@ class JaxState(ObjectState):
 
     def _apply_snapshot(self, known: Dict[str, Any],
                         trees: Dict[str, Any]) -> None:
+        saved_world = known.pop(_PER_RANK_WORLD, None)
+        if saved_world is not None:
+            import horovod_tpu as hvd
+            from ..optim.distributed_optimizer import (
+                select_per_rank_state)
+            rank, size = ((hvd.rank(), hvd.size())
+                          if hvd.is_initialized() else (0, 1))
+            trees = {k: select_per_rank_state(v, saved_world, rank, size)
+                     for k, v in trees.items()}
         for k, v in known.items():
             setattr(self, k, v)
         for k, v in trees.items():

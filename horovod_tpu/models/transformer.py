@@ -31,7 +31,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from ..common.compat import axis_size as _compat_axis_size
 from jax import lax
 
 from ..parallel.mesh import EXPERT_AXIS, SEQ_AXIS, TENSOR_AXIS
@@ -86,10 +85,10 @@ def _axis_size(name: Optional[str]) -> int:
     if name is None:
         return 1
     try:
-        # hvdlint: disable-next=HVD005 (version compat, not rank
-        # divergence: NameError depends on the jax build, which is
-        # identical on every rank tracing the same program)
-        return _compat_axis_size(name)
+        # hvdlint: disable-next=HVD005 (not rank divergence:
+        # NameError means the axis is unbound — outside shard_map —
+        # which is identical on every rank tracing the same program)
+        return lax.axis_size(name)
     except NameError:
         return 1
 
@@ -428,9 +427,14 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any],
         x, a = one_layer(layer_p, x)
         return (x, aux + a), None
 
-    # aux init derived from x so its shard_map varying-axes type matches
-    # the per-layer aux (which is computed from activations).
-    aux0 = jnp.sum(x * 0).astype(jnp.float32)
+    # The scan carry must have the per-layer aux's shard_map
+    # varying-axes type on every live mesh axis (the MoE aux also
+    # varies over `tensor`, which x alone does not).
+    aux_t = jax.eval_shape(
+        one_layer, jax.tree.map(lambda p: p[0], params["layers"]), x)[1]
+    aux0 = jnp.zeros((), jnp.float32)
+    if aux_t.vma:
+        aux0 = lax.pcast(aux0, tuple(aux_t.vma), to="varying")
     (x, aux), _ = lax.scan(body, (x, aux0), params["layers"])
     x = rmsnorm(x, params["final_norm"])
     return x, aux

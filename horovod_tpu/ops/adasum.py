@@ -51,7 +51,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..common import config as _config
-from ..common.compat import shard_map
+from jax import shard_map
 from .process_set import ProcessSet
 from . import dispatch
 

@@ -402,6 +402,11 @@ class NegotiatedController:
                 "auto), which requires the C++ core "
                 "(horovod_tpu/core/cc, built automatically when a "
                 "toolchain is present)")
+        if cfg.controller == "native" and not native.available():
+            raise RuntimeError(
+                "HOROVOD_CONTROLLER=native but the C++ core "
+                "(horovod_tpu/core/cc) could not be built or loaded; "
+                "run `make -C horovod_tpu/core/cc` to see why")
         use_native = (topology.size > 1 or cfg.controller == "native") \
             and native.available()
         if core is not None:

@@ -68,6 +68,43 @@ class _PowerSGDState(NamedTuple):
     step: jnp.ndarray
 
 
+def _map_residuals(fn, tree):
+    def is_psgd(x):
+        return isinstance(x, _PowerSGDState)
+    return jax.tree_util.tree_map(
+        lambda x: x._replace(e=fn(x.e)) if is_psgd(x) else x,
+        tree, is_leaf=is_psgd)
+
+
+def gather_per_rank_state(tree):
+    """Snapshot form of an optimizer-state tree: every PowerSGD
+    error-feedback residual — the one piece of optimizer state that
+    differs per rank (the residuals of a bucket nearly cancel across
+    ranks, so handing rank 0's to everyone corrupts the feedback) —
+    stacked across ranks on a new leading axis. Collective: every
+    rank calls it; a tree without residuals issues nothing."""
+    def gather(e):
+        keys = sorted(e)
+        if not keys:
+            return e
+        got = C.grouped_allgather(
+            [jnp.asarray(e[k])[None] for k in keys],
+            name="JaxState.snapshot.residuals")
+        return dict(zip(keys, got))
+    return _map_residuals(gather, tree)
+
+
+def select_per_rank_state(tree, saved_world: int, rank: int, size: int):
+    """Inverse of gather_per_rank_state on load: this rank's own row.
+    After a resize each rank takes an equal share of the summed
+    residual — the trajectory depends on the sum alone."""
+    def pick(v):
+        v = jnp.asarray(v)
+        return v[rank] if size == saved_world else v.sum(0) / size
+    return _map_residuals(
+        lambda e: {k: pick(v) for k, v in e.items()}, tree)
+
+
 def _tree_zeros_like(tree):
     return jax.tree_util.tree_map(jnp.zeros_like, tree)
 

@@ -8,12 +8,10 @@ first timed call) and for XLA's cost analysis (compiling a second
 time just to read flops would double a multi-ten-second ResNet
 compile).
 
-The fallback contract matters more than the fast path: on backends
-where ``lower().compile()`` or ``cost_analysis()`` is unavailable,
-the caller gets the original jitted callable back (the jit cache
-then owns compilation) and flops=0.0, never an exception — bench
-prints "unavailable" metrics and serving falls back to per-bucket
-jit warmup, but neither dies.
+A failed compile raises: a caller that asked for an executable for
+this backend must not be handed a jit path that may be compiling
+for another. Only cost analysis is optional (flops=0.0 where the
+backend does not report it).
 """
 
 from __future__ import annotations
@@ -27,17 +25,11 @@ def aot_compile(step_fn: Callable[..., Any], *args
                 ) -> Tuple[Callable[..., Any], float]:
     """AOT-compile ``step_fn`` (a jitted callable) for ``args``.
 
-    Returns ``(callable, flops_per_execution)``. The callable is the
-    compiled executable when lowering succeeds (exact-shape,
-    exact-placement: callers must feed arguments matching ``args``),
-    or ``step_fn`` itself when the backend cannot AOT-compile; flops
-    is 0.0 whenever cost analysis is unavailable.
+    Returns ``(compiled, flops_per_execution)``. The executable is
+    exact-shape, exact-placement: callers must feed arguments matching
+    ``args``. flops is 0.0 whenever cost analysis is unavailable.
     """
-    try:
-        compiled = step_fn.lower(*args).compile()
-    except Exception as e:  # pragma: no cover - backend-dependent
-        hlog.info("aot: AOT compile unavailable (%s); using jit path", e)
-        return step_fn, 0.0
+    compiled = step_fn.lower(*args).compile()
     flops = 0.0
     try:
         ca = compiled.cost_analysis()

@@ -15,7 +15,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from ..common.compat import axis_size as _compat_axis_size
 from jax import lax
 
 from .mesh import EXPERT_AXIS
@@ -57,7 +56,7 @@ def moe_ffn(tokens: jax.Array, router_w: jax.Array, w_in: jax.Array,
     E = E_local * ep. Returns (output (T, D), aux_loss)."""
     T, D = tokens.shape
     E_local = w_in.shape[0]
-    ep = _compat_axis_size(axis_name) if axis_name else 1
+    ep = lax.axis_size(axis_name) if axis_name else 1
     E = E_local * ep
     capacity = max(1, int(capacity_factor * T / E))
 

@@ -20,8 +20,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from ..common.compat import axis_size as _compat_axis_size
-from ..common.compat import pcast as _compat_pcast
 from jax import lax
 
 from .mesh import PIPE_AXIS
@@ -44,7 +42,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
     stage's results are broadcast back over the pipe axis with one
     psum-mask, so callers can compute loss uniformly).
     """
-    n_stages = _compat_axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     n_micro = x_micro.shape[0]
     ticks = n_micro + n_stages - 1
@@ -72,9 +70,9 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
 
     # carries become device-varying over the pipe axis on first tick;
     # start them varying (shard_map VMA typing).
-    init_state = _compat_pcast(jnp.zeros(act_shape, x_micro.dtype),
+    init_state = lax.pcast(jnp.zeros(act_shape, x_micro.dtype),
                            (axis_name,), to="varying")
-    init_out = _compat_pcast(jnp.zeros((n_micro,) + act_shape, x_micro.dtype),
+    init_out = lax.pcast(jnp.zeros((n_micro,) + act_shape, x_micro.dtype),
                          (axis_name,), to="varying")
     (_, outputs), _ = lax.scan(tick, (init_state, init_out),
                                jnp.arange(ticks))

@@ -28,7 +28,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from ..common.compat import axis_size as _compat_axis_size
 from jax import lax
 
 from .mesh import SEQ_AXIS
@@ -55,7 +54,7 @@ def _merge(acc_num, acc_den, acc_max, scores, v):
 def _ring_body(q, k, v, axis_name: str, causal: bool, scale: float):
     """Runs inside shard_map: q,k,v are this device's blocks
     (B, L, H, D)."""
-    n = _compat_axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     B, Lq, H, D = q.shape
     qf = q.astype(jnp.float32)
@@ -183,12 +182,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
         scale = q.shape[-1] ** -0.5
     mode = _flash_mode()
     if mode == "1" or (mode == "auto" and _flash_supported(q, k)):
-        try:
-            return flash_attention_path(q, k, v, causal, float(scale))
-        except Exception:
-            if mode == "1":
-                raise
-            # auto: fall through to the reference einsum path
+        return flash_attention_path(q, k, v, causal, float(scale))
     scores = _blockwise_scores(q.astype(jnp.float32),
                                k.astype(jnp.float32), float(scale))
     if causal:

@@ -26,12 +26,11 @@ from typing import Any, Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import numerics as _numerics
-from ..common.compat import GRADS_PRE_SUMMED, shard_map
 from ..ops.bucketing import (assignment_digest, partition_buckets,
                              split_by_dtype)
 from ..ops.compression import (CompressionSpec, effective_rank,
@@ -40,14 +39,8 @@ from ..ops.compression import (CompressionSpec, effective_rank,
                                powersgd_reduce, powersgd_wire_elements,
                                resolve_compression, wire_dtype_of)
 from ..ops import compression as _compression
-from .mesh import FSDP_AXIS, batch_axes
+from .mesh import AXIS_ORDER, FSDP_AXIS, batch_axes
 from .sharding import replicated
-
-# VMA-leg bucketing needs lax.pvary to keep the tag's outputs varying
-# (so the implicit-pbroadcast transpose cannot double-psum the
-# cotangents); modern shard_map without pvary (a narrow jax 0.5.x
-# band) falls back to the monolithic reduction.
-_OVERLAP_SUPPORTED = (not GRADS_PRE_SUMMED) or hasattr(lax, "pvary")
 
 
 def overlap_enabled() -> bool:
@@ -488,8 +481,7 @@ def _flag_carrier_group(groups, dtypes):
 def _make_bucket_tag(bucket_id: int, raxes: Tuple[str, ...],
                      all_axes: Tuple[str, ...],
                      shapes: Tuple, dtypes: Tuple, scale,
-                     guard: bool, vma: bool, probe,
-                     wire_cast=None):
+                     guard: bool, probe, wire_cast=None):
     """custom_vjp identity over one bucket of parameter leaves whose
     BACKWARD rule is the bucket's fused reduction: the cotangents are
     flattened and packed into one wire array per dtype (the in-jit
@@ -506,11 +498,11 @@ def _make_bucket_tag(bucket_id: int, raxes: Tuple[str, ...],
     way a value computed in a bwd rule can reach the caller of
     value_and_grad.
 
-    VMA leg (`vma`): the forward lifts each leaf to varying over the
-    reduce axes with lax.pvary, so no implicit pbroadcast (whose
+    The forward lifts each leaf to varying over the reduce axes
+    (lax.pcast to='varying'), so no implicit pbroadcast (whose
     transpose would psum the cotangent BEFORE it reaches this bwd
     rule) is inserted downstream — the bucket psum here is the one
-    and only reduction, same as the legacy leg.
+    and only reduction.
 
     `probe` (timeline verification only, off by default): host
     callbacks on the packed wire array (cotangents ready) and on the
@@ -556,9 +548,7 @@ def _make_bucket_tag(bucket_id: int, raxes: Tuple[str, ...],
         return x
 
     def _primal(xs):
-        if vma:
-            return tuple(lax.pvary(x, raxes) for x in xs)
-        return tuple(xs)
+        return tuple(lax.pcast(x, raxes, to="varying") for x in xs)
 
     @jax.custom_vjp
     def tag(dummy, *xs):
@@ -659,7 +649,7 @@ def _make_bucket_tag(bucket_id: int, raxes: Tuple[str, ...],
 
 def _make_powersgd_tag(bucket_id: int, raxes: Tuple[str, ...],
                        shapes: Tuple, dtypes: Tuple, scale,
-                       guard: bool, vma: bool, probe,
+                       guard: bool, probe,
                        rank: int, n_devices: int):
     """custom_vjp identity over one PowerSGD bucket: the backward
     rule runs the low-rank factor handshake of
@@ -700,9 +690,7 @@ def _make_powersgd_tag(bucket_id: int, raxes: Tuple[str, ...],
         return x
 
     def _primal(xs):
-        if vma:
-            return tuple(lax.pvary(x, raxes) for x in xs)
-        return tuple(xs)
+        return tuple(lax.pcast(x, raxes, to="varying") for x in xs)
 
     @jax.custom_vjp
     def tag(dummy, *args):
@@ -834,6 +822,16 @@ def build_train_step(
     `(bucket_id, phase, nbytes)` timestamping each bucket's
     ready/reduced edges — see tracing.OverlapProbe.
     """
+    unknown = [a for a in mesh.axis_names if a not in AXIS_ORDER]
+    if unknown:
+        # The batch is sharded over data/fsdp/expert only; under an
+        # axis outside the vocabulary every device would redo the
+        # same batch and the two reduction paths disagree on what a
+        # replicated loss's gradient is.
+        raise ValueError(
+            f"build_train_step: mesh axis {unknown[0]!r} is not one of "
+            f"{AXIS_ORDER}; build the mesh with data_parallel_mesh() "
+            "or make_mesh()")
     baxes = batch_axes(mesh)
     n_batch = 1
     for a in baxes:
@@ -854,56 +852,6 @@ def build_train_step(
     # axes. The true data-parallel MEAN gradient is therefore that
     # psum divided by the batch-axis product; one uniform scale is
     # correct for replicated AND model-sharded parameters alike.
-    # Legacy-jax model-axis over-count (jax < 0.5, no VMA typing,
-    # check_rep off): the transpose of a psum is another psum there,
-    # so every backward pass through the model's OWN replicating
-    # collectives (tp's psum'd projections/vocab-parallel CE, sp's
-    # loss pmean) multiplies the cotangent by the axis size — the
-    # per-rank gradient of a loss replicated across a model axis
-    # arrives exactly |axis|x too large, uniformly for every leaf
-    # (sharded or not; measured 2.0x per live tp/sp axis, 4.0x for
-    # tp x sp). The canonical MODEL axes (tensor/seq/pipe — the axes
-    # whose in-loss collectives replicate the loss) are known by
-    # name; axes outside the framework vocabulary (ad-hoc test
-    # meshes) are treated as Horovod-parity batch axes and left
-    # alone. The correction is one uniform scale: 1/prod(model-axis
-    # sizes). Modern jax's VMA transpose has no such over-count
-    # (pbroadcast transposes to psum exactly once) — the fix is
-    # legacy-leg only.
-    from .mesh import PIPE_AXIS, SEQ_AXIS, TENSOR_AXIS
-    n_model = 1
-    for a in (TENSOR_AXIS, SEQ_AXIS, PIPE_AXIS):
-        if a in mesh.shape and a not in baxes:
-            n_model *= mesh.shape[a]
-    legacy_fix = (1.0 / n_model
-                  if not GRADS_PRE_SUMMED and n_model > 1 else None)
-
-    def _sum_missing_axes(grads):
-        """Legacy-jax leg: without VMA typing (and with the legacy
-        replication checker off — see compat.shard_map) the transpose
-        does NOT psum a replicated parameter's cotangent, so each
-        device holds only its LOCAL contribution. Insert exactly the
-        missing psums: every mesh axis the parameter's spec does not
-        name (the axes it is replicated across) — then undo the
-        legacy model-axis over-count (see `legacy_fix` above)."""
-        axis_names = tuple(mesh.shape.keys())
-        spec_tree = _broadcast_specs(param_specs, grads)
-
-        def one(g, spec):
-            named = _spec_named_axes(spec)
-            for a in axis_names:
-                # psum over a size-1 axis is the identity — emitting
-                # it would only hand XLA dead collectives to elide
-                # (and kept the world-1 program from matching the
-                # wire-gated overlap build byte-for-byte).
-                if a not in named and mesh.shape[a] > 1:
-                    g = lax.psum(g, a)
-            if legacy_fix is not None and jnp.issubdtype(
-                    g.dtype, jnp.inexact):
-                g = g * jnp.asarray(legacy_fix, g.dtype)
-            return g
-
-        return jax.tree.map(one, grads, spec_tree)
 
     # Coordinated skip-step (numerics.py): decided once at build time
     # so a disabled guard changes NOTHING in the traced program (the
@@ -920,29 +868,18 @@ def build_train_step(
         to ONE shard of a model-sharded parameter yields a flag that
         differs across that axis, so a per-device decision would step
         some replicas and skip others (silently diverging replicated
-        params); unanimity is the only safe decision. On the VMA leg
-        the flag's varying-type is inherited from the gradient leaves,
-        and psum over an axis the flag is unvarying on is rejected by
-        the typing — lift the missing axes with lax.pvary first.
-
-        Legacy leg: the vote folds only LIVE (size>1) axes — a psum
-        over a size-1 axis is identity wire (the r08 wire-gate class;
-        HVD007 flags it as a dead collective), and a size-1 axis
-        contributes x1 to the count either way. The VMA leg keeps
-        EVERY axis: there the psum is what flips the flag's
+        params); unanimity is the only safe decision. The flag's
+        varying-type is inherited from the gradient leaves, and psum
+        over an axis the flag is unvarying on is rejected by the
+        typing — lift the missing axes first. EVERY axis is folded,
+        size-1 ones included: the psum is what flips the flag's
         varying-type to unvarying, so a size-1 axis' psum is
         type-required (and wire-free — XLA elides it)."""
-        axis_names = (tuple(mesh.shape.keys()) if GRADS_PRE_SUMMED
-                      else _live_axes(mesh))
-        if GRADS_PRE_SUMMED and hasattr(lax, "pvary"):
-            try:
-                vma = frozenset(getattr(getattr(flag, "aval", None),
-                                        "vma", ()) or ())
-            except Exception:  # pragma: no cover - typing introspection
-                vma = frozenset()
-            missing = tuple(a for a in axis_names if a not in vma)
-            if missing:
-                flag = lax.pvary(flag, missing)
+        axis_names = tuple(mesh.shape.keys())
+        missing = tuple(a for a in axis_names
+                        if a not in jax.typeof(flag).vma)
+        if missing:
+            flag = lax.pcast(flag, missing, to="varying")
         cnt = _psum_axes(flag, axis_names)
         return cnt > n_devices - 0.5
 
@@ -950,15 +887,12 @@ def build_train_step(
         ok = None
         if guard:
             # Local finite-flag over the incoming gradients, then the
-            # explicit all-axes unanimity vote (both legs: on the VMA
-            # leg the automatic psums only folded each leaf's
-            # REPLICATED axes, which is not device-global for sharded
-            # leaves).
+            # explicit all-axes unanimity vote (the automatic psums
+            # only folded each leaf's REPLICATED axes, which is not
+            # device-global for sharded leaves).
             flag = _numerics.local_finite_flag(
                 jax.tree_util.tree_leaves(grads))
             ok = _unanimity(flag)
-        if not GRADS_PRE_SUMMED:
-            grads = _sum_missing_axes(grads)
         if grad_reducer is not None:
             out = grad_reducer(grads)
         elif n_batch == 1:
@@ -984,7 +918,7 @@ def build_train_step(
     # the traced program (the HLO-identity acceptance test pins that
     # overlap=off lowers byte-identically to the monolithic builder).
     use_overlap = (overlap_enabled() if overlap is None
-                   else bool(overlap)) and _OVERLAP_SUPPORTED
+                   else bool(overlap))
     bthresh = (overlap_threshold_bytes() if overlap_threshold is None
                else int(overlap_threshold))
     cspec = compression_spec(compression, compression_rank,
@@ -996,20 +930,11 @@ def build_train_step(
             "enable HOROVOD_JIT_OVERLAP / overlap=True or set "
             "compression='none'")
     use_powersgd = cspec.kind == "powersgd"
-    vma_leg = GRADS_PRE_SUMMED and hasattr(lax, "pvary")
-    axis_names = tuple(mesh.shape.keys())
     live_axes = _live_axes(mesh)
-    # Bucketed-path scale: the 1/n_batch mean (when no custom reducer
-    # owns scaling) folded with the legacy model-axis correction —
-    # which applies EVEN under a custom reducer, so the reducer sees
-    # the same correctly-summed grads the monolithic path hands it.
-    _base_scale = (1.0 / n_batch
-                   if grad_reducer is None and n_batch != 1 else None)
-    if legacy_fix is not None:
-        default_scale = (_base_scale if _base_scale is not None
-                         else 1.0) * legacy_fix
-    else:
-        default_scale = _base_scale
+    # Bucketed-path scale: the 1/n_batch mean, unless a custom
+    # reducer owns scaling.
+    default_scale = (1.0 / n_batch
+                     if grad_reducer is None and n_batch != 1 else None)
 
     def _bucketed_value_and_grad(params, batch, cstate=None):
         """value_and_grad with per-bucket custom_vjp boundaries: each
@@ -1076,13 +1001,13 @@ def build_train_step(
             if ctag.startswith("powersgd"):
                 tags.append(_make_powersgd_tag(
                     bid, plan.bucket_raxes[bid], bshapes, bdtypes,
-                    default_scale, guard, vma_leg, overlap_probe,
+                    default_scale, guard, overlap_probe,
                     int(ctag.split(":", 1)[1]), n_devices))
             else:
                 tags.append(_make_bucket_tag(
                     bid, plan.bucket_raxes[bid], live_axes,
                     bshapes, bdtypes,
-                    default_scale, guard, vma_leg, overlap_probe,
+                    default_scale, guard, overlap_probe,
                     wire_cast=(jnp.dtype(jnp.float16)
                                if ctag == "fp16" else
                                jnp.dtype(jnp.bfloat16)
@@ -1188,23 +1113,25 @@ def build_train_step(
             grads = _numerics.imprint_non_finite(grads, ok)
         return loss, aux, grads, new_cstate
 
-    # Metric averaging: legacy leg only pmeans over LIVE batch axes
-    # (pmean over a size-1 axis is an identity psum + div-by-1 — dead
-    # wire HVD007 flags); the VMA leg keeps every axis because the
-    # psum inside pmean is what makes the loss unvarying so it can
-    # satisfy the replicated P() out_spec.
-    metric_baxes = (baxes if GRADS_PRE_SUMMED
-                    else tuple(a for a in baxes if mesh.shape[a] > 1))
+    def _replicate_metric(x):
+        """Average a metric over every batch axis (size-1 ones too:
+        the psum inside pmean is what makes the value unvarying so it
+        satisfies the replicated P() out_spec) and over any model
+        axis it is still typed varying on — the overlap tags lift
+        replicated params to varying, which can leave an
+        equal-valued loss varying-typed there."""
+        vma = jax.typeof(x).vma
+        return _pmean_axes(x, tuple(a for a in mesh.shape
+                                    if a in baxes or a in vma))
 
     def _finish_step(loss, aux, grads, params, opt_state):
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
-        metrics = {"loss": _pmean_axes(loss, metric_baxes)}
+        metrics = {"loss": _replicate_metric(loss)}
         if aux is not None:
             # aux is device-varying; average it so metrics satisfy the
             # replicated (P()) out_spec.
-            metrics["aux"] = jax.tree.map(
-                lambda a: _pmean_axes(a, metric_baxes), aux)
+            metrics["aux"] = jax.tree.map(_replicate_metric, aux)
         return params, opt_state, metrics
 
     if use_powersgd:

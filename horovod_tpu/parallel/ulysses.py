@@ -18,7 +18,6 @@ constraint and overlaps comm with compute).
 from __future__ import annotations
 
 import jax
-from ..common.compat import axis_size as _compat_axis_size
 from jax import lax
 
 from .mesh import SEQ_AXIS
@@ -28,7 +27,7 @@ from .ring_attention import attention
 def scatter_heads(x: jax.Array, axis_name: str = SEQ_AXIS) -> jax.Array:
     """(B, L_local, H, D) sharded by seq → (B, L_full, H/sp, D) sharded
     by heads. Inside shard_map."""
-    sp = _compat_axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     B, L, H, D = x.shape
     assert H % sp == 0, f"heads {H} not divisible by seq-parallel {sp}"
     # split head axis across devices, gather sequence axis.
@@ -42,7 +41,7 @@ def scatter_heads(x: jax.Array, axis_name: str = SEQ_AXIS) -> jax.Array:
 def gather_heads(x: jax.Array, axis_name: str = SEQ_AXIS) -> jax.Array:
     """Inverse of scatter_heads: (B, L_full, H/sp, D) → (B, L_local,
     H, D)."""
-    sp = _compat_axis_size(axis_name)
+    sp = lax.axis_size(axis_name)
     B, Lf, Hs, D = x.shape
     assert Lf % sp == 0
     x = x.reshape(B, sp, Lf // sp, Hs, D)

@@ -1518,6 +1518,11 @@ _tracing.register_postmortem_provider("serving", _postmortem_inflight)
 # Remote worker loop
 
 
+# How long a remote pool member keeps pulling from a frontend that
+# does not answer before it concludes the frontend is gone.
+_FRONTEND_GONE_S = 10.0
+
+
 def remote_worker_loop(addr: str, port: int,
                        forward_fn: Callable,
                        feature_shape: Sequence[int],
@@ -1591,6 +1596,7 @@ def remote_worker_loop(addr: str, port: int,
         compiled[shape] = fn
         _m_compiles.inc()
     done = 0
+    last_reply = time.monotonic()
     while True:
         if w_sub is not None:
             # Adopt between pulls — the remote member's epoch fence.
@@ -1623,8 +1629,16 @@ def remote_worker_loop(addr: str, port: int,
         reply = cli.try_request({"type": "pull", "worker": wid,
                                  "wait": 0.2}, retries=2)
         if reply is None:
+            # A frontend that stays unreachable is gone (closed, or
+            # died without saying stop): leave, don't spin forever.
+            if time.monotonic() - last_reply > _FRONTEND_GONE_S:
+                hlog.warning("serving: remote %s: frontend %s:%d "
+                             "unreachable for %.0fs; leaving the pool",
+                             wid, addr, port, _FRONTEND_GONE_S)
+                return done
             time.sleep(0.05)
             continue
+        last_reply = time.monotonic()
         if reply.get("stop"):
             return done
         b = reply.get("batch")

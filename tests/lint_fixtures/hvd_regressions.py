@@ -132,7 +132,7 @@ def pr8_wire_gate_builder():
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from horovod_tpu.common.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices("cpu")[:2]).reshape(2, 1),
                 ("data", "one"))
@@ -159,7 +159,7 @@ def pr8_legacy_double_reduce_builder():
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from horovod_tpu.common.compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices("cpu")[:2]), ("data",))
 
@@ -189,7 +189,7 @@ def pr13_flag_rides_compressed_carrier_builder():
     from jax import lax
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from horovod_tpu.common.compat import shard_map
+    from jax import shard_map
     from horovod_tpu.parallel.train import OverlapPlan, WireGroup
 
     mesh = Mesh(np.array(jax.devices("cpu")[:2]), ("data",))

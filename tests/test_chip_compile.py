@@ -1,0 +1,140 @@
+"""Compile the main path's kernels and step programs for a described
+TPU v5e 2x2 with the installed TPU compiler — no chip attached, nothing
+runs. The only test file that describes the chip: the topology call
+loads the TPU library, which one process at a time may hold, so it
+happens inside a module-scoped fixture (never at import, in a skipif or
+in parametrize arguments) and every compile stays in this process.
+"""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+import chip_smoke  # noqa: E402
+
+HBM_BYTES = 16e9  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent
+    # cache but cannot be read back without one: keep it off here.
+    # The chip runs with x64 off (conftest turns it on for the CPU
+    # tests); Mosaic refuses the i64 block indices x64 would make.
+    was = {k: getattr(jax.config, k) for k in
+           ("jax_enable_compilation_cache", "jax_enable_x64")}
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_enable_x64", False)
+    compilation_cache.reset_cache()
+    yield t
+    for k, v in was.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def _on(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=sharding), tree)
+
+
+@pytest.mark.parametrize("n", chip_smoke.ADASUM_SIZES,
+                         ids=["64MiB", "odd"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_adasum_pair_combine_compiles_to_custom_call(topo, n, dtype):
+    from horovod_tpu.ops.pallas_kernels import adasum_pair_combine
+    x = jax.ShapeDtypeStruct((n,), dtype,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+    compiled = adasum_pair_combine.lower(x, x, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_forward_and_backward_compile(topo):
+    from horovod_tpu.parallel.ring_attention import flash_attention_path
+    q = jax.ShapeDtypeStruct((16, 512, 16, 64), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(topo.devices[0]))
+
+    def fwd(q, k, v):
+        return flash_attention_path(q, k, v, True, 64 ** -0.5)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    for fn in (fwd, bwd):
+        hlo = jax.jit(fn).lower(q, q, q).compile().as_text()
+        assert "tpu_custom_call" in hlo
+
+
+def _flagship_compiled(devices, global_batch):
+    from horovod_tpu.models import transformer as tfm
+    mesh = Mesh(np.array(devices), axis_names=("data",))
+    shape = chip_smoke.FLAGSHIP
+    cfg, opt, step = chip_smoke.flagship_step(shape, mesh)
+    params = jax.eval_shape(
+        lambda: tfm.init_params(cfg, jax.random.PRNGKey(0)))
+    opt_state = jax.eval_shape(opt.init, params)
+    rep = NamedSharding(mesh, P())
+    tok = jax.ShapeDtypeStruct((global_batch, shape["seq"]), jnp.int32,
+                               sharding=NamedSharding(mesh, P("data")))
+    return step.lower(_on(params, rep), _on(opt_state, rep),
+                      {"tokens": tok, "targets": tok}).compile()
+
+
+def test_flagship_step_fits_one_chip(topo):
+    compiled = _flagship_compiled(topo.devices[:1],
+                                  chip_smoke.FLAGSHIP["batch"])
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_flagship_step_four_chip_data_mesh(topo):
+    from horovod_tpu.parallel.train import last_overlap_info
+    compiled = _flagship_compiled(topo.devices,
+                                  4 * chip_smoke.FLAGSHIP["batch"])
+    assert last_overlap_info()["buckets"] > 0
+    hlo = compiled.as_text()
+    assert " all-reduce(" in hlo or " all-reduce-start(" in hlo
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_resnet50_step_fits_one_chip(topo):
+    from horovod_tpu.models.resnet import init_resnet
+    mesh = Mesh(np.array(topo.devices[:1]), axis_names=("data",))
+    shape = chip_smoke.RESNET
+    model, opt, step = chip_smoke.resnet_step(shape, mesh)
+    variables = jax.eval_shape(
+        lambda: init_resnet(model, jax.random.PRNGKey(0),
+                            shape["image"]))
+    params, stats = variables["params"], variables["batch_stats"]
+    opt_state = jax.eval_shape(opt.init, params)
+    rep, data = NamedSharding(mesh, P()), NamedSharding(mesh, P("data"))
+    b, px = shape["batch"], shape["image"]
+    batch = {
+        "images": jax.ShapeDtypeStruct((b, px, px, 3), jnp.float32,
+                                       sharding=data),
+        "labels": jax.ShapeDtypeStruct((b,), jnp.int32, sharding=data),
+        "batch_stats": _on(stats, rep)}
+    compiled = step.lower(_on(params, rep), _on(opt_state, rep),
+                          batch).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES

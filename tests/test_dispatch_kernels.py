@@ -184,7 +184,7 @@ class TestAlltoallLaunchAwareHeuristic:
         dispatch.set_launch_profile(None, 4e10, 16)
 
     def test_skewed_high_latency_picks_padded(self):
-        # 50 ms/launch (a tunnel-attached host), 8 ranks, heavy skew:
+        # 50 ms/launch (a slow launch path), 8 ranks, heavy skew:
         # ragged saves ~7/8 of the bytes but pays 7 launches.
         dispatch.set_launch_profile(0.05, 4e10, 16)
         n = 8

@@ -4,7 +4,7 @@ technique 2 — fake devices instead of a cluster)."""
 
 import jax
 import jax.numpy as jnp
-from horovod_tpu.common.compat import shard_map
+from jax import shard_map
 import numpy as np
 import pytest
 from jax import lax

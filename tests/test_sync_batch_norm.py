@@ -6,7 +6,7 @@ with a live axis — this is that test."""
 
 import jax
 import jax.numpy as jnp
-from horovod_tpu.common.compat import shard_map
+from jax import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
