@@ -1,0 +1,8 @@
+"""See `perfbench/layer_readers.py` `device_idle_pct`."""
+
+from perfbench.layer_readers import device_idle_pct as compute  # noqa: F401
+
+NAME = "device_idle_pct.tok"
+UNIT = "%"
+LAYER = "device"
+MOVES = "tokens_per_s_chip"
