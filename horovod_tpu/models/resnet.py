@@ -19,6 +19,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..tracing import device_scope
+
 ModuleDef = Any
 
 
@@ -31,18 +33,27 @@ class BottleneckBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         residual = x
-        y = self.conv(self.filters, (1, 1))(x)
-        y = self.norm()(y)
+        with device_scope("hvd.conv"):
+            y = self.conv(self.filters, (1, 1))(x)
+        with device_scope("hvd.batchnorm"):
+            y = self.norm()(y)
         y = nn.relu(y)
-        y = self.conv(self.filters, (3, 3), self.strides)(y)
-        y = self.norm()(y)
+        with device_scope("hvd.conv"):
+            y = self.conv(self.filters, (3, 3), self.strides)(y)
+        with device_scope("hvd.batchnorm"):
+            y = self.norm()(y)
         y = nn.relu(y)
-        y = self.conv(self.filters * 4, (1, 1))(y)
-        y = self.norm(scale_init=nn.initializers.zeros)(y)
+        with device_scope("hvd.conv"):
+            y = self.conv(self.filters * 4, (1, 1))(y)
+        with device_scope("hvd.batchnorm"):
+            y = self.norm(scale_init=nn.initializers.zeros)(y)
         if residual.shape != y.shape:
-            residual = self.conv(self.filters * 4, (1, 1),
-                                 self.strides, name="conv_proj")(residual)
-            residual = self.norm(name="norm_proj")(residual)
+            with device_scope("hvd.conv"):
+                residual = self.conv(
+                    self.filters * 4, (1, 1), self.strides,
+                    name="conv_proj")(residual)
+            with device_scope("hvd.batchnorm"):
+                residual = self.norm(name="norm_proj")(residual)
         return nn.relu(residual + y)
 
 
@@ -63,9 +74,11 @@ class ResNet(nn.Module):
             axis_name=(tuple(self.sync_bn_axes)
                        if self.sync_bn_axes else None))
         x = x.astype(self.dtype)
-        x = conv(self.num_filters, (7, 7), (2, 2),
-                 padding=[(3, 3), (3, 3)], name="conv_init")(x)
-        x = norm(name="bn_init")(x)
+        with device_scope("hvd.conv"):
+            x = conv(self.num_filters, (7, 7), (2, 2),
+                     padding=[(3, 3), (3, 3)], name="conv_init")(x)
+        with device_scope("hvd.batchnorm"):
+            x = norm(name="bn_init")(x)
         x = nn.relu(x)
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         for i, block_count in enumerate(self.stage_sizes):
@@ -73,9 +86,10 @@ class ResNet(nn.Module):
                 strides = (2, 2) if i > 0 and j == 0 else (1, 1)
                 x = BottleneckBlock(self.num_filters * 2 ** i, strides,
                                     conv, norm)(x)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
-        return x.astype(jnp.float32)
+        with device_scope("hvd.head_loss"):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
+            return x.astype(jnp.float32)
 
 
 ResNet50 = functools.partial(ResNet, stage_sizes=[3, 4, 6, 3])
@@ -107,7 +121,8 @@ def resnet_loss_fn(model: ResNet, variables, batch, train: bool = True):
     else:
         logits = model.apply(variables, images, train=False)
         new_stats = variables.get("batch_stats")
-    onehot = jax.nn.one_hot(labels, logits.shape[-1])
-    loss = jnp.mean(
-        -jnp.sum(onehot * jax.nn.log_softmax(logits), axis=-1))
+    with device_scope("hvd.head_loss"):
+        onehot = jax.nn.one_hot(labels, logits.shape[-1])
+        loss = jnp.mean(
+            -jnp.sum(onehot * jax.nn.log_softmax(logits), axis=-1))
     return loss, new_stats

@@ -36,6 +36,7 @@ from jax import lax
 from ..parallel.mesh import EXPERT_AXIS, SEQ_AXIS, TENSOR_AXIS
 from ..parallel.ring_attention import attention as full_attention
 from ..parallel.ring_attention import ring_attention
+from ..tracing import device_scope
 
 
 @dataclasses.dataclass(frozen=True)
@@ -239,39 +240,43 @@ def _attention_block(cfg: TransformerConfig, p: Dict[str, jax.Array],
     local shards: wq (D, H_local*Dh) etc.)."""
     B, L, D = x.shape
     Dh = cfg.head_dim
-    h = rmsnorm(x, p["attn_norm"])
-    q = (h @ p["wq"]).reshape(B, L, -1, Dh)
-    kk = (h @ p["wk"]).reshape(B, L, -1, Dh)
-    v = (h @ p["wv"]).reshape(B, L, -1, Dh)
+    with device_scope("hvd.attn.proj"):
+        h = rmsnorm(x, p["attn_norm"])
+        q = (h @ p["wq"]).reshape(B, L, -1, Dh)
+        kk = (h @ p["wk"]).reshape(B, L, -1, Dh)
+        v = (h @ p["wv"]).reshape(B, L, -1, Dh)
 
-    sp_idx = _axis_index(cfg.sp_axis)
-    positions = sp_idx * L + jnp.arange(L)
-    q = _rope(q, positions, cfg.rope_theta)
-    kk = _rope(kk, positions, cfg.rope_theta)
+        sp_idx = _axis_index(cfg.sp_axis)
+        positions = sp_idx * L + jnp.arange(L)
+        q = _rope(q, positions, cfg.rope_theta)
+        kk = _rope(kk, positions, cfg.rope_theta)
 
-    # GQA: repeat kv heads to match local q heads.
-    reps = q.shape[2] // kk.shape[2]
-    if reps > 1:
-        kk = jnp.repeat(kk, reps, axis=2)
-        v = jnp.repeat(v, reps, axis=2)
+        # GQA: repeat kv heads to match local q heads.
+        reps = q.shape[2] // kk.shape[2]
+        if reps > 1:
+            kk = jnp.repeat(kk, reps, axis=2)
+            v = jnp.repeat(v, reps, axis=2)
 
-    if cfg.sp_axis is not None and _axis_size(cfg.sp_axis) > 1:
-        o = ring_attention(q, kk, v, cfg.sp_axis, causal=True)
-    else:
-        o = full_attention(q, kk, v, causal=True)
+    with device_scope("hvd.attn.core"):
+        if cfg.sp_axis is not None and _axis_size(cfg.sp_axis) > 1:
+            o = ring_attention(q, kk, v, cfg.sp_axis, causal=True)
+        else:
+            o = full_attention(q, kk, v, causal=True)
 
-    o = o.reshape(B, L, -1) @ p["wo"]          # partial sum over tp shard
-    o = _maybe_psum(o, cfg.tp_axis)
-    return x + o.astype(x.dtype)
+    with device_scope("hvd.attn.proj"):
+        o = o.reshape(B, L, -1) @ p["wo"]      # partial sum over tp shard
+        o = _maybe_psum(o, cfg.tp_axis)
+        return x + o.astype(x.dtype)
 
 
 def _dense_ffn(cfg: TransformerConfig, p, x):
-    h = rmsnorm(x, p["mlp_norm"])
-    gate = jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32))
-    up = (h @ p["w_up"]).astype(jnp.float32)
-    out = (gate * up).astype(x.dtype) @ p["w_down"]
-    out = _maybe_psum(out, cfg.tp_axis)
-    return x + out.astype(x.dtype)
+    with device_scope("hvd.ffn"):
+        h = rmsnorm(x, p["mlp_norm"])
+        gate = jax.nn.silu((h @ p["w_gate"]).astype(jnp.float32))
+        up = (h @ p["w_up"]).astype(jnp.float32)
+        out = (gate * up).astype(x.dtype) @ p["w_down"]
+        out = _maybe_psum(out, cfg.tp_axis)
+        return x + out.astype(x.dtype)
 
 
 def _ffn_block(cfg: TransformerConfig, p: Dict[str, jax.Array],
@@ -280,8 +285,9 @@ def _ffn_block(cfg: TransformerConfig, p: Dict[str, jax.Array],
         # fold gate/up into one in-projection for the shared moe_ffn
         # (SwiGLU needs two; combine by concat on F).
         pm = dict(p)
-        pm["w_gate_combined"] = jnp.concatenate(
-            [p["w_gate"], p["w_up"]], axis=-1)
+        with device_scope("hvd.moe"):
+            pm["w_gate_combined"] = jnp.concatenate(
+                [p["w_gate"], p["w_up"]], axis=-1)
         # hvdlint: disable-next=HVD005 (branch on static model
         # config: cfg.moe is identical on every rank, each arm is a
         # uniform schedule)
@@ -300,43 +306,44 @@ def _moe_swiglu(cfg: TransformerConfig, p, x):
     """MoE FFN with SwiGLU experts: in-proj produces [gate|up] (2F),
     activation splits them."""
     from ..parallel.moe import top1_route
-    B, L, D = x.shape
-    h = rmsnorm(x, p["mlp_norm"])
-    tokens = h.reshape(B * L, D).astype(jnp.float32)
-    ep_axis = (cfg.ep_axis if cfg.ep_axis is not None and
-               _axis_size(cfg.ep_axis) > 1 else None)
-    ep = _axis_size(ep_axis) if ep_axis else 1
-    E_local = p["w_down"].shape[0]
-    E = E_local * ep
-    T = tokens.shape[0]
-    C = max(1, int(cfg.capacity_factor * T / E))
+    with device_scope("hvd.moe"):
+        B, L, D = x.shape
+        h = rmsnorm(x, p["mlp_norm"])
+        tokens = h.reshape(B * L, D).astype(jnp.float32)
+        ep_axis = (cfg.ep_axis if cfg.ep_axis is not None and
+                   _axis_size(cfg.ep_axis) > 1 else None)
+        ep = _axis_size(ep_axis) if ep_axis else 1
+        E_local = p["w_down"].shape[0]
+        E = E_local * ep
+        T = tokens.shape[0]
+        C = max(1, int(cfg.capacity_factor * T / E))
 
-    logits = tokens @ p["router"]
-    dispatch, combine, aux = top1_route(logits, E, C)
-    xs = jnp.einsum("tec,td->ecd", dispatch, tokens)
-    if ep_axis:
-        xs = xs.reshape(ep, E_local, C, D)
-        xs = lax.all_to_all(xs, ep_axis, split_axis=0, concat_axis=2,
-                            tiled=True)
-        xs = xs.reshape(E_local, ep * C, D)
-    else:
-        xs = xs.reshape(E_local, C, D)
-    win = p["w_gate_combined"].astype(jnp.float32)   # (E_local, D, 2F)
-    F = win.shape[-1] // 2
-    hh = jnp.einsum("ecd,edf->ecf", xs, win)
-    act = jax.nn.silu(hh[..., :F]) * hh[..., F:]
-    ys = jnp.einsum("ecf,efd->ecd", act,
-                    p["w_down"].astype(jnp.float32))
-    if ep_axis:
-        ys = ys.reshape(E_local, ep, C, D)
-        ys = lax.all_to_all(ys, ep_axis, split_axis=1, concat_axis=0,
-                            tiled=True)
-        ys = ys.reshape(E, C, D)
-    out = jnp.einsum("tec,ecd->td", combine, ys)
-    # expert hidden F is tp-sharded too: the down-projection contracted
-    # a sharded dim, so this is a partial sum until psum over tensor.
-    out = _maybe_psum(out, cfg.tp_axis)
-    return x + out.reshape(B, L, D).astype(x.dtype), aux
+        logits = tokens @ p["router"]
+        dispatch, combine, aux = top1_route(logits, E, C)
+        xs = jnp.einsum("tec,td->ecd", dispatch, tokens)
+        if ep_axis:
+            xs = xs.reshape(ep, E_local, C, D)
+            xs = lax.all_to_all(xs, ep_axis, split_axis=0, concat_axis=2,
+                                tiled=True)
+            xs = xs.reshape(E_local, ep * C, D)
+        else:
+            xs = xs.reshape(E_local, C, D)
+        win = p["w_gate_combined"].astype(jnp.float32)   # (E_local, D, 2F)
+        F = win.shape[-1] // 2
+        hh = jnp.einsum("ecd,edf->ecf", xs, win)
+        act = jax.nn.silu(hh[..., :F]) * hh[..., F:]
+        ys = jnp.einsum("ecf,efd->ecd", act,
+                        p["w_down"].astype(jnp.float32))
+        if ep_axis:
+            ys = ys.reshape(E_local, ep, C, D)
+            ys = lax.all_to_all(ys, ep_axis, split_axis=1, concat_axis=0,
+                                tiled=True)
+            ys = ys.reshape(E, C, D)
+        out = jnp.einsum("tec,ecd->td", combine, ys)
+        # expert hidden F is tp-sharded too: the down-projection contracted
+        # a sharded dim, so this is a partial sum until psum over tensor.
+        out = _maybe_psum(out, cfg.tp_axis)
+        return x + out.reshape(B, L, D).astype(x.dtype), aux
 
 
 # ---------------------------------------------------------------------------
@@ -400,7 +407,8 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any],
             tokens: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """tokens: (B, L_local) → hidden states (B, L_local, D) and
     summed MoE aux loss. Operates on LOCAL param shards."""
-    x = embed_lookup(cfg, params["embed"], tokens)
+    with device_scope("hvd.embed"):
+        x = embed_lookup(cfg, params["embed"], tokens)
 
     def one_layer(layer_p, x):
         return _layer(cfg, layer_p, x)
@@ -436,7 +444,8 @@ def forward(cfg: TransformerConfig, params: Dict[str, Any],
     if aux_t.vma:
         aux0 = lax.pcast(aux0, tuple(aux_t.vma), to="varying")
     (x, aux), _ = lax.scan(body, (x, aux0), params["layers"])
-    x = rmsnorm(x, params["final_norm"])
+    with device_scope("hvd.head_loss"):
+        x = rmsnorm(x, params["final_norm"])
     return x, aux
 
 
@@ -454,9 +463,10 @@ def loss_fn(cfg: TransformerConfig, params, batch) -> jax.Array:
     """Next-token loss, local mean. batch: dict(tokens (B, L_local),
     targets (B, L_local)); caller pmeans over batch/seq axes."""
     hidden, aux = forward(cfg, params, batch["tokens"])
-    logits = logits_fn(cfg, params, hidden)
-    nll = vocab_parallel_xent(cfg, logits, batch["targets"])
-    loss = jnp.mean(nll) + 0.01 * aux
+    with device_scope("hvd.head_loss"):
+        logits = logits_fn(cfg, params, hidden)
+        nll = vocab_parallel_xent(cfg, logits, batch["targets"])
+        loss = jnp.mean(nll) + 0.01 * aux
     if cfg.sp_axis is not None and _axis_size(cfg.sp_axis) > 1:
         loss = lax.pmean(loss, cfg.sp_axis)
     return loss

@@ -39,6 +39,7 @@ from ..ops.compression import (CompressionSpec, effective_rank,
                                powersgd_reduce, powersgd_wire_elements,
                                resolve_compression, wire_dtype_of)
 from ..ops import compression as _compression
+from ..tracing import bucket_scope, device_scope
 from .mesh import AXIS_ORDER, FSDP_AXIS, batch_axes
 from .sharding import replicated
 
@@ -558,6 +559,12 @@ def _make_bucket_tag(bucket_id: int, raxes: Tuple[str, ...],
         return _primal(xs), None
 
     def bwd(_, cts):
+        # The all-reduce instruction carries its bucket's name into
+        # a profiler trace (tracing.DEVICE_SCOPES).
+        with bucket_scope(bucket_id):
+            return reduce_bucket(cts)
+
+    def reduce_bucket(cts):
         outs: list = [None] * len(cts)
         rflag = jnp.zeros((), jnp.float32)
         flag = None
@@ -701,6 +708,10 @@ def _make_powersgd_tag(bucket_id: int, raxes: Tuple[str, ...],
                 (args[:nleaves], args[nleaves:2 * nleaves]))
 
     def bwd(res, cts):
+        with bucket_scope(bucket_id):
+            return reduce_bucket(res, cts)
+
+    def reduce_bucket(res, cts):
         qs, es = res
         flag = None
         if guard:
@@ -884,6 +895,10 @@ def build_train_step(
         return cnt > n_devices - 0.5
 
     def reduce_grads(grads):
+        with device_scope("hvd.grad_reduce"):
+            return scale_and_vote(grads)
+
+    def scale_and_vote(grads):
         ok = None
         if guard:
             # Local finite-flag over the incoming gradients, then the
@@ -1125,8 +1140,10 @@ def build_train_step(
                                     if a in baxes or a in vma))
 
     def _finish_step(loss, aux, grads, params, opt_state):
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with device_scope("hvd.optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state,
+                                                  params)
+            params = optax.apply_updates(params, updates)
         metrics = {"loss": _replicate_metric(loss)}
         if aux is not None:
             # aux is device-varying; average it so metrics satisfy the

@@ -47,6 +47,7 @@ from __future__ import annotations
 import collections
 import json
 import os
+import re
 import signal
 import sys
 import threading
@@ -242,6 +243,62 @@ class OverlapProbe:
             timeline.span(f"overlap.bucket{b}", "REDUCE", t0, t1,
                           args={"bucket": b, "nbytes": nb})
         return len(spans)
+
+
+# ---------------------------------------------------------------------------
+# device-trace scopes (names on the jit plane's instructions)
+# ---------------------------------------------------------------------------
+# The jit plane runs no host code in a step, so what it can give a
+# profiler trace is names: a `jax.named_scope` becomes part of every
+# instruction's `op_name`, and the device's own timeline then says
+# which layer an instruction belongs to, whether it is the backward
+# pass (`transpose(` in the name) or a recompute under
+# `jax.checkpoint` (`rematted_computation`). Every scope of the program
+# starts with `hvd.` and is listed here; nothing else may.
+
+DEVICE_SCOPES: Dict[str, str] = {
+    "hvd.embed": "token embedding gather, and its scatter-add in backward",
+    "hvd.attn.proj": "attention norm, q/k/v projections, rope, GQA "
+                     "repeat, output projection and its tensor psum",
+    "hvd.attn.core": "attention scores, mask, softmax and PV "
+                     "(full, ring or flash path)",
+    "hvd.ffn": "dense FFN: norm and SwiGLU",
+    "hvd.moe": "MoE FFN: router, dispatch, experts, combine",
+    "hvd.head_loss": "final norm, LM head or classifier, cross-entropy",
+    "hvd.conv": "ResNet convolutions",
+    "hvd.batchnorm": "BatchNorm statistics and normalisation",
+    "hvd.grad_reduce": "gradient scale and guard vote of the "
+                       "monolithic path; hvd.grad_reduce.b<N> is "
+                       "bucket N of plan_overlap (pack, cast, "
+                       "all-reduce, unpack)",
+    "hvd.optimizer": "optimizer update and its application",
+}
+# Part of the persistent compile cache's key (common/compile_cache.py):
+# JAX's own key leaves names out, so a cache filled before a scope was
+# added, renamed or moved hands back executables with the old names.
+# Raise it with every such change.
+DEVICE_SCOPES_VERSION = 1
+_BUCKET_SCOPE = "hvd.grad_reduce.b"
+_BUCKET_SCOPE_NAME = re.compile(re.escape(_BUCKET_SCOPE) + "[0-9]+")
+
+
+def device_scope(name: str):
+    """`jax.named_scope(name)` for a name of `DEVICE_SCOPES`, or for a
+    bucket's `hvd.grad_reduce.b<N>`. Trace-time only: it adds nothing
+    to the lowered program's text and nothing to a step."""
+    import jax
+    if name not in DEVICE_SCOPES and \
+            not _BUCKET_SCOPE_NAME.fullmatch(name):
+        raise ValueError(
+            f"{name!r} is no registered device scope: add it to "
+            "tracing.DEVICE_SCOPES (known: "
+            f"{', '.join(DEVICE_SCOPES)}, {_BUCKET_SCOPE}<N>)")
+    return jax.named_scope(name)
+
+
+def bucket_scope(bucket_id: int):
+    """The scope of one `plan_overlap` bucket's reduction."""
+    return device_scope(f"{_BUCKET_SCOPE}{int(bucket_id)}")
 
 
 # ---------------------------------------------------------------------------
