@@ -640,12 +640,10 @@ def transformer_main():
 
     opt = optax.adamw(1e-4)
     opt_state = opt.init(params)
-    from horovod_tpu.parallel.ring_attention import flash_possible_cfg
-    flash_possible = flash_possible_cfg(cfg.head_dim, seq)
     step = build_train_step(
         lambda p, b: tfm.loss_fn(cfg, p, b), opt, mesh,
         batch_spec={"tokens": P("data"), "targets": P("data")},
-        donate=True, check_vma=not flash_possible)
+        donate=True)
 
     rng = np.random.default_rng(0)
     tokens = jnp.asarray(

@@ -180,15 +180,13 @@ def flagship_step(shape: dict, mesh):
     from jax.sharding import PartitionSpec as P
     from horovod_tpu.models import transformer as tfm
     from horovod_tpu.parallel import build_train_step
-    from horovod_tpu.parallel.ring_attention import flash_possible_cfg
 
     cfg = flagship_config(shape)
     opt = optax.adamw(1e-4)
     step = build_train_step(
         lambda p, b: tfm.loss_fn(cfg, p, b), opt, mesh,
         batch_spec={"tokens": P("data"), "targets": P("data")},
-        donate=True,
-        check_vma=not flash_possible_cfg(cfg.head_dim, shape["seq"]))
+        donate=True)
     return cfg, opt, step
 
 

@@ -30,7 +30,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horovod_tpu.parallel import MeshSpec, build_mesh
-from horovod_tpu.parallel.ring_attention import attention, ring_attention
+from horovod_tpu.parallel.ring_attention import (dense_attention,
+                                                 ring_attention)
 
 
 def main():
@@ -77,9 +78,9 @@ def main():
           f"({args.batch * L} tokens, causal)")
 
     if args.verify:
-        full = attention(jnp.asarray(jax.device_get(q)),
-                         jnp.asarray(jax.device_get(k)),
-                         jnp.asarray(jax.device_get(v)))
+        full = dense_attention(jnp.asarray(jax.device_get(q)),
+                               jnp.asarray(jax.device_get(k)),
+                               jnp.asarray(jax.device_get(v)))
         err = float(jnp.max(jnp.abs(jnp.asarray(jax.device_get(out))
                                     - full)))
         print(f"max |ring - full| = {err:.2e}")

@@ -730,11 +730,17 @@ KNOBS: List[Knob] = [
          "Override for TPU_CHIPS_PER_PROCESS_BOUNDS exported to "
          "workers. Empty = '1,1,1' (one chip per process)."),
     # -- attention kernels ---------------------------------------------------
-    Knob("HOROVOD_FLASH_ATTENTION", str, "0",
-         "Pallas flash-attention kernel inside ring attention: '1' "
-         "forces it, 'auto' tries it for supported shapes, '0' "
-         "(default) keeps the jnp path (measured SLOWER inside the "
-         "remat'd layer scan — see docs/benchmarks.md)."),
+    Knob("HOROVOD_FLASH_ATTENTION", str, "auto",
+         "Override of attention()'s path rule "
+         "(parallel/ring_attention.py): 'auto' (default) runs the "
+         "fused Pallas kernels (parallel/fused_attention.py) where "
+         "the call allows it (TPU, causal, seq in 128-blocks, "
+         "head_dim a multiple of 128, no live seq axis) and the dense "
+         "path elsewhere; '0' keeps the dense path everywhere; '1' "
+         "takes the fused path or raises. The rounds 4-5 rejects in "
+         "docs/benchmarks.md were of JAX's stock kernel at its "
+         "128-block default on the flagship model; PERF.md (PR 30) "
+         "has the block-tuned kernel on the Mistral cells."),
 ]
 
 _KNOBS_BY_ENV: Dict[str, Knob] = {k.env: k for k in KNOBS}

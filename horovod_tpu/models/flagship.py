@@ -106,20 +106,11 @@ def make_flagship(mesh: Mesh,
     def local_loss(params, batch):
         return tfm.loss_fn(cfg, params, batch)
 
-    # Flash attention (HOROVOD_FLASH_ATTENTION, resolved in
-    # parallel/ring_attention.py) is a Pallas kernel that cannot
-    # declare vma types; turn the replication checker off only when
-    # the path can actually engage for THIS config's shapes.
-    from ..parallel.ring_attention import flash_possible_cfg
-    flash_possible = flash_possible_cfg(
-        cfg.head_dim, cfg.max_seq,
-        sp_live=cfg.sp_axis is not None)
     step = build_train_step(
         local_loss, optimizer, mesh,
         batch_spec=batch_spec(mesh),
         param_specs=p_specs,
         opt_state_specs=opt_specs,
-        check_vma=not flash_possible,
     )
     return cfg, params, opt_state, step
 

@@ -63,11 +63,12 @@ class TransformerConfig:
     # remat_mode="full": the whole layer recomputes in backward.
     # "mlp_only": only the FFN sub-block remats (its d_ff temporaries
     # are the memory hog; its recompute is cheap dots) while the
-    # attention sub-block SAVES its residuals — with
-    # HOROVOD_FLASH_ATTENTION this is what keeps the Pallas kernel's
-    # forward from re-running inside backward (the custom VJP's saved
-    # lse/outputs survive), the round-4 flash measured-reject's
-    # diagnosed cause. Costs ~4x B*L*D extra bytes per layer.
+    # attention sub-block SAVES its residuals, so the fused attention
+    # kernel's forward does not run again inside backward (round 5
+    # built it for JAX's stock flash kernel at 128-blocks on the
+    # flagship model, where it did not rescue that kernel; no cell
+    # runs it, see PERF.md PR 30). Costs ~4x B*L*D extra bytes per
+    # layer.
     remat_mode: str = "full"
     # Live mesh axis names (None → that strategy is off). The model is
     # written once; trivial axes cost nothing.
@@ -251,14 +252,14 @@ def _attention_block(cfg: TransformerConfig, p: Dict[str, jax.Array],
         q = _rope(q, positions, cfg.rope_theta)
         kk = _rope(kk, positions, cfg.rope_theta)
 
-        # GQA: repeat kv heads to match local q heads.
-        reps = q.shape[2] // kk.shape[2]
-        if reps > 1:
-            kk = jnp.repeat(kk, reps, axis=2)
-            v = jnp.repeat(v, reps, axis=2)
-
     with device_scope("hvd.attn.core"):
         if cfg.sp_axis is not None and _axis_size(cfg.sp_axis) > 1:
+            # GQA: the ring takes kv heads repeated to the local q
+            # heads; full_attention takes them as they are.
+            reps = q.shape[2] // kk.shape[2]
+            if reps > 1:
+                kk = jnp.repeat(kk, reps, axis=2)
+                v = jnp.repeat(v, reps, axis=2)
             o = ring_attention(q, kk, v, cfg.sp_axis, causal=True)
         else:
             o = full_attention(q, kk, v, causal=True)

@@ -16,7 +16,8 @@ from .sharding import (  # noqa: F401
 )
 from .train import build_gspmd_train_step, build_train_step  # noqa: F401
 from .fsdp import zero3_param_shardings, zero3_spec  # noqa: F401
-from .ring_attention import attention, ring_attention  # noqa: F401
+from .ring_attention import (  # noqa: F401
+    attention, dense_attention, ring_attention)
 from .ulysses import (  # noqa: F401
     gather_heads, scatter_heads, ulysses_attention,
 )

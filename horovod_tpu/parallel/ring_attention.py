@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..metrics import REGISTRY as _METRICS
 from .mesh import SEQ_AXIS
 
 
@@ -107,82 +108,84 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return _ring_body(q, k, v, axis_name, causal, float(scale))
 
 
-# Single-device flash-attention path (HOROVOD_FLASH_ATTENTION):
-# Pallas fused kernel instead of materializing the (B,H,L,L) f32
-# score matrix in HBM. Default OFF: standalone the kernel measures
-# 2.65x faster fwd+bwd at seq 2048 on v5e, but INSIDE the remat'd
-# layer scan it measured 27-37% SLOWER end-to-end (the checkpoint
-# policy recomputes the kernel's forward and it serializes against
-# XLA's fused pipeline) — see docs/benchmarks.md measured-reject
-# note. "1" forces it (and requires check_vma=False on the enclosing
-# shard_map — build_train_step threads this); "auto" tries it for
-# supported shapes and falls back silently. Read at trace time, like
-# the Adasum Pallas switch.
+# Attention without a live sequence axis has two paths, and attention()
+# picks by what it observes (`_flash_supported`): on a TPU, a causal
+# self-attention call whose shapes the kernels of fused_attention.py
+# take runs fused (no f32 (B, H, L, L) scores in HBM, no masked half);
+# everything else, and every backend but the TPU, runs
+# dense_attention. The kernels declare their outputs' varying-axes
+# types, so shard_map's replication checker stays on around them.
+#
+# HOROVOD_FLASH_ATTENTION overrides the rule: "auto" (default) is the
+# rule, "0" keeps the dense path everywhere, "1" takes the fused path
+# or raises where the shapes do not allow it. Read at trace time, like
+# the Adasum Pallas switch. History: rounds 4 and 5 measured JAX's
+# stock Pallas flash kernel at its default 128-blocks on the flagship
+# model (heads of 64, seq 512) and found it 27-37 % slower inside the
+# remat'd layer scan; docs/benchmarks.md has those notes and PERF.md
+# (PR 30) what the block-tuned kernel measures on the Mistral cells.
 def _flash_mode() -> str:
     from ..common.config import env_value
     v = str(env_value("HOROVOD_FLASH_ATTENTION")).lower()
     v = {"true": "1", "yes": "1", "false": "0", "no": "0",
-         "": "0"}.get(v, v)
+         "": "auto"}.get(v, v)
     if v not in ("0", "1", "auto"):
         raise ValueError(
             f"HOROVOD_FLASH_ATTENTION must be 0/1/auto, got {v!r}")
     return v
 
 
-def flash_wanted() -> bool:
-    """The knob+backend half of the engagement predicate — what the
-    train-step builders consult to decide check_vma (the Pallas
-    kernel cannot declare vma types, so the replication checker must
-    be off wherever flash could trace)."""
-    return _flash_mode() in ("1", "auto") and \
-        jax.default_backend() == "tpu"
-
-
 def flash_possible_cfg(head_dim: int, seq: int,
                        sp_live: bool = False) -> bool:
-    """Static-config half of the predicate, for builders that know
-    the model config but not the runtime tensors: same shape rules as
-    _flash_supported. GQA needs no condition — callers repeat KV
-    heads to full width before attention(), so the kernel always
-    sees k.shape == q.shape. With a live sequence-parallel axis the
-    ring path runs instead and flash never traces. Builders keep
-    check_vma ON when this is False — flash can never engage, so the
-    checker loses nothing."""
-    return (flash_wanted() and head_dim in (64, 128, 256)
-            and seq % 128 == 0 and not sp_live)
+    """Whether a train-step builder must turn shard_map's replication
+    checker off (`check_vma=not flash_possible_cfg(...)`) because a
+    kernel that cannot type its outputs may trace in the model's
+    attention. Never, since the fused kernels declare the varying
+    axes of their outputs as those of q / k / v: the checker stays on
+    for every config. Kept for the builders that ask
+    (perfbench/models/transformer.py)."""
+    del head_dim, seq, sp_live
+    return False
 
 
-def _flash_supported(q, k) -> bool:
-    B, L, H, D = q.shape
-    return (jax.default_backend() == "tpu"
-            and k.shape == q.shape
-            and L % 128 == 0 and D in (64, 128, 256))
+def _flash_supported(q, k, v, causal: bool) -> bool:
+    """The engagement rule, on what the call observes: TPU backend,
+    causal, shapes the kernels take (self-attention, L in 128-blocks,
+    head_dim a whole number of lanes, heads in whole groups), bf16
+    operands (what the chip has measured; f32 callers keep the dense
+    path and its matmul precision), and no live sequence-parallel
+    axis on q (ring / Ulysses callers keep the path they were tested
+    on)."""
+    from . import fused_attention
+    return (jax.default_backend() == "tpu" and causal
+            and q.dtype == k.dtype == v.dtype == jnp.bfloat16
+            and fused_attention.supported(q.shape, k.shape, v.shape)
+            and SEQ_AXIS not in jax.typeof(q).vma)
 
 
 def flash_attention_path(q, k, v, causal: bool, scale: float):
-    """(B, L, H, D) in/out wrapper over the Pallas TPU flash kernel
-    (jax.experimental.pallas.ops.tpu.flash_attention — fused online-
-    softmax, custom VJP for the backward kernels)."""
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        flash_attention as _fa)
-    qt = jnp.swapaxes(q, 1, 2)          # (B, H, L, D)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    o = _fa(qt, kt, vt, causal=causal, sm_scale=scale)
-    return jnp.swapaxes(o, 1, 2).astype(q.dtype)
+    """The fused path: (B, L, H, D) in and out, k / v with H or fewer
+    (grouped) heads. Causal only."""
+    from .fused_attention import fused_causal_attention
+    if not causal:
+        raise ValueError("the fused attention kernels are causal only")
+    return fused_causal_attention(q, k, v, scale)
 
 
-def attention(q: jax.Array, k: jax.Array, v: jax.Array,
-              causal: bool = True,
-              scale: Optional[float] = None) -> jax.Array:
-    """Single-device reference attention with the same (B, L, H, D)
-    layout — the correctness oracle for ring_attention tests and the
-    path used when the mesh has no live seq axis."""
+def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    causal: bool = True,
+                    scale: Optional[float] = None) -> jax.Array:
+    """Plain attention, (B, L, H, D) in and out: f32 scores, mask,
+    softmax, PV, all materialised. The oracle of the ring-attention
+    and fused-kernel tests, and attention()'s path wherever the fused
+    kernels do not engage. k / v may carry fewer heads than q
+    (grouped-query): each is repeated over its group."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
-    mode = _flash_mode()
-    if mode == "1" or (mode == "auto" and _flash_supported(q, k)):
-        return flash_attention_path(q, k, v, causal, float(scale))
+    reps = q.shape[2] // k.shape[2]
+    if reps > 1:
+        k = jnp.repeat(k, reps, axis=2)
+        v = jnp.repeat(v, reps, axis=2)
     scores = _blockwise_scores(q.astype(jnp.float32),
                                k.astype(jnp.float32), float(scale))
     if causal:
@@ -192,3 +195,27 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+_m_traces = _METRICS.counter(
+    "hvd_attention_traces_total",
+    "Times attention() was traced, by the path it took: fused (the "
+    "Pallas kernels of parallel/fused_attention.py) or dense.",
+    ("path",))
+
+
+def attention(q: jax.Array, k: jax.Array, v: jax.Array,
+              causal: bool = True,
+              scale: Optional[float] = None) -> jax.Array:
+    """Attention on one device's (B, L, H, D) blocks, for a mesh with
+    no live sequence axis: fused where `_flash_supported` says so,
+    else `dense_attention`. k / v may carry fewer (grouped) heads."""
+    mode = _flash_mode()
+    fused = mode == "1" or (mode == "auto"
+                            and _flash_supported(q, k, v, causal))
+    _m_traces.labels(path="fused" if fused else "dense").inc()
+    if fused:
+        return flash_attention_path(
+            q, k, v, causal,
+            float(q.shape[-1] ** -0.5 if scale is None else scale))
+    return dense_attention(q, k, v, causal, scale)

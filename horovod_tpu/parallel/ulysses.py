@@ -9,8 +9,9 @@ blocks). Here the full pattern is provided natively:
                      → all_to_all → sharded-by-heads, full sequence
   after attention:   inverse swap.
 
-Each device then runs *ordinary* (flash) attention on a head slice of
-the full sequence — no ring, one collective each way. Requires
+Each device then runs *ordinary* attention (`attention()`, which keeps
+its dense path under a live sequence axis) on a head slice of the full
+sequence — no ring, one collective each way. Requires
 heads % sp == 0; complements ring attention (which has no such
 constraint and overlaps comm with compute).
 """

@@ -258,10 +258,12 @@ class OverlapProbe:
 
 DEVICE_SCOPES: Dict[str, str] = {
     "hvd.embed": "token embedding gather, and its scatter-add in backward",
-    "hvd.attn.proj": "attention norm, q/k/v projections, rope, GQA "
-                     "repeat, output projection and its tensor psum",
-    "hvd.attn.core": "attention scores, mask, softmax and PV "
-                     "(full, ring or flash path)",
+    "hvd.attn.proj": "attention norm, q/k/v projections, rope, "
+                     "output projection and its tensor psum",
+    "hvd.attn.core": "attention scores, mask, softmax and PV: the "
+                     "fused kernels (forward, dQ, dK/dV), the dense "
+                     "path or the ring, with the GQA repeat of K / V "
+                     "where the path needs one",
     "hvd.ffn": "dense FFN: norm and SwiGLU",
     "hvd.moe": "MoE FFN: router, dispatch, experts, combine",
     "hvd.head_loss": "final norm, LM head or classifier, cross-entropy",

@@ -67,21 +67,38 @@ def test_adasum_pair_combine_compiles_to_custom_call(topo, n, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_flash_attention_forward_and_backward_compile(topo):
+@pytest.mark.parametrize("shape", [(2, 2048, 32, 8, 128),
+                                   (1, 256, 32, 8, 128),
+                                   (2, 2048, 32, 32, 128),
+                                   (1, 2048, 8, 2, 256),
+                                   (1, 640, 16, 2, 128)],
+                         ids=["mistral-window", "mistral-sample", "mha",
+                              "head256", "group8-seq640"])
+def test_flash_attention_forward_and_backward_compile(topo, shape):
+    """The fused kernels at the Mistral cells' shapes and the blocks
+    the rule gives them (512 at seq 2048, 256 at the seq-256 sample):
+    one custom call forward, three with backward, and no
+    (B, H, L, L) tensor left in the program."""
+    from horovod_tpu.parallel import fused_attention
     from horovod_tpu.parallel.ring_attention import flash_attention_path
-    q = jax.ShapeDtypeStruct((16, 512, 16, 64), jnp.bfloat16,
-                             sharding=SingleDeviceSharding(topo.devices[0]))
+    B, L, H, Hkv, D = shape
+    assert fused_attention.block_size(L) == {2048: 512, 256: 256,
+                                             640: 128}[L]
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((B, L, H, D), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((B, L, Hkv, D), jnp.bfloat16, sharding=one)
 
     def fwd(q, k, v):
-        return flash_attention_path(q, k, v, True, 64 ** -0.5)
+        return flash_attention_path(q, k, v, True, D ** -0.5)
 
     def bwd(q, k, v):
         return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    for fn in (fwd, bwd):
-        hlo = jax.jit(fn).lower(q, q, q).compile().as_text()
-        assert "tpu_custom_call" in hlo
+    for fn, calls in ((fwd, 1), (bwd, 3)):
+        hlo = jax.jit(fn).lower(q, k, k).compile().as_text()
+        assert hlo.count('custom_call_target="tpu_custom_call"') == calls
+        assert f"[{B},{H},{L},{L}]" not in hlo
 
 
 def _flagship_compiled(devices, global_batch):

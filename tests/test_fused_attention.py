@@ -1,0 +1,248 @@
+"""The fused causal attention kernels (parallel/fused_attention.py) in
+Pallas's interpreter against `dense_attention`, and the rule by which
+`attention()` picks its path. On the CPU the rule never engages by
+itself: a test that needs the TPU branch patches
+`jax.default_backend`, which only steers tracing (nothing is lowered)."""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
+
+from horovod_tpu.metrics import REGISTRY
+from horovod_tpu.parallel import fused_attention as fa
+from horovod_tpu.parallel.ring_attention import (attention,
+                                                 dense_attention,
+                                                 flash_possible_cfg)
+
+# `horovod_tpu.parallel.ring_attention` the attribute is the function.
+ra = importlib.import_module("horovod_tpu.parallel.ring_attention")
+
+
+def _qkv(B, L, H, Hkv, D, dtype=jnp.float32, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (B, L, H, D), dtype),
+            jax.random.normal(ks[1], (B, L, Hkv, D), dtype),
+            jax.random.normal(ks[2], (B, L, Hkv, D), dtype),
+            jax.random.normal(ks[3], (B, L, H, D), jnp.float32))
+
+
+def _engages(q, k, causal=True):
+    """`_flash_supported` as a trace sees it, for shapes."""
+    seen = []
+    jax.eval_shape(
+        lambda q, k: seen.append(ra._flash_supported(q, k, k, causal)),
+        q, k)
+    return seen[0]
+
+
+def _fused(q, k, v):
+    return fa.fused_causal_attention(q, k, v, q.shape[-1] ** -0.5,
+                                     interpret=True)
+
+
+def _loss(f, w):
+    return lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) * w)
+
+
+# (B, L, H, Hkv, D): the issue's (1, 4, 256, 128) in (B, H, L, D)
+# terms, a grouped-query case, and two that cross block borders (two
+# 512-blocks; five 128-blocks), where the masked blocks are skipped.
+# The fifth has a group of 8 q heads, which takes two grid steps of 4.
+SHAPES = [(1, 256, 4, 4, 128), (1, 256, 4, 2, 128),
+          (1, 1024, 2, 1, 128), (2, 640, 2, 2, 128),
+          (1, 256, 8, 1, 128)]
+
+
+@pytest.mark.parametrize("shape", SHAPES,
+                         ids=["mha256", "gqa256", "gqa1024", "mha640",
+                              "mqa256"])
+def test_fused_matches_dense_forward_and_gradients(shape):
+    q, k, v, w = _qkv(*shape)
+    np.testing.assert_allclose(
+        np.asarray(_fused(q, k, v)),
+        np.asarray(dense_attention(q, k, v, causal=True)),
+        rtol=2e-5, atol=2e-5)
+    got = jax.grad(_loss(_fused, w), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(_loss(dense_attention, w), argnums=(0, 1, 2))(q, k, v)
+    for g, o, name in zip(got, want, "qkv"):
+        assert g.shape == o.shape and g.dtype == o.dtype
+        np.testing.assert_allclose(np.asarray(g), np.asarray(o),
+                                   rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+def test_fused_bf16_within_rounding_of_dense():
+    q, k, v, w = _qkv(1, 256, 4, 2, 128, jnp.bfloat16)
+    out = _fused(q, k, v)
+    assert out.dtype == jnp.bfloat16
+    ref = dense_attention(q, k, v, causal=True)
+    assert float(jnp.max(jnp.abs(out.astype(jnp.float32)
+                                 - ref.astype(jnp.float32)))) < 0.05
+
+
+def test_fused_under_shard_map_with_the_replication_checker_on():
+    """Outputs are typed varying as q is: the kernels trace under
+    check_vma=True, and gradients of replicated inputs still arrive
+    summed over the data axis."""
+    mesh = Mesh(np.array(jax.devices()[:2]), axis_names=("data",))
+    q, k, v, w = _qkv(2, 256, 2, 1, 128)
+
+    def local(q, k, v, w):
+        def loss(q, k, v):
+            return _loss(_fused, w)(q, k, v)
+        l, g = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return jax.lax.psum(l, "data"), g
+
+    f = jax.jit(shard_map(local, mesh=mesh, in_specs=(P("data"),) * 4,
+                          out_specs=(P(), (P("data"),) * 3),
+                          check_vma=True))
+    l, g = f(q, k, v, w)
+    want_l, want_g = jax.value_and_grad(
+        _loss(dense_attention, w), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(l), float(want_l), rtol=1e-5)
+    for a, b in zip(g, want_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_fused_refuses_shapes_it_does_not_take():
+    q, k, v, _ = _qkv(1, 200, 2, 2, 128)
+    with pytest.raises(ValueError, match="128-blocks"):
+        fa.fused_causal_attention(q, k, v, 1.0, interpret=True)
+
+
+@pytest.mark.parametrize("seq,block", [
+    (2048, 512), (1024, 512), (256, 256), (128, 128), (384, 384),
+    (640, 128), (1536, 512), (200, 0), (64, 0)])
+def test_block_rule(seq, block):
+    assert fa.block_size(seq) == block
+
+
+@pytest.mark.parametrize("group,heads", [
+    (1, 1), (2, 2), (4, 4), (8, 4), (6, 3), (7, 1), (32, 4)])
+def test_heads_per_step_rule(group, heads):
+    assert fa.heads_per_step(group) == heads
+
+
+# backend, (L, H, Hkv, D), kv length, causal, engages
+RULE = [
+    ("tpu", (2048, 32, 8, 128), None, True, True),
+    ("tpu", (256, 32, 8, 128), None, True, True),     # the cells' sample
+    ("tpu", (256, 32, 32, 128), None, True, True),
+    ("tpu", (256, 4, 4, 256), None, True, True),
+    ("tpu", (200, 32, 8, 128), None, True, False),    # not in 128-blocks
+    ("tpu", (512, 16, 16, 64), None, True, False),    # the flagship's heads
+    ("tpu", (256, 6, 4, 128), None, True, False),     # no whole groups
+    ("tpu", (256, 32, 8, 128), 512, True, False),     # cross-attention
+    ("tpu", (256, 32, 8, 128), None, False, False),   # not causal
+    ("cpu", (2048, 32, 8, 128), None, True, False),
+    ("gpu", (256, 32, 8, 128), None, True, False),
+]
+
+
+@pytest.mark.parametrize("backend,shape,kv_len,causal,engages", RULE)
+def test_engagement_rule(monkeypatch, backend, shape, kv_len, causal,
+                         engages):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    L, H, Hkv, D = shape
+    q = jax.ShapeDtypeStruct((1, L, H, D), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, kv_len or L, Hkv, D), jnp.bfloat16)
+    assert _engages(q, k, causal) is engages
+
+
+@pytest.mark.parametrize("dtype,engages", [
+    (jnp.bfloat16, True), (jnp.float32, False), (jnp.float16, False)])
+def test_engagement_rule_dtype(monkeypatch, dtype, engages):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    q = jax.ShapeDtypeStruct((1, 256, 4, 128), dtype)
+    assert _engages(q, q) is engages
+
+
+@pytest.mark.parametrize("axis,engages", [("data", True), ("seq", False)])
+def test_engagement_rule_live_axis(monkeypatch, axis, engages):
+    """A live sequence-parallel axis on q (Ulysses after its
+    all-to-all) keeps the dense path; a data axis does not."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.array(jax.devices()[:2]), axis_names=(axis,))
+    seen = []
+
+    def local(q):
+        seen.append(ra._flash_supported(q, q, q, True))
+        return q
+
+    q = jax.ShapeDtypeStruct((2, 256, 4, 128), jnp.bfloat16)
+    jax.eval_shape(shard_map(local, mesh=mesh, in_specs=P(axis),
+                             out_specs=P(axis)), q)
+    assert seen == [engages]
+
+
+@pytest.mark.parametrize("seq", [2048, 256])
+@pytest.mark.parametrize("sp_live", [False, True])
+def test_flash_possible_cfg_keeps_the_checker_on(monkeypatch, seq, sp_live):
+    """What perfbench's builder asks before `build_train_step`: no
+    config needs check_vma off, on the TPU or off it."""
+    for backend in ("tpu", "cpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert flash_possible_cfg(128, seq, sp_live=sp_live) is False
+
+
+def _trace_attention(*shapes, **kw):
+    """One fresh trace of attention() (eval_shape caches by function)."""
+    return jax.eval_shape(lambda *a: attention(*a, **kw), *shapes)
+
+
+def _traces():
+    snap = REGISTRY.snapshot().get("hvd_attention_traces_total", {})
+    return {path: snap.get((path,), 0.0) for path in ("fused", "dense")}
+
+
+def test_path_counter_counts_each_trace(monkeypatch):
+    q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+    before = _traces()
+    out = _trace_attention(q, k, k)            # the CPU: dense
+    assert out.shape == q.shape
+    mid = _traces()
+    assert (mid["dense"], mid["fused"]) == (before["dense"] + 1,
+                                            before["fused"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    out = _trace_attention(q, k, k)
+    assert out.shape == q.shape and out.dtype == q.dtype
+    after = _traces()
+    assert (after["dense"], after["fused"]) == (mid["dense"],
+                                                mid["fused"] + 1)
+
+
+@pytest.mark.parametrize("mode,backend,path", [
+    ("0", "tpu", "dense"), ("auto", "tpu", "fused"), ("", "tpu", "fused"),
+    ("1", "cpu", "fused"), ("auto", "cpu", "dense")])
+def test_knob_overrides_the_rule(monkeypatch, mode, backend, path):
+    monkeypatch.setenv("HOROVOD_FLASH_ATTENTION", mode)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
+    before = _traces()
+    _trace_attention(q, q, q)
+    after = _traces()
+    assert after[path] == before[path] + 1
+
+
+def test_knob_forced_on_raises_on_unsupported_shapes(monkeypatch):
+    monkeypatch.setenv("HOROVOD_FLASH_ATTENTION", "1")
+    q = jax.ShapeDtypeStruct((1, 200, 4, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="128-blocks"):
+        _trace_attention(q, q, q)
+    with pytest.raises(ValueError, match="causal only"):
+        q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
+        _trace_attention(q, q, q, causal=False)
+
+
+def test_attention_takes_grouped_kv_on_the_dense_path():
+    q, k, v, _ = _qkv(1, 64, 4, 2, 16)
+    rep = lambda x: jnp.repeat(x, 2, axis=2)  # noqa: E731
+    np.testing.assert_allclose(
+        np.asarray(attention(q, k, v)),
+        np.asarray(dense_attention(q, rep(k), rep(v))), rtol=1e-6)

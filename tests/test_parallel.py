@@ -11,7 +11,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from horovod_tpu.parallel import (
-    MeshSpec, attention, build_mesh, build_train_step, moe_ffn,
+    MeshSpec, build_mesh, build_train_step, dense_attention, moe_ffn,
     pipeline_apply, ring_attention, stack_stage_params,
     ulysses_attention,
 )
@@ -61,7 +61,7 @@ class TestRingAttention:
         k = jax.random.normal(kk, (B, L, H, D), jnp.float32)
         v = jax.random.normal(kv, (B, L, H, D), jnp.float32)
 
-        oracle = attention(q, k, v, causal=causal)
+        oracle = dense_attention(q, k, v, causal=causal)
 
         mesh = seq_mesh(4)
         ring = jax.jit(shard_map(
@@ -87,7 +87,7 @@ class TestRingAttention:
             return jnp.sum(f(q, k, v) ** 2)
 
         def loss_full(q, k, v):
-            return jnp.sum(attention(q, k, v, causal=True) ** 2)
+            return jnp.sum(dense_attention(q, k, v, causal=True) ** 2)
 
         g1 = jax.grad(loss_ring)(q, k, v)
         g2 = jax.grad(loss_full)(q, k, v)
@@ -101,7 +101,7 @@ class TestUlysses:
         key = jax.random.PRNGKey(2)
         q, k, v = (jax.random.normal(kk, (B, L, H, D))
                    for kk in jax.random.split(key, 3))
-        oracle = attention(q, k, v, causal=True)
+        oracle = dense_attention(q, k, v, causal=True)
         mesh = seq_mesh(4)
         f = jax.jit(shard_map(
             lambda q, k, v: ulysses_attention(q, k, v, "seq"),
