@@ -17,7 +17,22 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..metrics import REGISTRY as _METRICS
 from .mesh import EXPERT_AXIS
+
+
+_m_moe_traces = _METRICS.counter(
+    "hvd_moe_traces_total",
+    "Times an expert layer was traced, by the dispatch it took: "
+    "sorted_grouped_kernels (expert_share_ffn: pairs sorted by "
+    "expert, gathered, the Pallas grouped matmuls), sorted_ragged "
+    "(the same with lax.ragged_dot, off the TPU) or onehot_capacity "
+    "(top1_route's (T, E, C) einsum).", ("dispatch",))
+_m_moe_bound = _METRICS.gauge(
+    "hvd_moe_pairs_bound",
+    "Rows of the buffer the last traced expert_share_ffn gathers its "
+    "(token, expert) pairs into: a static bound, at most tokens x "
+    "min(k, experts held), which no batch can exceed.")
 
 
 def top1_route(logits: jax.Array, n_experts: int, capacity: int
@@ -26,6 +41,7 @@ def top1_route(logits: jax.Array, n_experts: int, capacity: int
 
     logits: (T, E). Returns (dispatch (T, E, C) one-hot, combine
     (T, E, C) weights, aux_loss scalar)."""
+    _m_moe_traces.labels(dispatch="onehot_capacity").inc()
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     expert = jnp.argmax(probs, axis=-1)                       # (T,)
     onehot = jax.nn.one_hot(expert, n_experts, dtype=jnp.float32)
@@ -88,3 +104,186 @@ def moe_ffn(tokens: jax.Array, router_w: jax.Array, w_in: jax.Array,
 
     out = jnp.einsum("tec,ecd->td", combine, ys)
     return out.astype(tokens.dtype), aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless top-k routing for one chip's share of the experts
+# ---------------------------------------------------------------------------
+#
+# The layer is told which experts it holds (`first`, and as many as its
+# weights stack) and how wide the router is. It scores every expert,
+# chooses k a token, and computes the chosen (token, expert) pairs
+# whose expert it holds: pairs sorted by expert, their rows gathered
+# into one buffer of a static bound, grouped matmuls over it
+# (`grouped_matmul.py`: on the TPU kernels that compute only the row
+# tiles that hold rows), gathered back with the gates.
+# No (T, E, C) one-hot and no capacity: a pair is left out only if
+# the buffer's bound is set below what a batch can send, and then it
+# is counted, never silent. With a live `expert` axis the rows would
+# be exchanged between the chips first; that exchange is not written
+# yet (ROADMAP B7), and nothing here stands in for it.
+
+def topk_sigmoid_route(logits: jax.Array, bias: jax.Array, k: int,
+                       scale: float) -> Tuple[jax.Array, jax.Array]:
+    """Sigmoid scores, the k experts with the largest score + bias a
+    token, and their gates: the chosen scores (without the bias)
+    normalised to sum 1 and multiplied by `scale` (DeepSeek-V3's
+    `noaux_tc` with one group). logits: (T, E) float32, bias: (E,),
+    which only moves the choice and gets no gradient. Returns
+    (experts (T, k) int32, gates (T, k) float32)."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, experts = lax.top_k(scores + lax.stop_gradient(bias), k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), gates * scale
+
+
+def max_pairs(tokens: int, k: int, held: int) -> int:
+    """The most (token, expert) pairs that can land on `held` experts:
+    a token chooses k different experts."""
+    return tokens * min(k, held)
+
+
+# Dispatch and combine are gathers of whole rows in both directions:
+# pair p = (token t, choice j) sits in buffer row slots[t, j] where
+# landed[t, j], and buffer row s holds pair order[s] where valid[s].
+# Left to autodiff, each gather's transpose is a scatter-add of tens
+# of thousands of rows, which the TPU serialises (PERF.md, PR 31: 4.6
+# ms a layer each); written out, the transposes are gathers too. One
+# gather a choice j, T rows each: a (T, k, D) intermediate would be
+# tiled with k on the sublanes and copied to be summed.
+
+def _choice_rows(x, slots, landed, j):
+    """x[slots[:, j]] in float32, zero where choice j did not land."""
+    return jnp.where(landed[:, j, None], x[slots[:, j]], 0).astype(
+        jnp.float32)
+
+
+@jax.custom_vjp
+def _permute(tokens, order, slots, landed):
+    """tokens (T, D) -> the dispatch buffer (rows, D): row s holds the
+    token of pair order[s]. Rows that hold no pair hold some token's
+    row too: finite, and nothing downstream reads what comes of
+    them."""
+    return tokens[order // slots.shape[1]]
+
+
+def _permute_fwd(tokens, order, slots, landed):
+    return _permute(tokens, order, slots, landed), (slots, landed)
+
+
+def _permute_bwd(res, d_xs):
+    slots, landed = res
+    d_tokens = sum(_choice_rows(d_xs, slots, landed, j)
+                   for j in range(slots.shape[1]))
+    return d_tokens.astype(d_xs.dtype), None, None, None
+
+
+_permute.defvjp(_permute_fwd, _permute_bwd)
+
+
+@jax.custom_vjp
+def _unpermute(ys, gates, order, slots, valid, landed):
+    """The gated sum back at the tokens, float32: out[t] = sum over the
+    landed choices j of gates[t, j] * ys[slots[t, j]]. ys: (rows, D),
+    gates: (T, k). Only rows that hold a pair are read."""
+    return sum(gates[:, j, None] * _choice_rows(ys, slots, landed, j)
+               for j in range(slots.shape[1]))
+
+
+def _unpermute_fwd(ys, gates, order, slots, valid, landed):
+    return (_unpermute(ys, gates, order, slots, valid, landed),
+            (ys, gates, order, slots, valid, landed))
+
+
+def _unpermute_bwd(res, d_out):
+    """d_ys is exactly zero in every row that holds no pair: that is
+    what keeps such rows out of the weights' gradients."""
+    ys, gates, order, slots, valid, landed = res
+    k = gates.shape[1]
+    row_gate = jnp.where(valid, gates.reshape(-1)[order], 0.0)
+    d_ys = d_out.astype(ys.dtype)[order // k].astype(jnp.float32) \
+        * row_gate[:, None]
+    d_gates = jnp.stack(
+        [jnp.sum(_choice_rows(ys, slots, landed, j) * d_out, axis=-1)
+         for j in range(k)], axis=1)
+    return d_ys.astype(ys.dtype), d_gates, None, None, None, None
+
+
+_unpermute.defvjp(_unpermute_fwd, _unpermute_bwd)
+
+
+def expert_share_ffn(tokens: jax.Array, experts: jax.Array,
+                     gates: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+                     w_down: jax.Array, first: int) -> jax.Array:
+    """The held experts' part of sum_i gate_i * E_i(token), with
+    E(h) = (silu(h W_g) * (h W_u)) W_d.
+
+    tokens: (T, D); experts, gates: (T, k) from `topk_sigmoid_route`
+    over the router's full width; w_gate / w_up: (held, D, F), w_down:
+    (held, F, D): expert `first + i` of the router is row i. The
+    dispatch buffer takes `max_pairs` pairs, which no batch can
+    exceed: no pair is dropped. Returns the output, (T, D) float32."""
+    from ..tracing import device_scope
+    from . import grouped_matmul as gm
+    T, D = tokens.shape
+    k = experts.shape[1]
+    held = w_gate.shape[0]
+    bound = max_pairs(T, k, held)
+    # The buffer: every group in whole tiles and at least one, so that
+    # a row tile of the grouped matmul belongs to one expert.
+    tile = gm.TILE_M
+    n_rows = -(-bound // tile) * tile + held * tile
+    kernels = gm.kernels_engage(
+        jax.ShapeDtypeStruct((n_rows, D), tokens.dtype), w_gate, tile)
+    _m_moe_traces.labels(dispatch="sorted_grouped_kernels" if kernels
+                         else "sorted_ragged").inc()
+    _m_moe_bound.set(bound)
+    i32 = jnp.int32
+
+    with device_scope("hvd.moe.route"):
+        local = experts.reshape(-1) - first                   # (T * k,)
+        mine = (local >= 0) & (local < held)
+        key = jnp.where(mine, local, held).astype(i32)
+        pairs = jnp.arange(T * k, dtype=i32)
+        _, by_expert = lax.sort((key, pairs), num_keys=1)     # stable
+        _, position = lax.sort((by_expert, pairs), num_keys=1)  # inverse
+        sizes = jnp.sum(key[:, None] == jnp.arange(held, dtype=i32),
+                        axis=0, dtype=i32)                    # (held,)
+        # the groups as the buffer holds them: every group padded to
+        # whole tiles
+        starts = jnp.cumsum(sizes) - sizes
+        padded = jnp.maximum(-(-sizes // tile) * tile, tile)
+        padded_starts = jnp.cumsum(padded) - padded
+        # pair -> buffer row
+        group = jnp.minimum(key, held - 1)
+        landed = mine.reshape(T, k)
+        slots = jnp.where(
+            mine, padded_starts[group] + position - starts[group], 0
+        ).reshape(T, k)
+        # buffer row -> pair
+        row = jnp.arange(n_rows, dtype=i32)
+        row_group = gm.tile_groups(padded, n_rows // tile, tile)[0][
+            row // tile]
+        rank = row - padded_starts[row_group]
+        valid = (rank >= 0) & (rank < sizes[row_group])
+        order = by_expert[jnp.clip(starts[row_group] + rank, 0, T * k - 1)]
+        xs = _permute(tokens, order, slots, landed)          # (n_rows, D)
+
+    with device_scope("hvd.moe.experts"):
+        # bf16 in and out, f32 accumulation inside, like every other
+        # matmul of the model; the SwiGLU and the gated sum are f32.
+        # No mask on these buffers: a row that holds no pair is a dead
+        # tile's, which the kernels neither compute nor read back, or
+        # padding inside a live tile, finite, whose outputs no pair
+        # reads and whose cotangent `_unpermute` makes exactly zero.
+        def grouped(x, w):
+            return gm.grouped_matmul(x, w, padded, tile_m=tile)
+        gate = jax.nn.silu(grouped(xs, w_gate).astype(jnp.float32))
+        act = (gate * grouped(xs, w_up).astype(jnp.float32)
+               ).astype(tokens.dtype)
+        ys = grouped(act, w_down)
+
+    with device_scope("hvd.moe.route"):
+        out = _unpermute(ys, gates, order, slots, valid, landed)
+    return out

@@ -148,28 +148,58 @@ def flash_possible_cfg(head_dim: int, seq: int,
     return False
 
 
+def _fused_width(q, v) -> int:
+    """The head width the fused kernels are handed. Equal widths go as
+    they are (heads of 64 keep the dense path, as measured). Latent
+    attention's unequal ones (q / k 192, v 128) are zero-padded to one
+    whole number of 128 lanes: zero columns of q and k change no
+    score, those of v give zero output columns that are cut off
+    again, so the result is exact and the matmuls grow by the
+    padding (192 / 128 -> 256 / 256 is 1.6 x the core's work)."""
+    from .fused_attention import LANES
+    if q.shape[-1] == v.shape[-1]:
+        return q.shape[-1]
+    return -(-max(q.shape[-1], v.shape[-1]) // LANES) * LANES
+
+
 def _flash_supported(q, k, v, causal: bool) -> bool:
     """The engagement rule, on what the call observes: TPU backend,
-    causal, shapes the kernels take (self-attention, L in 128-blocks,
-    head_dim a whole number of lanes, heads in whole groups), bf16
-    operands (what the chip has measured; f32 callers keep the dense
-    path and its matmul precision), and no live sequence-parallel
-    axis on q (ring / Ulysses callers keep the path they were tested
-    on)."""
+    causal, shapes the kernels take at `_fused_width` (self-attention,
+    L in 128-blocks, head width a whole number of lanes, heads in
+    whole groups), bf16 operands (what the chip has measured; f32
+    callers keep the dense path and its matmul precision), and no
+    live sequence-parallel axis on q (ring / Ulysses callers keep the
+    path they were tested on)."""
     from . import fused_attention
+    if not (q.ndim == k.ndim == v.ndim == 4
+            and q.shape[-1] == k.shape[-1]):
+        return False
+    width = _fused_width(q, v)
     return (jax.default_backend() == "tpu" and causal
             and q.dtype == k.dtype == v.dtype == jnp.bfloat16
-            and fused_attention.supported(q.shape, k.shape, v.shape)
+            and fused_attention.supported(
+                (*q.shape[:-1], width), (*k.shape[:-1], width),
+                (*v.shape[:-1], width))
             and SEQ_AXIS not in jax.typeof(q).vma)
 
 
 def flash_attention_path(q, k, v, causal: bool, scale: float):
     """The fused path: (B, L, H, D) in and out, k / v with H or fewer
-    (grouped) heads. Causal only."""
+    (grouped) heads; v may be narrower than q / k (latent attention:
+    192 / 128) and is then zero-padded with them to `_fused_width`.
+    `scale` is the caller's, never derived from a padded width.
+    Causal only."""
     from .fused_attention import fused_causal_attention
     if not causal:
         raise ValueError("the fused attention kernels are causal only")
-    return fused_causal_attention(q, k, v, scale)
+    width = _fused_width(q, v)
+
+    def padded(x):
+        extra = width - x.shape[-1]
+        return x if extra == 0 else jnp.pad(
+            x, ((0, 0),) * (x.ndim - 1) + ((0, extra),))
+    out = fused_causal_attention(padded(q), padded(k), padded(v), scale)
+    return out[..., :v.shape[-1]] if width != v.shape[-1] else out
 
 
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -179,7 +209,8 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     softmax, PV, all materialised. The oracle of the ring-attention
     and fused-kernel tests, and attention()'s path wherever the fused
     kernels do not engage. k / v may carry fewer heads than q
-    (grouped-query): each is repeated over its group."""
+    (grouped-query): each is repeated over its group; the output has
+    v's head width."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     reps = q.shape[2] // k.shape[2]
@@ -209,7 +240,9 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               scale: Optional[float] = None) -> jax.Array:
     """Attention on one device's (B, L, H, D) blocks, for a mesh with
     no live sequence axis: fused where `_flash_supported` says so,
-    else `dense_attention`. k / v may carry fewer (grouped) heads."""
+    else `dense_attention`. k / v may carry fewer (grouped) heads, and
+    v another head width than q / k; the default `scale` is that of
+    q's own width."""
     mode = _flash_mode()
     fused = mode == "1" or (mode == "auto"
                             and _flash_supported(q, k, v, causal))

@@ -266,6 +266,19 @@ DEVICE_SCOPES: Dict[str, str] = {
                      "where the path needs one",
     "hvd.ffn": "dense FFN: norm and SwiGLU",
     "hvd.moe": "MoE FFN: router, dispatch, experts, combine",
+    "hvd.moe.route": "dropless expert layer: norm, router scores, "
+                     "top-k, the sort and the gathers of the (token, "
+                     "expert) pairs held here, and the gated sum back "
+                     "(gathers too)",
+    "hvd.moe.experts": "dropless expert layer: the grouped matmuls "
+                       "over the experts held and their SwiGLU",
+    "hvd.moe.shared": "the shared expert's SwiGLU",
+    "hvd.hc": "residual streams (hyper-connections): coefficients, "
+              "Sinkhorn, the mix into a sub-layer's input, the "
+              "write-back, and the streams' sum at the exit",
+    "hvd.mtp": "multi-token module: the two norms, the embedding of "
+               "the next token and the 2D x D projection (its block "
+               "keeps its own scopes)",
     "hvd.head_loss": "final norm, LM head or classifier, cross-entropy",
     "hvd.conv": "ResNet convolutions",
     "hvd.batchnorm": "BatchNorm statistics and normalisation",
@@ -279,7 +292,7 @@ DEVICE_SCOPES: Dict[str, str] = {
 # JAX's own key leaves names out, so a cache filled before a scope was
 # added, renamed or moved hands back executables with the old names.
 # Raise it with every such change.
-DEVICE_SCOPES_VERSION = 1
+DEVICE_SCOPES_VERSION = 2
 _BUCKET_SCOPE = "hvd.grad_reduce.b"
 _BUCKET_SCOPE_NAME = re.compile(re.escape(_BUCKET_SCOPE) + "[0-9]+")
 

@@ -101,6 +101,53 @@ def test_flash_attention_forward_and_backward_compile(topo, shape):
         assert f"[{B},{H},{L},{L}]" not in hlo
 
 
+@pytest.mark.parametrize("B, L", [(2, 4096), (1, 256)],
+                         ids=["window", "sample"])
+def test_latent_attention_core_compiles_padded(topo, B, L):
+    """q / k 192 wide, v 128 (the latent-attention cell): padded to
+    256 for the fused kernels, the output cut back to 128, no
+    (B, H, L, L) tensor in the program."""
+    from horovod_tpu.parallel.ring_attention import flash_attention_path
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((B, L, 32, 192), jnp.bfloat16, sharding=one)
+    v = jax.ShapeDtypeStruct((B, L, 32, 128), jnp.bfloat16, sharding=one)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: flash_attention_path(
+            *a, True, 0.1447).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+    hlo = jax.jit(bwd).lower(q, q, v).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    assert f"[{B},32,{L},{L}]" not in hlo
+    dq, dk, dv = jax.eval_shape(bwd, q, q, v)
+    assert (dq.shape, dk.shape, dv.shape) == (q.shape, q.shape, v.shape)
+
+
+@pytest.mark.parametrize("k, n", [(3584, 1024), (1024, 3584)],
+                         ids=["gate-up", "down"])
+def test_grouped_matmul_kernels_compile(topo, k, n):
+    """The expert layer's grouped matmuls at the published expert's
+    widths over the cell's dispatch buffer (34,816 rows: 8,192 tokens
+    x 4 choices and a tile of padding an expert): forward, dx and dw
+    are one Mosaic kernel each."""
+    from horovod_tpu.parallel import grouped_matmul as gm
+    one = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((34816, k), jnp.bfloat16, sharding=one)
+    w = jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16, sharding=one)
+    rows = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
+
+    def both(x, w, rows):
+        def loss(x, w):
+            out = gm.grouped_matmul_kernels(x, w, rows)
+            return jnp.sum(jnp.square(out.astype(jnp.float32)))
+        return jax.value_and_grad(loss, (0, 1))(x, w)
+    hlo = jax.jit(both).lower(x, w, rows).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    for name in ("hvd_grouped_matmul_fwd", "hvd_grouped_matmul_dx",
+                 "hvd_grouped_matmul_dw"):
+        assert name in hlo
+
+
 def _flagship_compiled(devices, global_batch):
     from horovod_tpu.models import transformer as tfm
     mesh = Mesh(np.array(devices), axis_names=("data",))

@@ -15,7 +15,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu import tracing
 from horovod_tpu.metrics import snapshot
-from horovod_tpu.models import resnet, transformer
+from horovod_tpu.models import latent_moe, resnet, transformer
 from horovod_tpu.parallel.aot import aot_compile
 from horovod_tpu.parallel.train import build_train_step, plan_overlap
 
@@ -117,9 +117,47 @@ def test_resnet_scopes_reach_the_compiled_program(resnet_names, scope,
     assert want <= variants(resnet_names, scope)
 
 
+@pytest.fixture(scope="module")
+def latent_moe_names():
+    """The latent-attention, sparse-expert, multi-stream model with
+    every layer checkpointed, through `build_train_step`."""
+    cfg = latent_moe.LatentMoEConfig(
+        vocab=64, d_model=16, n_expert_layers=1, n_heads=2, qk_nope_dim=4,
+        qk_rope_dim=4, v_head_dim=4, q_rank=8, kv_rank=8, d_ff_dense=32,
+        d_ff_expert=8, n_experts=8, experts_held=2, top_k=2, hc_mult=2,
+        hc_iters=2, mtp=True, dtype=jnp.float32, remat=True)
+    mesh = Mesh(np.array(jax.devices()[:1]), axis_names=("data",))
+    params = latent_moe.init_params(cfg, jax.random.PRNGKey(0))
+    tx = optax.sgd(0.1)
+    step = build_train_step(
+        lambda p, b: latent_moe.loss_fn(cfg, p, b), tx, mesh,
+        batch_spec={"tokens": P("data")}, donate=False)
+    batch = {"tokens": jnp.zeros((2, 8), jnp.int32)}
+    return op_names(step.lower(params, tx.init(params), batch).compile())
+
+
+@pytest.mark.parametrize("scope, want", [
+    ("hvd.hc", {"forward", "backward", "recompute"}),
+    ("hvd.moe.route", {"forward", "backward", "recompute"}),
+    ("hvd.moe.experts", {"forward", "backward", "recompute"}),
+    ("hvd.moe.shared", {"forward", "backward", "recompute"}),
+    ("hvd.mtp", {"forward", "backward"}),
+    ("hvd.attn.proj", {"forward", "backward", "recompute"}),
+    ("hvd.attn.core", {"forward", "backward", "recompute"}),
+    ("hvd.ffn", {"forward", "backward", "recompute"}),
+    ("hvd.embed", {"forward", "backward"}),
+    ("hvd.head_loss", {"forward", "backward"}),
+])
+def test_latent_moe_scopes_reach_the_compiled_program(latent_moe_names,
+                                                      scope, want):
+    assert want <= variants(latent_moe_names, scope)
+    assert not variants(latent_moe_names, "hvd.moe")
+
+
 def test_every_name_in_a_program_is_registered(transformer_names,
-                                               resnet_names):
-    found = {s for n in transformer_names | resnet_names
+                                               resnet_names,
+                                               latent_moe_names):
+    found = {s for n in transformer_names | resnet_names | latent_moe_names
              for s in SCOPE.findall(n)}
     buckets = {s for s in found if s.startswith("hvd.grad_reduce.b")}
     assert buckets and found - buckets <= set(tracing.DEVICE_SCOPES)
@@ -196,10 +234,11 @@ def test_registry_names_and_version():
     assert all(name.startswith("hvd.") and SCOPE.fullmatch(name)
                for name in tracing.DEVICE_SCOPES)
     assert (tracing.DEVICE_SCOPES_VERSION,
-            sorted(tracing.DEVICE_SCOPES)) == (1, [
+            sorted(tracing.DEVICE_SCOPES)) == (2, [
         "hvd.attn.core", "hvd.attn.proj", "hvd.batchnorm", "hvd.conv",
-        "hvd.embed", "hvd.ffn", "hvd.grad_reduce", "hvd.head_loss",
-        "hvd.moe", "hvd.optimizer"])
+        "hvd.embed", "hvd.ffn", "hvd.grad_reduce", "hvd.hc",
+        "hvd.head_loss", "hvd.moe", "hvd.moe.experts", "hvd.moe.route",
+        "hvd.moe.shared", "hvd.mtp", "hvd.optimizer"])
     with tracing.bucket_scope(3):
         pass
 
