@@ -1,0 +1,171 @@
+"""Device time of the fused attention kernels alone, on the chip.
+
+    python3 benchmarks/attn_kernel_timing.py [--steps 10] [--block-cap N]
+        [--heads-cap N] [B,L,H,Hkv,Dqk,Dv ...]
+
+For each shape: seeded bf16 q, k, v and dO, one jitted program that
+runs forward and backward, `--steps` calls of it under the profiler,
+and from the trace the mean device time a call of each kernel and of
+everything else in the program (the `di` row sums, pads, cuts). The
+forms: `kernels` is `fused_attention._forward` and `_backward` on the
+shapes as given (where `supported` takes them); a shape whose q / k
+and v widths differ also runs `path` (`flash_attention_path` as it
+is), `path_padded_qk` (q and k zero-padded to whole lanes, v at its
+own width) and `path_one_width` (the path before PR 32: q, k and v
+zero-padded to one width, the output cut back). One JSON line a
+measurement, also appended to `chiprun_out/attn_kernel_timing.jsonl`.
+Fails where JAX finds no TPU: a CPU time is no device time.
+
+The default shapes are the `xing4-29b-ep8.jit-dp1` cell's core as the
+model calls it (q / k 192, v 128), with q / k at 256, with all at 256
+(what the kernels ran before PR 32), and the Mistral cells' core.
+`--block-cap` and `--heads-cap` are for sweeps only: they override
+`BLOCK_CAP` and `HEADS_CAP` in this process.
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import importlib
+import json
+import os
+import sys
+import tempfile
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from horovod_tpu.parallel import fused_attention as fa  # noqa: E402
+# `horovod_tpu.parallel.ring_attention` the attribute is the function.
+ra = importlib.import_module("horovod_tpu.parallel.ring_attention")
+from perfbench.trace_reduce import (OPS_LINE, instruction,  # noqa: E402
+                                    newest_xplane, read_events)
+
+DEFAULT = ["2,4096,32,32,192,128", "2,4096,32,32,256,128",
+           "2,4096,32,32,256,256", "2,2048,32,8,128,128"]
+KERNELS = ("hvd_fused_attention_fwd", "hvd_fused_attention_dq",
+           "hvd_fused_attention_dkv")
+OUT = os.path.join("chiprun_out", "attn_kernel_timing.jsonl")
+
+
+def _inputs(B, L, H, Hkv, Dqk, Dv):
+    ks = jax.random.split(jax.random.PRNGKey(32), 4)
+    draw = functools.partial(jax.random.normal, dtype=jnp.bfloat16)
+    return (draw(ks[0], (B, L, H, Dqk)), draw(ks[1], (B, L, Hkv, Dqk)),
+            draw(ks[2], (B, L, Hkv, Dv)), draw(ks[3], (B, L, H, Dv)))
+
+
+def _kernels(q, k, v, do, scale):
+    o, lse = fa._forward(q, k, v, scale, False)
+    return o, fa._backward(q, k, v, o, lse, do, scale, False)
+
+
+def _path(q, k, v, do, scale):
+    out, vjp = jax.vjp(
+        lambda q, k, v: ra.flash_attention_path(q, k, v, True, scale),
+        q, k, v)
+    return out, vjp(do)
+
+
+def _padded(x, width):
+    return jnp.pad(x, ((0, 0),) * 3 + ((0, width - x.shape[-1]),))
+
+
+def _lanes(width):
+    return -(-width // fa.LANES) * fa.LANES
+
+
+def _path_padded_qk(q, k, v, do, scale):
+    width = _lanes(q.shape[-1])
+    out, vjp = jax.vjp(
+        lambda q, k, v: fa.fused_causal_attention(
+            _padded(q, width), _padded(k, width), v, scale), q, k, v)
+    return out, vjp(do)
+
+
+def _path_one_width(q, k, v, do, scale):
+    width = _lanes(max(q.shape[-1], v.shape[-1]))
+
+    def path(q, k, v):
+        out = fa.fused_causal_attention(
+            _padded(q, width), _padded(k, width), _padded(v, width), scale)
+        return out[..., :v.shape[-1]]
+    out, vjp = jax.vjp(path, q, k, v)
+    return out, vjp(do)
+
+
+def _device_ms(trace_dir: str, steps: int):
+    """Mean device ms a call by kernel, and of every other
+    instruction, on the first chip."""
+    planes = read_events(newest_xplane(trace_dir))
+    ops = planes[sorted(p for p in planes if p.startswith("/device:TPU:"))[0]]
+    by = dict.fromkeys(KERNELS, 0.0)
+    by["other"] = 0.0
+    for text, start, end in ops[OPS_LINE]:
+        name = instruction(text)[0]
+        key = next((k for k in KERNELS if k in name), "other")
+        by[key] += (end - start) / 1e9 / steps
+    return by
+
+
+def measure(form: str, fn, shape, steps: int):
+    args = _inputs(*shape)
+    scale = float(shape[4]) ** -0.5
+    step = jax.jit(functools.partial(fn, scale=scale))
+    jax.block_until_ready(step(*args))
+    jax.block_until_ready(step(*args))
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for _ in range(steps):
+                out = step(*args)
+            jax.block_until_ready(out)
+        by = _device_ms(d, steps)
+    dev = jax.devices()[0]
+    line = {"form": form, "shape": list(shape),
+            "block": fa.block_size(shape[1]),
+            "step_heads": fa.step_heads(*shape[2:]),
+            "steps": steps,
+            "fwd_ms": by[KERNELS[0]], "dq_ms": by[KERNELS[1]],
+            "dkv_ms": by[KERNELS[2]], "other_ms": by["other"],
+            "sum_ms": sum(by.values()),
+            "device": {"platform": dev.platform, "kind": dev.device_kind}}
+    print(json.dumps(line), flush=True)
+    os.makedirs(os.path.dirname(OUT), exist_ok=True)
+    with open(OUT, "a") as f:
+        f.write(json.dumps(line) + "\n")
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("shapes", nargs="*", default=DEFAULT)
+    ap.add_argument("--steps", type=int, default=10)
+    ap.add_argument("--block-cap", type=int, default=fa.BLOCK_CAP)
+    ap.add_argument("--heads-cap", type=int, default=fa.HEADS_CAP)
+    a = ap.parse_args()
+    if jax.default_backend() != "tpu":
+        raise SystemExit("attn_kernel_timing: no TPU; a time from "
+                         f"{jax.default_backend()} is no device time")
+    if a.block_cap != fa.BLOCK_CAP:
+        fa.block_size = functools.partial(fa.block_size, cap=a.block_cap)
+    if a.heads_cap != fa.HEADS_CAP:
+        fa.HEADS_CAP = a.heads_cap
+        fa.heads_per_step = functools.partial(fa.heads_per_step,
+                                              cap=a.heads_cap)
+    for text in a.shapes:
+        B, L, H, Hkv, Dqk, Dv = shape = tuple(
+            int(x) for x in text.split(","))
+        if fa.supported((B, L, H, Dqk), (B, L, Hkv, Dqk), (B, L, Hkv, Dv)):
+            measure("kernels", _kernels, shape, a.steps)
+        if Dqk != Dv:
+            for form, fn in (("path", _path),
+                             ("path_padded_qk", _path_padded_qk),
+                             ("path_one_width", _path_one_width)):
+                measure(form, fn, shape, a.steps)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

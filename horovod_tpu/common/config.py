@@ -734,8 +734,9 @@ KNOBS: List[Knob] = [
          "Override of attention()'s path rule "
          "(parallel/ring_attention.py): 'auto' (default) runs the "
          "fused Pallas kernels (parallel/fused_attention.py) where "
-         "the call allows it (TPU, causal, seq in 128-blocks, "
-         "head_dim a multiple of 128, no live seq axis) and the dense "
+         "the call allows it (TPU, causal, bf16, seq in 128-blocks, "
+         "v's head width a multiple of 128, q / k's the same or any "
+         "other, no live seq axis) and the dense "
          "path elsewhere; '0' keeps the dense path everywhere; '1' "
          "takes the fused path or raises. The rounds 4-5 rejects in "
          "docs/benchmarks.md were of JAX's stock kernel at its "

@@ -15,7 +15,18 @@ transpose to (B, H, L, D) and back, and K / V keep their own heads
 (grouped-query attention: q head h reads kv head h // (H // Hkv)).
 One grid step takes the q heads that share a kv head (`heads_per_step`),
 so their K / V block is loaded once, and the dK/dV kernel sums the
-group in VMEM.
+group in VMEM; where every q head has a kv head of its own, a step
+takes several heads of both side by side (`step_heads`).
+
+Two head widths, both read from the shapes of the call: `dqk` of q and
+k, `dv` of v (latent attention: 192 and 128; equal everywhere else).
+q / k blocks, dQ and dK are `dqk` wide; v blocks, the output, dO, the
+f32 accumulator and dV are `dv` wide, so a narrower v is neither
+padded in HBM nor multiplied as zeros on the MXU. A column block is a
+whole number of lanes: `dv` is, and so is `dqk` or an even number of
+heads of it (two or four heads of 192 a step; the caller zero-pads q
+and k where neither holds). With equal widths and grouped kv the
+kernels are what they were with one width.
 
 Blocks come from the shapes the call sees (`block_size`): the largest
 multiple of 128 up to `BLOCK_CAP` that divides L, so seq 2048 runs
@@ -47,6 +58,15 @@ BLOCK_CAP = 512
 # measured; blocks of 512 x 4 heads of 256 still fit the compiler's
 # default VMEM budget, tests/test_chip_compile.py).
 HEADS_CAP = 4
+# Where every q head has its own kv head there is no K / V block to
+# share, and a grid step takes up to HEADS_CAP heads of both side by
+# side: fewer, longer steps (at 32 heads of 192 / 128 and seq 4096 the
+# three kernels read 15.5 ms with one head a step, 14.0 with two, 13.1
+# with four; PERF.md section 6, PR 32), and an even number of heads of
+# 192 is a whole number of lanes. At most this many K and V columns a
+# step, both widths together: four heads of 256 / 128 fit the
+# compiler's VMEM budget at blocks of 512, four of 256 / 256 do not.
+KV_COLS_CAP = 1536
 # Finite, so that a fully masked row of a diagonal block gives
 # exp(MASK - m) = 0 and never inf - inf.
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
@@ -65,20 +85,44 @@ def block_size(seq: int, cap: int = BLOCK_CAP) -> int:
 
 def supported(q_shape, k_shape, v_shape) -> bool:
     """The shapes the kernels take: self-attention (equal lengths),
-    heads in whole groups, L in 128-blocks, D a whole number of
-    lanes."""
+    heads in whole groups, L in 128-blocks, the v head width a whole
+    number of lanes, and a q / k head width of which `step_heads` makes
+    column blocks of whole lanes (a whole number of lanes itself; or,
+    where every q head has its own kv head, one of which two or four
+    heads side by side are: 192)."""
     B, L, H, D = q_shape
-    return (tuple(v_shape) == tuple(k_shape) and len(k_shape) == 4
+    return (len(k_shape) == len(v_shape) == 4
+            and tuple(v_shape[:3]) == tuple(k_shape[:3])
             and k_shape[0] == B and k_shape[1] == L
             and k_shape[3] == D and k_shape[2] > 0
-            and H % k_shape[2] == 0 and D % LANES == 0
-            and block_size(L) > 0)
+            and H % k_shape[2] == 0
+            and v_shape[3] > 0 and v_shape[3] % LANES == 0
+            and block_size(L) > 0
+            and step_heads(H, k_shape[2], D, v_shape[3]) is not None)
 
 
 def heads_per_step(group: int, cap: int = HEADS_CAP) -> int:
     """q heads one grid step takes: all that share a kv head, up to
     `cap` (the largest divisor of the group within it)."""
     return max(c for c in range(1, min(group, cap) + 1) if group % c == 0)
+
+
+def step_heads(H: int, Hkv: int, Dqk: int, Dv: int):
+    """(q heads, kv heads, steps a kv head) one grid step takes; None
+    where the q / k width gives no column block of whole lanes.
+    Grouped kv: the q heads that share one kv head, `heads_per_step`
+    of them, in as many steps as the group needs. Every q head with a
+    kv head of its own: as many heads of both, side by side, as divide
+    Hkv, stay within `HEADS_CAP` and `KV_COLS_CAP` and make whole
+    lanes (of 192: two or four)."""
+    group = H // Hkv
+    if group > 1:
+        hs = heads_per_step(group)
+        return (hs, 1, group // hs) if Dqk % LANES == 0 else None
+    fits = [n for n in range(1, min(Hkv, HEADS_CAP) + 1)
+            if Hkv % n == 0 and n * Dqk % LANES == 0
+            and (n == 1 or n * (Dqk + Dv) <= KV_COLS_CAP)]
+    return (fits[-1], fits[-1], 1) if fits else None
 
 
 def _visible(q_lo, k_lo, q_axis: int, shape):
@@ -102,9 +146,19 @@ def _column(row):
                             (row.shape[0], LANES))
 
 
-def _head_cols(heads: int, dh: int):
-    """The column slice of each head in a (rows, heads * dh) block."""
-    return [slice(g * dh, (g + 1) * dh) for g in range(heads)]
+def _head_cols(heads: int, width: int):
+    """The column slice of each head in a (rows, heads * width)
+    block."""
+    return [slice(g * width, (g + 1) * width) for g in range(heads)]
+
+
+def _kv_index(heads: int, width: int):
+    """The index of each kv head in a grid step's (rows, heads * width)
+    K / V block or dK / dV accumulator: the whole of it where the
+    step has one."""
+    if heads == 1:
+        return [...]
+    return [(slice(None), cols) for cols in _head_cols(heads, width)]
 
 
 def _on_visible(q_lo, bq, k_lo, bk, step):
@@ -118,10 +172,11 @@ def _on_visible(q_lo, bq, k_lo, bk, step):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
-                *, scale: float, bq: int, bk: int, heads: int, dh: int):
+                *, scale: float, bq: int, bk: int, heads: int,
+                kv_heads: int, dqk: int, dv: int):
     i, j = pl.program_id(2), pl.program_id(3)
     q_lo, k_lo = i * bq, j * bk
-    cols = _head_cols(heads, dh)
+    qcols, vcols = _head_cols(heads, dqk), _head_cols(heads, dv)
 
     @pl.when(j == 0)
     def _():
@@ -130,10 +185,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
     def step(masked: bool):
-        k, v = k_ref[...], v_ref[...]
+        ks = [k_ref[ix] for ix in _kv_index(kv_heads, dqk)]
+        vs = [v_ref[ix] for ix in _kv_index(kv_heads, dv)]
         keep = _visible(q_lo, k_lo, 0, (bq, bk)) if masked else None
         for g in range(heads):
-            s = lax.dot_general(q_ref[:, cols[g]], k, _NT,
+            k, v = ks[g % kv_heads], vs[g % kv_heads]
+            s = lax.dot_general(q_ref[:, qcols[g]], k, _NT,
                                 preferred_element_type=_F32) * scale
             if masked:
                 s = jnp.where(keep, s, MASK_VALUE)
@@ -146,7 +203,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
             alpha = jnp.exp(m_prev - m_next)
             p = jnp.exp(s - _tile(m_next, bk))
             l_sc[g] = alpha * l_prev + p.sum(axis=1)[:, None]
-            acc_sc[:, cols[g]] = _tile(alpha, dh) * acc_sc[:, cols[g]] \
+            acc_sc[:, vcols[g]] = _tile(alpha, dv) * acc_sc[:, vcols[g]] \
                 + jnp.dot(p.astype(v.dtype), v,
                           preferred_element_type=_F32)
             m_sc[g] = m_next
@@ -157,8 +214,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
     def _():
         for g in range(heads):
             l = l_sc[g]
-            o_ref[:, cols[g]] = (acc_sc[:, cols[g]]
-                                 * _tile(1.0 / l, dh)).astype(o_ref.dtype)
+            o_ref[:, vcols[g]] = (acc_sc[:, vcols[g]]
+                                  * _tile(1.0 / l, dv)).astype(o_ref.dtype)
             # (bq, 128) lane-replicated column -> (1, bq) row, the
             # form both backward kernels read.
             lse_ref[g] = (m_sc[g] + jnp.log(l)).T[:1]
@@ -166,29 +223,31 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
                dq_sc, *, scale: float, bq: int, bk: int, heads: int,
-               dh: int):
+               kv_heads: int, dqk: int, dv: int):
     i, j = pl.program_id(2), pl.program_id(3)
     q_lo, k_lo = i * bq, j * bk
-    cols = _head_cols(heads, dh)
+    qcols, vcols = _head_cols(heads, dqk), _head_cols(heads, dv)
 
     @pl.when(j == 0)
     def _():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
     def step(masked: bool):
-        k, v = k_ref[...], v_ref[...]
+        ks = [k_ref[ix] for ix in _kv_index(kv_heads, dqk)]
+        vs = [v_ref[ix] for ix in _kv_index(kv_heads, dv)]
         keep = _visible(q_lo, k_lo, 0, (bq, bk)) if masked else None
         for g in range(heads):
-            s = lax.dot_general(q_ref[:, cols[g]], k, _NT,
+            k, v = ks[g % kv_heads], vs[g % kv_heads]
+            s = lax.dot_general(q_ref[:, qcols[g]], k, _NT,
                                 preferred_element_type=_F32) * scale
             if masked:
                 s = jnp.where(keep, s, MASK_VALUE)
             p = jnp.exp(s - _tile(_column(lse_ref[g, 0]), bk))
-            dp = lax.dot_general(do_ref[:, cols[g]], v, _NT,
+            dp = lax.dot_general(do_ref[:, vcols[g]], v, _NT,
                                  preferred_element_type=_F32)
             ds = p * (dp - _tile(_column(di_ref[g, 0]), bk))
-            dq_sc[:, cols[g]] += jnp.dot(ds.astype(k.dtype), k,
-                                         preferred_element_type=_F32)
+            dq_sc[:, qcols[g]] += jnp.dot(ds.astype(k.dtype), k,
+                                          preferred_element_type=_F32)
 
     _on_visible(q_lo, bq, k_lo, bk, step)
 
@@ -199,36 +258,40 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref,
                 dv_ref, dk_sc, dv_sc, *, scale: float, bq: int, bk: int,
-                heads: int, dh: int):
+                heads: int, kv_heads: int, dqk: int, dv: int):
     """Scores transposed, (bk, bq): keys along sublanes, so dV and dK
     are plain p^T @ dO and ds^T @ q, and lse / di broadcast as the
     rows they are stored as."""
     j, c, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
     q_lo, k_lo = i * bq, j * bk
-    cols = _head_cols(heads, dh)
+    qcols, vcols = _head_cols(heads, dqk), _head_cols(heads, dv)
 
     @pl.when(jnp.logical_and(c == 0, i == 0))
     def _():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
+    kidx, vidx = _kv_index(kv_heads, dqk), _kv_index(kv_heads, dv)
+
     def step(masked: bool):
-        k, v = k_ref[...], v_ref[...]
+        ks, vs = [k_ref[ix] for ix in kidx], [v_ref[ix] for ix in vidx]
         keep = _visible(q_lo, k_lo, 1, (bk, bq)) if masked else None
         for g in range(heads):
-            q, do = q_ref[:, cols[g]], do_ref[:, cols[g]]
+            h = g % kv_heads
+            k, v = ks[h], vs[h]
+            q, do = q_ref[:, qcols[g]], do_ref[:, vcols[g]]
             st = lax.dot_general(k, q, _NT,
                                  preferred_element_type=_F32) * scale
             if masked:
                 st = jnp.where(keep, st, MASK_VALUE)
             pt = jnp.exp(st - lse_ref[g])
-            dv_sc[...] += jnp.dot(pt.astype(do.dtype), do,
-                                  preferred_element_type=_F32)
+            dv_sc[vidx[h]] += jnp.dot(pt.astype(do.dtype), do,
+                                      preferred_element_type=_F32)
             dpt = lax.dot_general(v, do, _NT,
                                   preferred_element_type=_F32)
             dst = pt * (dpt - di_ref[g])
-            dk_sc[...] += jnp.dot(dst.astype(q.dtype), q,
-                                  preferred_element_type=_F32)
+            dk_sc[kidx[h]] += jnp.dot(dst.astype(q.dtype), q,
+                                      preferred_element_type=_F32)
 
     _on_visible(q_lo, bq, k_lo, bk, step)
 
@@ -249,100 +312,118 @@ def _params(n_parallel: int, n_grid: int):
         + ("arbitrary",) * (n_grid - n_parallel)))
 
 
-def _plan(q, k):
-    """What the three calls share: (B, L, H, Hkv, D), the q heads a
-    grid step takes, the steps of them a kv head, the block."""
-    B, L, H, D = q.shape
-    Hkv = k.shape[2]
-    hs = heads_per_step(H // Hkv)
-    return (B, L, H, Hkv, D), hs, H // Hkv // hs, block_size(L)
+def _plan(q, k, v):
+    """What the three calls share: (B, L, H, Hkv, Dqk, Dv), `step_heads`
+    (the q heads and the kv heads a grid step takes, the steps a kv
+    block), the block."""
+    B, L, H, Dqk = q.shape
+    Hkv, Dv = k.shape[2], v.shape[3]
+    return ((B, L, H, Hkv, Dqk, Dv), step_heads(H, Hkv, Dqk, Dv),
+            block_size(L))
 
 
-def _q_major(dims, hs: int, per_kv: int, blk: int):
+def _q_major(dims, heads, blk: int):
     """Grid and specs of the kernels that walk key blocks for a query
-    block (forward, dQ): (grid, q / o / dO spec, k / v spec, lse / di
-    row spec). One step takes `hs` q heads of one kv head, a
-    (blk, hs * D) column block. Keys past the diagonal are not loaded:
-    their steps name the block already resident."""
-    B, L, H, _, D = dims
-    q_spec = pl.BlockSpec((None, blk, hs * D),
-                          lambda b, c, i, j: (b, i, c))
-    kv_spec = pl.BlockSpec(
-        (None, blk, D),
-        lambda b, c, i, j: (b, jnp.minimum(j, i), c // per_kv))
+    block (forward, dQ): (grid, q / dQ spec, o / dO spec, k spec,
+    v spec, lse / di row spec). One step takes `hs` q heads, a
+    (blk, hs * Dqk) column block of q and a (blk, hs * Dv) one of the
+    output, and the `kvs` kv heads they read. Keys past the diagonal
+    are not loaded: their steps name the block already resident."""
+    B, L, H, _, Dqk, Dv = dims
+    hs, kvs, per_kv = heads
+
+    def q_cols(width):
+        return pl.BlockSpec((None, blk, hs * width),
+                            lambda b, c, i, j: (b, i, c))
+
+    def kv_cols(width):
+        return pl.BlockSpec(
+            (None, blk, kvs * width),
+            lambda b, c, i, j: (b, jnp.minimum(j, i), c // per_kv))
     row_spec = pl.BlockSpec((None, hs, 1, blk),
                             lambda b, c, i, j: (b, c, 0, i))
-    return (B, H // hs, L // blk, L // blk), q_spec, kv_spec, row_spec
+    return ((B, H // hs, L // blk, L // blk), q_cols(Dqk), q_cols(Dv),
+            kv_cols(Dqk), kv_cols(Dv), row_spec)
 
 
 def _forward(q, k, v, scale: float, interpret: bool):
-    dims, hs, per_kv, blk = _plan(q, k)
-    B, L, H, Hkv, D = dims
+    dims, heads, blk = _plan(q, k, v)
+    B, L, H, Hkv, Dqk, Dv = dims
+    hs, kvs, _ = heads
     vma = _vma(q, k, v)
-    grid, q_spec, kv_spec, row_spec = _q_major(dims, hs, per_kv, blk)
+    grid, q_spec, o_spec, k_spec, v_spec, row_spec = _q_major(
+        dims, heads, blk)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, bq=blk, bk=blk,
-                          heads=hs, dh=D),
+                          heads=hs, kv_heads=kvs, dqk=Dqk, dv=Dv),
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((B, L, H * D), q.dtype, vma=vma),
+        in_specs=[q_spec, k_spec, v_spec],
+        out_specs=[o_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct((B, L, H * Dv), q.dtype, vma=vma),
                    jax.ShapeDtypeStruct((B, H, 1, L), _F32, vma=vma)],
         scratch_shapes=[pltpu.VMEM((hs, blk, LANES), _F32),
                         pltpu.VMEM((hs, blk, LANES), _F32),
-                        pltpu.VMEM((blk, hs * D), _F32)],
+                        pltpu.VMEM((blk, hs * Dv), _F32)],
         compiler_params=_params(3, 4),
         interpret=interpret,
         name="hvd_fused_attention_fwd",
-    )(q.reshape(B, L, H * D), k.reshape(B, L, Hkv * D),
-      v.reshape(B, L, Hkv * D))
-    return o.reshape(B, L, H, D), lse
+    )(q.reshape(B, L, H * Dqk), k.reshape(B, L, Hkv * Dqk),
+      v.reshape(B, L, Hkv * Dv))
+    return o.reshape(B, L, H, Dv), lse
 
 
 def _backward(q, k, v, o, lse, do, scale: float, interpret: bool):
-    dims, hs, per_kv, blk = _plan(q, k)
-    B, L, H, Hkv, D = dims
+    dims, heads, blk = _plan(q, k, v)
+    B, L, H, Hkv, Dqk, Dv = dims
+    hs, kvs, per_kv = heads
     vma = _vma(q, k, v, do)
     di = jnp.sum(o.astype(_F32) * do.astype(_F32), axis=-1)   # (B, L, H)
     di = jnp.swapaxes(di, 1, 2)[:, :, None, :]                # (B, H, 1, L)
-    q3, do3 = q.reshape(B, L, H * D), do.reshape(B, L, H * D)
-    k3, v3 = k.reshape(B, L, Hkv * D), v.reshape(B, L, Hkv * D)
-    kw = dict(scale=scale, bq=blk, bk=blk, heads=hs, dh=D)
+    q3, do3 = q.reshape(B, L, H * Dqk), do.reshape(B, L, H * Dv)
+    k3, v3 = k.reshape(B, L, Hkv * Dqk), v.reshape(B, L, Hkv * Dv)
+    kw = dict(scale=scale, bq=blk, bk=blk, heads=hs, kv_heads=kvs,
+              dqk=Dqk, dv=Dv)
 
-    grid, q_spec, kv_spec, row_spec = _q_major(dims, hs, per_kv, blk)
+    grid, q_spec, o_spec, k_spec, v_spec, row_spec = _q_major(
+        dims, heads, blk)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **kw),
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B, L, H * D), q.dtype, vma=vma),
-        scratch_shapes=[pltpu.VMEM((blk, hs * D), _F32)],
+        out_shape=jax.ShapeDtypeStruct((B, L, H * Dqk), q.dtype, vma=vma),
+        scratch_shapes=[pltpu.VMEM((blk, hs * Dqk), _F32)],
         compiler_params=_params(3, 4),
         interpret=interpret,
         name="hvd_fused_attention_dq",
     )(q3, k3, v3, do3, lse, di)
 
-    # dK/dV walks query blocks for a key block, a kv head's q heads in
-    # `per_kv` steps of `hs`. Query blocks before the diagonal see
-    # nothing of key block j: their steps name the first that does.
-    qg_spec = pl.BlockSpec(
-        (None, blk, hs * D),
-        lambda b, h, j, c, i: (b, jnp.maximum(i, j), h * per_kv + c))
+    # dK/dV walks query blocks for a key block of `kvs` kv heads, their
+    # q heads in `per_kv` steps of `hs`. Query blocks before the
+    # diagonal see nothing of key block j: their steps name the first
+    # that does.
+    def qg_cols(width):
+        return pl.BlockSpec(
+            (None, blk, hs * width),
+            lambda b, h, j, c, i: (b, jnp.maximum(i, j), h * per_kv + c))
+
+    def kvg_cols(width):
+        return pl.BlockSpec((None, blk, kvs * width),
+                            lambda b, h, j, c, i: (b, j, h))
     rowg_spec = pl.BlockSpec(
         (None, hs, 1, blk),
         lambda b, h, j, c, i: (b, h * per_kv + c, 0, jnp.maximum(i, j)))
-    kvg_spec = pl.BlockSpec((None, blk, D),
-                            lambda b, h, j, c, i: (b, j, h))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **kw),
-        grid=(B, Hkv, L // blk, per_kv, L // blk),
-        in_specs=[qg_spec, kvg_spec, kvg_spec, qg_spec, rowg_spec,
-                  rowg_spec],
-        out_specs=[kvg_spec, kvg_spec],
-        out_shape=[jax.ShapeDtypeStruct((B, L, Hkv * D), k.dtype, vma=vma),
-                   jax.ShapeDtypeStruct((B, L, Hkv * D), v.dtype, vma=vma)],
-        scratch_shapes=[pltpu.VMEM((blk, D), _F32),
-                        pltpu.VMEM((blk, D), _F32)],
+        grid=(B, Hkv // kvs, L // blk, per_kv, L // blk),
+        in_specs=[qg_cols(Dqk), kvg_cols(Dqk), kvg_cols(Dv), qg_cols(Dv),
+                  rowg_spec, rowg_spec],
+        out_specs=[kvg_cols(Dqk), kvg_cols(Dv)],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, L, Hkv * Dqk), k.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, L, Hkv * Dv), v.dtype, vma=vma)],
+        scratch_shapes=[pltpu.VMEM((blk, kvs * Dqk), _F32),
+                        pltpu.VMEM((blk, kvs * Dv), _F32)],
         compiler_params=_params(3, 5),
         interpret=interpret,
         name="hvd_fused_attention_dkv",
@@ -371,13 +452,14 @@ _attention.defvjp(_attention_fwd, _attention_bwd)
 def fused_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                            scale: float, *,
                            interpret: bool = False) -> jax.Array:
-    """Causal self-attention, q (B, L, H, D), k / v (B, L, Hkv, D)
-    with H a multiple of Hkv, for shapes `supported` takes.
-    `interpret` runs the kernels in Pallas's interpreter (the CPU
-    tests)."""
+    """Causal self-attention, q (B, L, H, Dqk), k (B, L, Hkv, Dqk),
+    v (B, L, Hkv, Dv) with H a multiple of Hkv, for shapes `supported`
+    takes; the output is (B, L, H, Dv). `interpret` runs the kernels
+    in Pallas's interpreter (the CPU tests)."""
     if not supported(q.shape, k.shape, v.shape):
         raise ValueError(
             f"fused attention does not take q {q.shape}, k {k.shape}, "
             f"v {v.shape}: it needs equal lengths in 128-blocks, "
-            f"head_dim a multiple of {LANES}, heads in whole groups")
+            f"head widths (q / k, v) in whole {LANES}-lane column "
+            f"blocks, heads in whole groups")
     return _attention(q, k, v, float(scale), bool(interpret))

@@ -148,58 +148,56 @@ def flash_possible_cfg(head_dim: int, seq: int,
     return False
 
 
-def _fused_width(q, v) -> int:
-    """The head width the fused kernels are handed. Equal widths go as
-    they are (heads of 64 keep the dense path, as measured). Latent
-    attention's unequal ones (q / k 192, v 128) are zero-padded to one
-    whole number of 128 lanes: zero columns of q and k change no
-    score, those of v give zero output columns that are cut off
-    again, so the result is exact and the matmuls grow by the
-    padding (192 / 128 -> 256 / 256 is 1.6 x the core's work)."""
-    from .fused_attention import LANES
-    if q.shape[-1] == v.shape[-1]:
-        return q.shape[-1]
-    return -(-max(q.shape[-1], v.shape[-1]) // LANES) * LANES
+def _fused_qk_width(q, k, v) -> int:
+    """The q / k head width the fused kernels are handed; v always
+    goes at its own. Equal widths go as they are (heads of 64 keep the
+    dense path, as measured), and so does a q / k width the kernels
+    take beside another v width (latent attention's 192 against 128,
+    two heads a grid step). Any other is zero-padded to a whole number
+    of 128 lanes: zero columns of q and k change no score."""
+    from .fused_attention import LANES, supported
+    width = q.shape[-1]
+    if width == v.shape[-1] or supported(q.shape, k.shape, v.shape):
+        return width
+    return -(-width // LANES) * LANES
 
 
 def _flash_supported(q, k, v, causal: bool) -> bool:
     """The engagement rule, on what the call observes: TPU backend,
-    causal, shapes the kernels take at `_fused_width` (self-attention,
-    L in 128-blocks, head width a whole number of lanes, heads in
-    whole groups), bf16 operands (what the chip has measured; f32
-    callers keep the dense path and its matmul precision), and no
-    live sequence-parallel axis on q (ring / Ulysses callers keep the
-    path they were tested on)."""
+    causal, shapes the kernels take with q / k at `_fused_qk_width`
+    (self-attention, L in 128-blocks, head widths in whole lane
+    blocks, heads in whole groups), bf16 operands (what the chip has
+    measured; f32 callers keep the dense path and its matmul
+    precision), and no live sequence-parallel axis on q (ring /
+    Ulysses callers keep the path they were tested on)."""
     from . import fused_attention
     if not (q.ndim == k.ndim == v.ndim == 4
             and q.shape[-1] == k.shape[-1]):
         return False
-    width = _fused_width(q, v)
+    width = _fused_qk_width(q, k, v)
     return (jax.default_backend() == "tpu" and causal
             and q.dtype == k.dtype == v.dtype == jnp.bfloat16
             and fused_attention.supported(
-                (*q.shape[:-1], width), (*k.shape[:-1], width),
-                (*v.shape[:-1], width))
+                (*q.shape[:-1], width), (*k.shape[:-1], width), v.shape)
             and SEQ_AXIS not in jax.typeof(q).vma)
 
 
 def flash_attention_path(q, k, v, causal: bool, scale: float):
-    """The fused path: (B, L, H, D) in and out, k / v with H or fewer
-    (grouped) heads; v may be narrower than q / k (latent attention:
-    192 / 128) and is then zero-padded with them to `_fused_width`.
-    `scale` is the caller's, never derived from a padded width.
-    Causal only."""
+    """The fused path: (B, L, H, D) in, k / v with H or fewer (grouped)
+    heads, the output at v's head width. v may be narrower than q / k
+    (latent attention: 192 / 128): it goes to the kernels as it is,
+    and q and k do too where the kernels take their width, else
+    zero-padded to `_fused_qk_width`. `scale` is the caller's, never
+    derived from a padded width. Causal only."""
     from .fused_attention import fused_causal_attention
     if not causal:
         raise ValueError("the fused attention kernels are causal only")
-    width = _fused_width(q, v)
+    extra = _fused_qk_width(q, k, v) - q.shape[-1]
 
     def padded(x):
-        extra = width - x.shape[-1]
         return x if extra == 0 else jnp.pad(
             x, ((0, 0),) * (x.ndim - 1) + ((0, extra),))
-    out = fused_causal_attention(padded(q), padded(k), padded(v), scale)
-    return out[..., :v.shape[-1]] if width != v.shape[-1] else out
+    return fused_causal_attention(padded(q), padded(k), v, scale)
 
 
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -231,7 +229,8 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 _m_traces = _METRICS.counter(
     "hvd_attention_traces_total",
     "Times attention() was traced, by the path it took: fused (the "
-    "Pallas kernels of parallel/fused_attention.py) or dense.",
+    "Pallas kernels of parallel/fused_attention.py), fused_padded_qk "
+    "(the same kernels, q and k zero-padded to whole lanes) or dense.",
     ("path",))
 
 
@@ -246,7 +245,10 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     mode = _flash_mode()
     fused = mode == "1" or (mode == "auto"
                             and _flash_supported(q, k, v, causal))
-    _m_traces.labels(path="fused" if fused else "dense").inc()
+    path = "dense" if not fused else (
+        "fused" if _fused_qk_width(q, k, v) == q.shape[-1]
+        else "fused_padded_qk")
+    _m_traces.labels(path=path).inc()
     if fused:
         return flash_attention_path(
             q, k, v, causal,

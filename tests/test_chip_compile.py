@@ -7,6 +7,7 @@ in parametrize arguments) and every compile stays in this process.
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -103,10 +104,11 @@ def test_flash_attention_forward_and_backward_compile(topo, shape):
 
 @pytest.mark.parametrize("B, L", [(2, 4096), (1, 256)],
                          ids=["window", "sample"])
-def test_latent_attention_core_compiles_padded(topo, B, L):
-    """q / k 192 wide, v 128 (the latent-attention cell): padded to
-    256 for the fused kernels, the output cut back to 128, no
-    (B, H, L, L) tensor in the program."""
+def test_latent_attention_core_compiles_with_v_at_its_own_width(topo, B, L):
+    """q / k 192 wide, v 128 (the latent-attention cell): every
+    operand of the fused kernels at its own width (q, k, dQ, dK 32 x
+    192 columns; v, the output, dO and dV 32 x 128), nothing padded to
+    256 anywhere in the program, no (B, H, L, L) tensor."""
     from horovod_tpu.parallel.ring_attention import flash_attention_path
     one = SingleDeviceSharding(topo.devices[0])
     q = jax.ShapeDtypeStruct((B, L, 32, 192), jnp.bfloat16, sharding=one)
@@ -121,6 +123,18 @@ def test_latent_attention_core_compiles_padded(topo, B, L):
     assert f"[{B},32,{L},{L}]" not in hlo
     dq, dk, dv = jax.eval_shape(bwd, q, q, v)
     assert (dq.shape, dk.shape, dv.shape) == (q.shape, q.shape, v.shape)
+    assert f"[{B},{L},32,256]" not in hlo and f"[{B},{L},8192]" not in hlo
+    # What each kernel is handed and gives back, in operand order.
+    wide, narrow = f"bf16[{B},{L},6144]", f"bf16[{B},{L},4096]"
+    calls = {}
+    for line in hlo.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = next(n for n in ("_fwd", "_dq", "_dkv")
+                        if f"hvd_fused_attention{n}" in line)
+            calls[name] = re.findall(r"bf16\[[0-9,]+\]", line)
+    assert calls["_fwd"] == [narrow, wide, wide, narrow]
+    assert calls["_dq"] == [wide, wide, wide, narrow, narrow]
+    assert calls["_dkv"] == [wide, narrow, wide, wide, narrow, narrow]
 
 
 @pytest.mark.parametrize("k, n", [(3584, 1024), (1024, 3584)],

@@ -4,6 +4,7 @@ Pallas's interpreter against `dense_attention`, and the rule by which
 itself: a test that needs the TPU branch patches
 `jax.default_backend`, which only steers tracing (nothing is lowered)."""
 
+import functools
 import importlib
 
 import jax
@@ -23,20 +24,23 @@ from horovod_tpu.parallel.ring_attention import (attention,
 ra = importlib.import_module("horovod_tpu.parallel.ring_attention")
 
 
-def _qkv(B, L, H, Hkv, D, dtype=jnp.float32, seed=0):
+def _qkv(B, L, H, Hkv, D, Dv=None, dtype=jnp.float32, seed=0):
+    """q, k (D wide), v (Dv wide, D where not given) and a weight of
+    the output's shape."""
+    Dv = Dv or D
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     return (jax.random.normal(ks[0], (B, L, H, D), dtype),
             jax.random.normal(ks[1], (B, L, Hkv, D), dtype),
-            jax.random.normal(ks[2], (B, L, Hkv, D), dtype),
-            jax.random.normal(ks[3], (B, L, H, D), jnp.float32))
+            jax.random.normal(ks[2], (B, L, Hkv, Dv), dtype),
+            jax.random.normal(ks[3], (B, L, H, Dv), jnp.float32))
 
 
-def _engages(q, k, causal=True):
+def _engages(q, k, causal=True, v=None):
     """`_flash_supported` as a trace sees it, for shapes."""
     seen = []
     jax.eval_shape(
-        lambda q, k: seen.append(ra._flash_supported(q, k, k, causal)),
-        q, k)
+        lambda q, k, v: seen.append(ra._flash_supported(q, k, v, causal)),
+        q, k, k if v is None else v)
     return seen[0]
 
 
@@ -45,29 +49,54 @@ def _fused(q, k, v):
                                      interpret=True)
 
 
+def _latent(q, k, v):
+    """The latent call: `flash_attention_path` with the kernels in the
+    interpreter, at the scale of q's own width."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fa, "fused_causal_attention", functools.partial(
+            fa.fused_causal_attention, interpret=True))
+        return ra.flash_attention_path(q, k, v, True,
+                                       q.shape[-1] ** -0.5)
+
+
 def _loss(f, w):
     return lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) * w)
 
 
-# (B, L, H, Hkv, D): the issue's (1, 4, 256, 128) in (B, H, L, D)
-# terms, a grouped-query case, and two that cross block borders (two
-# 512-blocks; five 128-blocks), where the masked blocks are skipped.
-# The fifth has a group of 8 q heads, which takes two grid steps of 4.
+# (B, L, H, Hkv, D[, Dv]): the issue's (1, 4, 256, 128) in
+# (B, H, L, D) terms, a grouped-query case, and two that cross block
+# borders (two 512-blocks; five 128-blocks), where the masked blocks
+# are skipped. The fifth has a group of 8 q heads, which takes two
+# grid steps of 4. Then v narrower than q / k: one block, across block
+# borders, with grouped kv, and the latent call through
+# `flash_attention_path` (192 / 128: four and two heads a step
+# unpadded, and with grouped kv, where q and k are padded to 256).
 SHAPES = [(1, 256, 4, 4, 128), (1, 256, 4, 2, 128),
           (1, 1024, 2, 1, 128), (2, 640, 2, 2, 128),
-          (1, 256, 8, 1, 128)]
+          (1, 256, 8, 1, 128),
+          (1, 256, 4, 4, 256, 128), (2, 640, 2, 2, 256, 128),
+          (1, 256, 4, 2, 256, 128), (1, 256, 4, 4, 192, 128),
+          (1, 256, 2, 2, 192, 128), (1, 256, 4, 2, 192, 128)]
+IDS = ["mha256", "gqa256", "gqa1024", "mha640", "mqa256",
+       "mha256-v128", "mha640-v128", "gqa256-v128", "latent192-v128",
+       "latent192-two-heads", "latent192-gqa-padded"]
 
 
-@pytest.mark.parametrize("shape", SHAPES,
-                         ids=["mha256", "gqa256", "gqa1024", "mha640",
-                              "mqa256"])
+def _path(shape):
+    return _fused if shape[4] % fa.LANES == 0 else _latent
+
+
+@pytest.mark.parametrize("shape", SHAPES, ids=IDS)
 def test_fused_matches_dense_forward_and_gradients(shape):
     q, k, v, w = _qkv(*shape)
+    fused = _path(shape)
+    out = fused(q, k, v)
+    assert out.shape == w.shape
     np.testing.assert_allclose(
-        np.asarray(_fused(q, k, v)),
+        np.asarray(out),
         np.asarray(dense_attention(q, k, v, causal=True)),
         rtol=2e-5, atol=2e-5)
-    got = jax.grad(_loss(_fused, w), argnums=(0, 1, 2))(q, k, v)
+    got = jax.grad(_loss(fused, w), argnums=(0, 1, 2))(q, k, v)
     want = jax.grad(_loss(dense_attention, w), argnums=(0, 1, 2))(q, k, v)
     for g, o, name in zip(got, want, "qkv"):
         assert g.shape == o.shape and g.dtype == o.dtype
@@ -75,13 +104,22 @@ def test_fused_matches_dense_forward_and_gradients(shape):
                                    rtol=1e-4, atol=1e-4, err_msg=name)
 
 
-def test_fused_bf16_within_rounding_of_dense():
-    q, k, v, w = _qkv(1, 256, 4, 2, 128, jnp.bfloat16)
+@pytest.mark.parametrize("shape", [(1, 256, 4, 2, 128),
+                                   (1, 256, 4, 2, 256, 128)],
+                         ids=["gqa256", "gqa256-v128"])
+def test_fused_bf16_within_rounding_of_dense(shape):
+    q, k, v, w = _qkv(*shape, dtype=jnp.bfloat16)
     out = _fused(q, k, v)
-    assert out.dtype == jnp.bfloat16
+    assert out.dtype == jnp.bfloat16 and out.shape == w.shape
     ref = dense_attention(q, k, v, causal=True)
     assert float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                  - ref.astype(jnp.float32)))) < 0.05
+    got = jax.grad(_loss(_fused, w), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(_loss(dense_attention, w), argnums=(0, 1, 2))(q, k, v)
+    for g, o in zip(got, want):
+        assert g.shape == o.shape and g.dtype == jnp.bfloat16
+        assert float(jnp.max(jnp.abs(g.astype(jnp.float32)
+                                     - o.astype(jnp.float32)))) < 0.25
 
 
 def test_fused_under_shard_map_with_the_replication_checker_on():
@@ -128,12 +166,72 @@ def test_heads_per_step_rule(group, heads):
     assert fa.heads_per_step(group) == heads
 
 
-# backend, (L, H, Hkv, D), kv length, causal, engages
+# q, k, v shapes and whether the kernels take them
+SUPPORTED = [
+    ((1, 256, 4, 128), (1, 256, 4, 128), (1, 256, 4, 128), True),
+    ((1, 256, 4, 256), (1, 256, 4, 256), (1, 256, 4, 128), True),
+    ((2, 640, 2, 256), (2, 640, 2, 256), (2, 640, 2, 128), True),
+    ((1, 256, 4, 256), (1, 256, 2, 256), (1, 256, 2, 128), True),
+    ((1, 256, 4, 128), (1, 256, 4, 128), (1, 256, 4, 256), True),
+    # every q head its own kv head: an even number of heads of 192
+    # side by side is a whole number of lanes
+    ((1, 256, 4, 192), (1, 256, 4, 192), (1, 256, 4, 128), True),
+    ((2, 4096, 32, 192), (2, 4096, 32, 192), (2, 4096, 32, 128), True),
+    ((1, 256, 3, 192), (1, 256, 3, 192), (1, 256, 3, 128), False),
+    ((1, 256, 4, 192), (1, 256, 2, 192), (1, 256, 2, 128), False),
+    ((1, 256, 4, 192), (1, 256, 4, 192), (1, 256, 4, 192), False),
+    ((1, 256, 4, 256), (1, 256, 4, 256), (1, 256, 4, 64), False),
+    ((1, 256, 4, 256), (1, 256, 4, 128), (1, 256, 4, 128), False),
+    ((1, 256, 4, 256), (1, 256, 4, 256), (1, 256, 2, 128), False),
+    ((1, 256, 4, 256), (1, 256, 4, 256), (1, 128, 4, 128), False),
+    ((1, 256, 4, 256), (1, 256, 4, 256), (256, 4, 128), False),
+]
+
+
+@pytest.mark.parametrize("q,k,v,takes", SUPPORTED)
+def test_supported_shapes(q, k, v, takes):
+    assert fa.supported(q, k, v) is takes
+
+
+# (H, Hkv, Dqk, Dv) -> (q heads, kv heads, steps a kv head) a grid step
+STEP_HEADS = [
+    ((32, 8, 128, 128), (4, 1, 1)),       # the Mistral cells
+    ((8, 1, 128, 128), (4, 1, 2)),
+    ((8, 2, 256, 256), (4, 1, 1)),
+    ((6, 2, 128, 128), (3, 1, 1)),
+    ((4, 2, 192, 128), None),             # grouped kv wants whole lanes
+    ((32, 32, 192, 128), (4, 4, 1)),      # the latent cell
+    ((2, 2, 192, 128), (2, 2, 1)),
+    ((3, 3, 192, 128), None),
+    ((3, 3, 128, 128), (3, 3, 1)),
+    ((32, 32, 128, 128), (4, 4, 1)),
+    ((32, 32, 256, 128), (4, 4, 1)),
+    ((32, 32, 256, 256), (2, 2, 1)),      # four do not fit VMEM
+    ((7, 7, 512, 512), (1, 1, 1)),
+]
+
+
+@pytest.mark.parametrize("heads,step", STEP_HEADS)
+def test_step_heads_rule(heads, step):
+    assert fa.step_heads(*heads) == step
+
+
+# backend, (L, H, Hkv, D[, Dv]), kv length, causal, engages
 RULE = [
     ("tpu", (2048, 32, 8, 128), None, True, True),
     ("tpu", (256, 32, 8, 128), None, True, True),     # the cells' sample
     ("tpu", (256, 32, 32, 128), None, True, True),
     ("tpu", (256, 4, 4, 256), None, True, True),
+    ("tpu", (256, 4, 4, 256, 128), None, True, True),
+    ("tpu", (640, 2, 2, 256, 128), None, True, True),
+    ("tpu", (256, 4, 2, 256, 128), None, True, True),
+    ("tpu", (256, 4, 4, 192, 128), None, True, True),   # the latent call
+    ("tpu", (256, 4, 2, 192, 128), None, True, True),   # q / k padded
+    ("tpu", (256, 3, 3, 160, 128), None, True, True),   # q / k padded
+    ("tpu", (4096, 32, 32, 192, 128), None, True, True),
+    ("tpu", (256, 4, 4, 192, 64), None, True, False),   # v no whole lanes
+    ("tpu", (256, 4, 4, 192, 192), None, True, False),  # equal, unpadded
+    ("cpu", (256, 4, 4, 192, 128), None, True, False),
     ("tpu", (200, 32, 8, 128), None, True, False),    # not in 128-blocks
     ("tpu", (512, 16, 16, 64), None, True, False),    # the flagship's heads
     ("tpu", (256, 6, 4, 128), None, True, False),     # no whole groups
@@ -148,10 +246,11 @@ RULE = [
 def test_engagement_rule(monkeypatch, backend, shape, kv_len, causal,
                          engages):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    L, H, Hkv, D = shape
+    L, H, Hkv, D = shape[:4]
     q = jax.ShapeDtypeStruct((1, L, H, D), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((1, kv_len or L, Hkv, D), jnp.bfloat16)
-    assert _engages(q, k, causal) is engages
+    v = jax.ShapeDtypeStruct((*k.shape[:3], shape[-1]), jnp.bfloat16)
+    assert _engages(q, k, causal, v) is engages
 
 
 @pytest.mark.parametrize("dtype,engages", [
@@ -197,7 +296,8 @@ def _trace_attention(*shapes, **kw):
 
 def _traces():
     snap = REGISTRY.snapshot().get("hvd_attention_traces_total", {})
-    return {path: snap.get((path,), 0.0) for path in ("fused", "dense")}
+    return {path: snap.get((path,), 0.0)
+            for path in ("fused", "fused_padded_qk", "dense")}
 
 
 def test_path_counter_counts_each_trace(monkeypatch):
@@ -215,6 +315,26 @@ def test_path_counter_counts_each_trace(monkeypatch):
     after = _traces()
     assert (after["dense"], after["fused"]) == (mid["dense"],
                                                 mid["fused"] + 1)
+
+
+def test_path_counter_names_the_padded_form(monkeypatch):
+    """Latent attention's q / k of 192 reach the kernels as they are
+    where every q head has its own kv head, and count as `fused` like
+    equal widths; with grouped kv they are zero-padded to 256 and
+    count as `fused_padded_qk`. v goes at its own 128 either way."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for width, kv_heads, path in ((192, 4, "fused"), (256, 4, "fused"),
+                                  (128, 2, "fused"),
+                                  (192, 2, "fused_padded_qk"),
+                                  (160, 1, "fused_padded_qk")):
+        q = jax.ShapeDtypeStruct((1, 256, 4, width), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((1, 256, kv_heads, width), jnp.bfloat16)
+        v = jax.ShapeDtypeStruct((1, 256, kv_heads, 128), jnp.bfloat16)
+        before = _traces()
+        assert _trace_attention(q, k, v).shape == (1, 256, 4, 128)
+        after = _traces()
+        assert {p: after[p] - before[p] for p in after} == {
+            **dict.fromkeys(after, 0.0), path: 1.0}
 
 
 @pytest.mark.parametrize("mode,backend,path", [

@@ -143,10 +143,12 @@ def test_yarn_frequencies_and_scale(cfg, reference):
 
 
 def test_fused_core_takes_latent_widths(monkeypatch):
-    """q / k 192 wide, v 128: the fused path pads all three to 256,
-    keeps the scale it is handed, and cuts the output back to 128;
-    forward and gradients agree with `dense_attention`. Equal widths
-    that are no whole number of lanes keep the dense path."""
+    """q / k 192 wide, v 128: the fused path hands the kernels all
+    three as they are (two heads of 192 are a whole number of lanes),
+    keeps the scale it is handed, and returns the kernels' 128-wide
+    output; forward and gradients agree with `dense_attention`. With
+    grouped kv, q and k are zero-padded to 256. Equal widths that are
+    no whole number of lanes keep the dense path."""
     import functools
     from horovod_tpu.parallel import fused_attention
     seen = []
@@ -170,13 +172,14 @@ def test_fused_core_takes_latent_widths(monkeypatch):
     (_, want), g_want = jax.value_and_grad(
         functools.partial(loss, ra.dense_attention), (0, 1, 2),
         has_aux=True)(q, k, v)
-    assert seen[0] == ((1, 128, 2, 256),) * 3 + (scale,)
+    assert seen[0] == ((1, 128, 2, 192),) * 2 + ((1, 128, 2, 128), scale)
     close(got, want, 2e-4)
     for a, b in zip(g_got, g_want):
         assert a.shape == b.shape
         close(a, b, 2e-4)
-    assert ra._fused_width(q, v) == 256
-    assert ra._fused_width(q[..., :64], v[..., :64]) == 64
+    assert ra._fused_qk_width(q, k, v) == 192
+    assert ra._fused_qk_width(q, k[:, :, :1], v[:, :, :1]) == 256
+    assert ra._fused_qk_width(q[..., :64], k[..., :64], v[..., :64]) == 64
 
 
 def test_attention_counts_the_latent_call_as_dense_on_the_cpu():
