@@ -45,13 +45,7 @@ recorded round says WHERE the time went, not just the rate. Every
 artifact also carries `mfu` and `compiled_gflop_per_img`
 (null when the backend can't supply them).
 
-`--compression-ab` writes the round-13 compression A/B
-(benchmarks/BENCH_compression_ab_r13.json): exact plan-derived wire
-accounting for VGG-16/the flagship transformer across the compressor
-registry plus a measured step-time A/B on this host.
-`--convergence-compression` records the error-feedback convergence
-proof (BENCH_convergence_compression_r13.json). `--trajectory`
-consolidates the committed per-round artifacts into one
+`--trajectory` consolidates the committed per-round artifacts into one
 byte-deterministic benchmarks/BENCH_trajectory.json.
 
 `--autotune` (with --model resnet50|transformer) runs the EAGER bench
@@ -728,7 +722,6 @@ def transformer_main():
         "analytic_gflop_per_token": round(analytic_per_tok / 1e9, 4),
         "profile": _profile_block(profile_dir),
         "metrics": _metrics_snapshot(),
-        "compression": _compression_block(),
         "trace": _trace_digest(),
         "journal": _journal_digest(),
         "health": _health_digest(),
@@ -866,374 +859,6 @@ def autotune_main(model: str) -> None:
         "unit": "percent",
         "vs_baseline": 1.0,
     })
-
-
-def _compression_block():
-    """The `compression` digest block every bench JSON carries: what
-    transform the wire took (the knob), the plan's exact raw-vs-wire
-    byte split for the built step, and the wire counters the run
-    actually recorded — so a recorded rate can never silently mix
-    compressed and uncompressed wire."""
-    from horovod_tpu.common import config as hvdconfig
-    from horovod_tpu.parallel.train import last_overlap_info
-    info = last_overlap_info()
-    snap = _metrics_snapshot() or {}
-    wire = {k: v for k, v in snap.items()
-            if k.startswith(("hvd_wire_bytes", "hvd_compression"))}
-    return {
-        "compression": info.get(
-            "compression",
-            hvdconfig.env_value("HOROVOD_COMPRESSION")),
-        "raw_bucket_bytes": info.get("raw_bucket_bytes"),
-        "wire_bucket_bytes": info.get("wire_bucket_bytes"),
-        "plan_digest": info.get("digest"),
-        "recorded_wire_metrics": wire or None,
-    }
-
-
-def _wire_accounting(shapes_tree, mesh, compression, rank=None):
-    """Exact plan-derived wire accounting for one (model, config):
-    the same `plan_overlap` the builder emits and HVD007 ties to the
-    traced program, over `jax.eval_shape` leaves — zero allocation.
-    Returns totals plus the dense-bucket (compressed-family) split
-    the >=4x acceptance gate reads."""
-    from horovod_tpu.parallel.train import plan_overlap
-    plan = plan_overlap(shapes_tree, mesh, guard=True,
-                        compression=compression,
-                        compression_rank=rank)
-    raw = wire = d_raw = d_wire = 0
-    loose = sum(
-        int(np.prod(l.shape)) * l.dtype.itemsize
-        for i, l in enumerate(jax.tree_util.tree_leaves(shapes_tree))
-        if i in set(plan.loose_inexact))
-    for b, groups in enumerate(plan.wire):
-        braw = plan.bucket_nbytes[b]
-        bwire = sum(int(g.n) * jnp.dtype(g.dtype).itemsize
-                    for g in groups)
-        raw += braw
-        wire += bwire
-        if plan.bucket_compression[b] != "none":
-            d_raw += braw
-            d_wire += bwire
-    return {
-        "plan_digest": plan.digest,
-        "buckets": len(plan.wire),
-        "compressed_buckets": sum(
-            1 for t in plan.bucket_compression if t != "none"),
-        "raw_mb": round(raw / 1e6, 3),
-        "wire_mb": round(wire / 1e6, 3),
-        "loose_exact_mb": round(loose / 1e6, 3),
-        "total_wire_mb": round((wire + loose) / 1e6, 3),
-        "dense_raw_mb": round(d_raw / 1e6, 3),
-        "dense_wire_mb": round(d_wire / 1e6, 3),
-        "dense_reduction_x": (round(d_raw / d_wire, 2)
-                              if d_wire else None),
-        "total_reduction_x": (round((raw + loose) / (wire + loose), 2)
-                              if wire + loose else None),
-        "total_wire_bytes": int(wire + loose),
-    }
-
-
-def _tiny_transformer(d_model=256, n_layers=4, n_heads=8, d_ff=1024,
-                      vocab=2048, seq=128):
-    """The r08 A/B's CPU-container transformer config — small enough
-    to time on this host, all-dense enough that every weight matrix
-    is PowerSGD-eligible at the default min_elements."""
-    from horovod_tpu.models import transformer as tfm
-    return tfm.TransformerConfig(
-        vocab=vocab, d_model=d_model, n_layers=n_layers,
-        n_heads=n_heads, n_kv_heads=n_heads,
-        head_dim=d_model // n_heads, d_ff=d_ff, max_seq=seq,
-        moe=False, dtype=jnp.float32, remat=False,
-        tp_axis=None, sp_axis=None, ep_axis=None)
-
-
-def compression_ab_main() -> None:
-    """`--compression-ab`: the round-13 compression A/B artifact.
-
-    Two legs, honestly separated like the r08 wire-gate artifact:
-    (1) EXACT wire accounting for the committed headline models
-    (VGG-16, flagship transformer) from `plan_overlap` over
-    `jax.eval_shape` init — the >=4x dense-bucket acceptance gate
-    reads this leg; it is the same accounting HVD007 machine-ties to
-    the traced program. (2) a MEASURED step-time A/B on this host
-    (the r08 CPU-container transformer config): single-host wire is
-    shared memory, so the delta isolates the compression compute tax
-    (Gram orthogonalization + factor matmuls) — the wire win at
-    scale is leg 1's number and is not measured. Output:
-    BENCH_COMPRESSION_OUT (default
-    benchmarks/BENCH_compression_ab_r13.json)."""
-    from horovod_tpu.models import transformer as tfm
-    from horovod_tpu.models.vgg import create_vgg16, init_vgg
-    from horovod_tpu.parallel.train import init_compression_state
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    out_path = os.environ.get("BENCH_COMPRESSION_OUT") or os.path.join(
-        here, "benchmarks", "BENCH_compression_ab_r13.json")
-    steps = int(os.environ.get("BENCH_STEPS", "12"))
-    warmup = int(os.environ.get("BENCH_WARMUP", "2"))
-    seq = int(os.environ.get("BENCH_SEQ", "128"))
-    batch_per_chip = int(os.environ.get("BENCH_BATCH", "2"))
-
-    hvd.init()
-    mesh = data_parallel_mesh()
-    n_chips = mesh.devices.size
-    global_batch = batch_per_chip * n_chips
-
-    # --- leg 1: exact plan accounting over the real headline models
-    vgg_shapes = jax.eval_shape(
-        lambda k: init_vgg(create_vgg16(dtype=jnp.bfloat16), k, 224),
-        jax.random.PRNGKey(0))["params"]
-    flag_cfg = tfm.TransformerConfig(
-        vocab=32768, d_model=1024, n_layers=24, n_heads=16,
-        n_kv_heads=16, head_dim=64, d_ff=4096, max_seq=512,
-        moe=False, dtype=jnp.bfloat16, remat=True,
-        tp_axis=None, sp_axis=None, ep_axis=None)
-    flag_shapes = jax.eval_shape(
-        lambda k: tfm.init_params(flag_cfg, k), jax.random.PRNGKey(0))
-    configs = (("none", None), ("fp16", None), ("bf16", None),
-               ("powersgd", 1), ("powersgd", 2), ("powersgd", 4))
-    accounting = {}
-    for name, shapes in (("vgg16", vgg_shapes),
-                         ("flagship_transformer", flag_shapes)):
-        accounting[name] = {}
-        for comp, rank in configs:
-            tag = comp if rank is None else f"{comp}:{rank}"
-            accounting[name][tag] = _wire_accounting(
-                shapes, mesh, comp, rank)
-            log(f"bench[compression]: {name} {tag} dense "
-                f"{accounting[name][tag]['dense_reduction_x']}x "
-                f"total {accounting[name][tag]['total_reduction_x']}x")
-
-    vgg4 = accounting["vgg16"]["powersgd:4"]["dense_reduction_x"]
-    flag4 = (accounting["flagship_transformer"]["powersgd:4"]
-             ["dense_reduction_x"])
-    acceptance = {
-        "claim": ">=4x wire-bytes reduction on the VGG-16/"
-                 "transformer dense-matrix buckets at rank <= 4",
-        "vgg16_rank4_dense_reduction_x": vgg4,
-        "flagship_rank4_dense_reduction_x": flag4,
-        "passes": bool(vgg4 and flag4 and vgg4 >= 4.0
-                       and flag4 >= 4.0),
-    }
-
-    # --- leg 2: measured step-time A/B on this host ----------------
-    cfg = _tiny_transformer(seq=seq)
-    params = tfm.init_params(cfg, jax.random.PRNGKey(0))
-    opt = optax.adamw(1e-4)
-    opt_state = opt.init(params)
-    rng = np.random.default_rng(0)
-    tokens = jax.device_put(
-        jnp.asarray(rng.integers(0, cfg.vocab, (global_batch, seq)),
-                    jnp.int32), NamedSharding(mesh, P("data")))
-    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, axis=1)}
-    loss = lambda p, b: tfm.loss_fn(cfg, p, b)  # noqa: E731
-    bspec = {"tokens": P("data"), "targets": P("data")}
-
-    def timed(step, *state):
-        out = step(*state, batch) if len(state) == 2 else \
-            step(state[0], state[1], batch, state[2])
-        jax.block_until_ready(out)
-        for _ in range(warmup - 1):
-            out = (step(out[0], out[1], batch) if len(state) == 2
-                   else step(out[0], out[1], batch, out[3]))
-        jax.block_until_ready(out)
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            out = (step(out[0], out[1], batch) if len(state) == 2
-                   else step(out[0], out[1], batch, out[3]))
-        jax.block_until_ready(out)
-        return (time.perf_counter() - t0) / steps * 1e3
-
-    step_a = build_train_step(loss, opt, mesh, batch_spec=bspec,
-                              donate=False)
-    exact_ms = timed(step_a, params, opt_state)
-    step_b = build_train_step(loss, opt, mesh, batch_spec=bspec,
-                              donate=False, compression="powersgd",
-                              compression_rank=4)
-    cstate, _ = init_compression_state(
-        params, mesh, compression="powersgd", compression_rank=4)
-    comp_ms = timed(step_b, params, opt_state, cstate)
-    # after the timed run: last_overlap_info now reflects step_b's
-    # trace and the wire counters recorded the compressed submissions
-    b_block = _compression_block()
-    delta_pct = (comp_ms - exact_ms) / exact_ms * 100.0
-    log(f"bench[compression]: measured exact {exact_ms:.1f} ms "
-        f"powersgd:4 {comp_ms:.1f} ms ({delta_pct:+.1f}%)")
-
-    doc = {
-        "recorded": "2026-08-04 (round 13, CPU container: "
-                    "JAX_PLATFORMS=cpu; no TPU access this round)",
-        "what": "Gradient-compression A/B: exact plan-derived wire "
-                "accounting for the committed headline models (the "
-                ">=4x acceptance gate) + a measured step-time A/B "
-                "on this host isolating the compression compute "
-                "tax. Single-host wire is shared memory, so the "
-                "wire win materializes at scale (not measured).",
-        "wire_accounting": accounting,
-        "acceptance": acceptance,
-        "measured_step_time": {
-            "config": f"r08 CPU transformer config (d256 L4 h8 "
-                      f"ff1024 vocab2048 seq{seq}), "
-                      f"global_batch={global_batch}, "
-                      f"devices={n_chips}, steps={steps}",
-            "exact_ms_per_step": round(exact_ms, 2),
-            "powersgd4_ms_per_step": round(comp_ms, 2),
-            "delta_pct": round(delta_pct, 2),
-            "note": "compute-tax only on this host (wire is shared "
-                    "memory at world %d-on-1); the r13 projection "
-                    "prices the wire win with this tax included"
-                    % n_chips,
-            "compression": b_block,
-        },
-    }
-    os.makedirs(os.path.dirname(out_path), exist_ok=True)
-    with open(out_path, "w") as f:
-        json.dump(doc, f, indent=1, sort_keys=True)
-        f.write("\n")
-    log(f"bench[compression]: artifact written to {out_path}")
-    emit({
-        "metric": "compression_ab_vgg16_rank4_dense_reduction",
-        "value": vgg4, "unit": "x_wire_bytes",
-        "vs_baseline": 1.0})
-
-
-def convergence_compression_main() -> None:
-    """`--convergence-compression`: train the same model twice on
-    identical fixed data — exact wire vs powersgd:2 with error
-    feedback (after the documented HOROVOD_COMPRESSION_WARMUP_STEPS
-    harness switch) — and record that the compressed run reaches the
-    uncompressed loss target within stated tolerance. Error feedback
-    is the load-bearing part: rank-2 factors alone lose most of the
-    gradient; the residual accumulator returns it over steps
-    (Karimireddy et al., ICML 2019). Output: BENCH_CONVERGENCE_OUT
-    (default benchmarks/BENCH_convergence_compression_r13.json)."""
-    from horovod_tpu.models import transformer as tfm
-    from horovod_tpu.parallel.train import init_compression_state
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    out_path = (os.environ.get("BENCH_CONVERGENCE_OUT")
-                or os.path.join(
-                    here, "benchmarks",
-                    "BENCH_convergence_compression_r13.json"))
-    steps = int(os.environ.get("BENCH_STEPS", "80"))
-    warmup_steps = int(os.environ.get(
-        "BENCH_COMPRESSION_WARMUP", "5"))
-    tol = float(os.environ.get("BENCH_CONVERGENCE_TOL", "0.10"))
-
-    hvd.init()
-    mesh = data_parallel_mesh()
-    n_chips = mesh.devices.size
-    seq = 64
-    global_batch = 2 * n_chips
-    cfg = _tiny_transformer(d_model=128, n_layers=2, n_heads=4,
-                            d_ff=512, vocab=512, seq=seq)
-    rng = np.random.default_rng(7)
-    tokens = jax.device_put(
-        jnp.asarray(rng.integers(0, cfg.vocab, (global_batch, seq)),
-                    jnp.int32), NamedSharding(mesh, P("data")))
-    batch = {"tokens": tokens, "targets": jnp.roll(tokens, -1, axis=1)}
-    loss = lambda p, b: tfm.loss_fn(cfg, p, b)  # noqa: E731
-    bspec = {"tokens": P("data"), "targets": P("data")}
-    opt = optax.adam(1e-3)
-
-    def curve_exact():
-        params = tfm.init_params(cfg, jax.random.PRNGKey(1))
-        opt_state = opt.init(params)
-        step = build_train_step(loss, opt, mesh, batch_spec=bspec,
-                                donate=False)
-        losses = []
-        for _ in range(steps):
-            params, opt_state, m = step(params, opt_state, batch)
-            losses.append(float(m["loss"]))
-        return losses
-
-    budget = int(os.environ.get("BENCH_COMPRESSION_BUDGET_X", "4"))
-
-    def curve_compressed(target):
-        params = tfm.init_params(cfg, jax.random.PRNGKey(1))
-        opt_state = opt.init(params)
-        exact = build_train_step(loss, opt, mesh, batch_spec=bspec,
-                                 donate=False)
-        comp = build_train_step(loss, opt, mesh, batch_spec=bspec,
-                                donate=False, compression="powersgd",
-                                compression_rank=2,
-                                compression_min_elements=1024)
-        cstate, _ = init_compression_state(
-            params, mesh, compression="powersgd",
-            compression_rank=2, compression_min_elements=1024)
-        losses = []
-        for i in range(steps * budget):
-            if i < warmup_steps:     # the documented harness switch
-                params, opt_state, m = exact(params, opt_state, batch)
-            else:
-                params, opt_state, m, cstate = comp(
-                    params, opt_state, batch, cstate)
-            losses.append(float(m["loss"]))
-            if losses[-1] <= target:
-                break
-        res_norm = float(np.sqrt(sum(
-            float((np.asarray(e, np.float64) ** 2).sum())
-            for e in cstate["e"].values())))
-        return losses, res_norm
-
-    exact_losses = curve_exact()
-    final_exact = exact_losses[-1]
-    # The uncompressed final loss defines the target; error feedback
-    # guarantees the same asymptote at a (boundedly) slower rate
-    # (Karimireddy et al.), so the compressed run gets a stated step
-    # budget — budget_x times the exact run — to reach it.
-    target = final_exact + tol
-    comp_losses, res_norm = curve_compressed(target)
-    final_comp = comp_losses[-1]
-    converged = final_comp <= target
-    log(f"bench[convergence]: exact {final_exact:.4f} in {steps} "
-        f"steps; powersgd:2+EF reached {final_comp:.4f} in "
-        f"{len(comp_losses)} steps (target {target:.4f}, budget "
-        f"{steps * budget}) -> {'OK' if converged else 'MISS'}")
-
-    doc = {
-        "benchmark": "transformer_memorization_convergence_"
-                     "compression",
-        "recorded": "2026-08-04 (round 13, CPU container)",
-        "what": "Same init, same fixed batch, same optimizer; the "
-                "only difference is the gradient wire: exact f32 vs "
-                "PowerSGD rank-2 factors with error feedback after "
-                "a %d-step exact warmup. The uncompressed final "
-                "loss (+tolerance) defines the target; error "
-                "feedback guarantees the same asymptote at a "
-                "boundedly slower rate, so the compressed run gets "
-                "a %dx step budget to reach it and records the "
-                "steps it actually took." % (warmup_steps, budget),
-        "config": "transformer d128 L2 h4 ff512 vocab512 seq64, "
-                  "global_batch=%d, devices=%d, adam(1e-3)"
-                  % (global_batch, n_chips),
-        "steps": steps,
-        "steps_compressed": len(comp_losses),
-        "step_budget_compressed": steps * budget,
-        "compression": "powersgd:2",
-        "warmup_steps": warmup_steps,
-        "min_elements": 1024,
-        "final_loss_exact": round(final_exact, 4),
-        "final_loss_compressed": round(final_comp, 4),
-        "loss_target": round(final_exact + tol, 4),
-        "tolerance_abs": tol,
-        "converged": bool(converged),
-        "final_residual_norm": round(res_norm, 4),
-        "curve_every_10": {
-            "exact": [round(v, 4) for v in exact_losses[::10]],
-            "compressed": [round(v, 4) for v in comp_losses[::10]],
-        },
-    }
-    os.makedirs(os.path.dirname(out_path), exist_ok=True)
-    with open(out_path, "w") as f:
-        json.dump(doc, f, indent=1, sort_keys=True)
-        f.write("\n")
-    log(f"bench[convergence]: artifact written to {out_path}")
-    emit({
-        "metric": "compression_convergence_final_loss_delta",
-        "value": round(final_comp - final_exact, 4),
-        "unit": "nats", "vs_baseline": 1.0})
 
 
 def _regen_serving_attribution(here):
@@ -2261,7 +1886,7 @@ DETERMINISTIC_ENTRYPOINTS = ("trajectory_main",)
 def trajectory_main() -> None:
     """`--trajectory`: consolidate the committed per-round artifacts
     into one byte-deterministic BENCH_trajectory.json — the headline
-    r06->r20 story in a single file. Reads ONLY committed artifacts (no
+    r08->r20 story in a single file. Reads ONLY committed artifacts (no
     clocks, no env), writes with sorted keys — rerunning on the same
     tree reproduces the bytes exactly; this path is on hvdlint
     HVD009's byte-determinism beat via DETERMINISTIC_ENTRYPOINTS."""
@@ -2288,42 +1913,11 @@ def trajectory_main() -> None:
                 "them: rerunning --trajectory reproduces it "
                 "byte-for-byte.",
         "generated_by": "python bench.py --trajectory",
-        "r06_overlap_ab": {
-            "hidden_comm_fraction": read(
-                "benchmarks/BENCH_overlap_ab_r06.json",
-                "overlap", "hidden_comm_fraction"),
-            "exposed_comm_fraction": read(
-                "benchmarks/BENCH_overlap_ab_r06.json",
-                "overlap", "exposed_comm_fraction"),
-            "note": "world-1 schedule placement: the win is wire-"
-                    "time hiding, not measured at scale",
-            "source": "benchmarks/BENCH_overlap_ab_r06.json",
-        },
         "r08_wire_gate_ab": {
             "resnet_delta_pct": read(
                 "benchmarks/BENCH_wiregate_ab_r08.json",
                 "resnet_stash_ab", "delta_pct"),
             "source": "benchmarks/BENCH_wiregate_ab_r08.json",
-        },
-        "r13_compression_ab": {
-            "vgg16_rank4_dense_reduction_x": read(
-                "benchmarks/BENCH_compression_ab_r13.json",
-                "acceptance", "vgg16_rank4_dense_reduction_x"),
-            "flagship_rank4_dense_reduction_x": read(
-                "benchmarks/BENCH_compression_ab_r13.json",
-                "acceptance", "flagship_rank4_dense_reduction_x"),
-            "convergence_final_loss_delta": (
-                None if read("benchmarks/"
-                             "BENCH_convergence_compression_r13.json",
-                             "final_loss_compressed") is None
-                else round(
-                    read("benchmarks/"
-                         "BENCH_convergence_compression_r13.json",
-                         "final_loss_compressed")
-                    - read("benchmarks/"
-                           "BENCH_convergence_compression_r13.json",
-                           "final_loss_exact"), 4)),
-            "source": "benchmarks/BENCH_compression_ab_r13.json",
         },
         "r15_serving": {
             "p99_ms_at_100qps": read(
@@ -2454,49 +2048,8 @@ def trajectory_main() -> None:
     log(f"bench[trajectory]: written to {out_path}")
     emit({
         "metric": "trajectory_rounds_recorded",
-        "value": 8, "unit": "rounds",
+        "value": 6, "unit": "rounds",
         "vs_baseline": 1.0})
-
-
-def _overlap_ab_requested() -> bool:
-    """--overlap-ab / BENCH_OVERLAP=ab: run the jit bench twice
-    (bucketed overlap on, then off) and record the A/B in the JSON's
-    `overlap` block, plus the probe's exposed-comm fraction."""
-    return ("--overlap-ab" in sys.argv
-            or os.environ.get("BENCH_OVERLAP", "") == "ab")
-
-
-def _probe_overlap_stats(build_step, params, opt_state, batch,
-                         probe_steps: int = 8):
-    """Bucket plan + schedule-placement accounting from a short
-    probed run: builds the step once more with a tracing.OverlapProbe
-    attached (callbacks cost host time, so this run is SEPARATE from
-    the timed loops), arms it after one compile/warmup call, and
-    reads the exposed-comm fraction — the share of bucket-reduce wall
-    time past the last bucket's cotangent-ready edge, i.e. the tail
-    no schedule can hide. Non-donating build so the caller's buffers
-    survive."""
-    from horovod_tpu import tracing
-    from horovod_tpu.parallel.train import last_overlap_info
-    probe = tracing.OverlapProbe()
-    step = build_step(overlap=True, overlap_probe=probe, donate=False)
-    out = step(params, opt_state, batch)          # compile: unrecorded
-    jax.block_until_ready(out)
-    info = last_overlap_info()
-    probe.armed = True
-    for _ in range(probe_steps):
-        t0 = time.monotonic_ns()
-        out = step(params, opt_state, batch)
-        jax.block_until_ready(out)
-        probe.step_span(t0, time.monotonic_ns())
-    probe.armed = False
-    stats = {"overlap_enabled": bool(info.get("enabled")),
-             "buckets": info.get("buckets"),
-             "bucket_bytes": info.get("bucket_bytes"),
-             "threshold_bytes": info.get("threshold"),
-             "n_grad_leaves": info.get("n_leaves")}
-    stats.update(probe.hidden_fraction())
-    return stats, probe
 
 
 def main(model_name: str = "resnet50"):
@@ -2576,20 +2129,11 @@ def main(model_name: str = "resnet50"):
     opt = optax.sgd(0.0125 * n_chips, momentum=0.9)
     opt_state = opt.init(params)
 
-    def build_step(**overrides):
-        kw = dict(batch_spec={"images": P("data"), "labels": P("data"),
-                              "batch_stats": P()},
-                  loss_has_aux=True, donate=True)
-        kw.update(overrides)
-        return build_train_step(loss_fn, opt, mesh, **kw)
-
-    step = build_step()
-    # Effective overlap of the HEADLINE program (knob default may be
-    # off, or the jax band unsupported): build_train_step records it
-    # at build time; captured here before any other build resets it.
-    from horovod_tpu.parallel.train import last_overlap_info
-    headline_overlap = bool(last_overlap_info().get("enabled"))
-    compression_block = _compression_block()
+    step = build_train_step(
+        loss_fn, opt, mesh,
+        batch_spec={"images": P("data"), "labels": P("data"),
+                    "batch_stats": P()},
+        loss_has_aux=True, donate=True)
 
     rng = np.random.default_rng(0)
     images = jnp.asarray(
@@ -2668,80 +2212,6 @@ def main(model_name: str = "resnet50"):
             f"{flops_per_step / global_batch / 1e9:.1f} GFLOP/img "
             f"compiled)")
 
-    overlap_block = None
-    if _overlap_ab_requested():
-        # A/B: the headline loop above ran with the shipped default
-        # (overlap ON). Probe the bucket plan + exposed-comm fraction
-        # on a separate short run (callbacks are not free), then time
-        # the overlap-OFF (monolithic end-of-step reduction) program
-        # under the same warmup discipline.
-        batch = {"images": images, "labels": labels,
-                 "batch_stats": batch_stats}
-        stats, _ = _probe_overlap_stats(build_step, params, opt_state,
-                                        batch)
-        step_off, _ = aot_compile(build_step(overlap=False),
-                                  params, opt_state, batch)
-
-        def run_off(params, opt_state, batch_stats):
-            b = {"images": images, "labels": labels,
-                 "batch_stats": batch_stats}
-            params, opt_state, m = step_off(params, opt_state, b)
-            return params, opt_state, m["aux"], m["loss"]
-
-        for _ in range(warmup):
-            params, opt_state, batch_stats, loss = run_off(
-                params, opt_state, batch_stats)
-        t0 = time.perf_counter()
-        for _ in range(steps):
-            params, opt_state, batch_stats, loss = run_off(
-                params, opt_state, batch_stats)
-        float(loss)
-        dt_off = time.perf_counter() - t0
-        off_chip = global_batch * steps / dt_off / n_chips
-        overlap_block = dict(stats)
-        overlap_block["on_leg_overlap_enabled"] = headline_overlap
-        if not headline_overlap:
-            # The 'on' leg is the headline loop (shipped default): if
-            # the knob or the jax band disabled overlap there, BOTH
-            # timed legs ran the identical monolithic program — say so
-            # instead of publishing a vacuous A/B as a hiding proof
-            # (the probe forces overlap=True, so its bucket stats
-            # describe a program the headline never executed).
-            overlap_block["note"] = (
-                "overlap disabled on the headline leg "
-                "(HOROVOD_JIT_OVERLAP=0 or unsupported jax band): "
-                "both timed legs ran the monolithic reduction — the "
-                "rates below are a null A/B, and the bucket/"
-                "exposed_comm stats describe the forced-overlap probe "
-                "program only")
-        overlap_block.update({
-            "on_img_sec_per_chip": round(img_sec_chip, 2),
-            "off_img_sec_per_chip": round(off_chip, 2),
-            "delta_pct": round((img_sec_chip / off_chip - 1) * 100, 2)
-            if off_chip else 0.0,
-            "world_size": hvd.size(),
-        })
-        if hvd.size() <= 1 and n_chips <= 1:
-            overlap_block["roofline_note"] = (
-                "world_size 1: since the r08 wire gate, leaves whose "
-                "reduce axes multiply out to one device are never "
-                "bucketed (their psum is the identity — packing them "
-                "was pure overhead: +41 dead instructions on the "
-                "world-1 transformer step, +5.4% jit ResNet "
-                "throughput from eliding them), so BOTH legs lower "
-                "the identical monolithic program and the on/off "
-                "rates are equal by construction. The overlap's win "
-                "is wire-time hiding, which needs wire: probe "
-                "exposed_comm_fraction / the merged timeline at "
-                "world>1, where item 2's efficiency curve is "
-                "dominated by the end-of-step serialization the "
-                "buckets remove.")
-        log(f"bench: overlap A/B on={img_sec_chip:.1f} "
-            f"off={off_chip:.1f} img/s/chip "
-            f"({overlap_block['delta_pct']:+.2f}%) "
-            f"buckets={stats.get('buckets')} "
-            f"exposed_comm={stats.get('exposed_comm_fraction')}")
-
     metric = f"{model_name}_synthetic_train_img_sec_per_chip"
     doc = {
         "metric": metric,
@@ -2752,13 +2222,10 @@ def main(model_name: str = "resnet50"):
         "compiled_gflop_per_img": gflop_per_img,
         "profile": _profile_block(profile_dir),
         "metrics": _metrics_snapshot(),
-        "compression": compression_block,
         "trace": _trace_digest(),
         "journal": _journal_digest(),
         "health": _health_digest(),
     }
-    if overlap_block is not None:
-        doc["overlap"] = overlap_block
     emit(doc)
 
 
@@ -2789,10 +2256,6 @@ if __name__ == "__main__":
         health_main()
     elif "--serving" in sys.argv:
         serving_main()
-    elif "--compression-ab" in sys.argv:
-        compression_ab_main()
-    elif "--convergence-compression" in sys.argv:
-        convergence_compression_main()
     elif "--trajectory" in sys.argv:
         trajectory_main()
     elif "--autotune" in sys.argv:

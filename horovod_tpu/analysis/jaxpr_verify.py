@@ -6,9 +6,9 @@ and round 8 proved it real twice (dead size-1-axis psums shipped at
 world 1; the legacy psum-transpose gradient over-count — both
 IR-level defects no AST pass can express). This module closes it: it
 builds the repo's REAL step builders (`parallel.train.STEP_BUILDERS`)
-across a config matrix — world size 1/2/8 x overlap on/off x numerics
-on/off, plus a multi-axis mesh, a trivial-axis mesh, a bf16
-separate-vote config, and the eager grouped-allreduce plan — traces
+across a config matrix — world size 1/2/8 x numerics on/off, plus a
+multi-axis mesh, a trivial-axis mesh, a bf16 separate-vote config, a
+bf16 wire cast, and the eager grouped-allreduce plan — traces
 each to a closed jaxpr with `jax.make_jaxpr` under a `Mesh` context
 (optimizer state shapes via `jax.eval_shape`; zero FLOPs, no
 accelerator needed, works on a laptop), and walks the jaxprs with the
@@ -68,13 +68,10 @@ class StepConfig:
     name: str
     kind: str = "jit"                 # "jit" | "eager-plan"
     mesh_axes: Tuple[Tuple[str, int], ...] = (("data", 1),)
-    overlap: bool = True
     numerics: bool = False
     dtype: str = "float32"
     threshold: int = _THRESHOLD
-    # Per-bucket wire compression ("none"/"fp16"/"bf16"/
-    # "powersgd:r"). Compressed cells trace with min_elements=1 so
-    # the 16-element chain weights qualify for the low-rank path.
+    # Per-bucket wire cast ("none"/"fp16"/"bf16").
     compression: str = "none"
 
     @property
@@ -86,51 +83,35 @@ class StepConfig:
 
 
 def default_matrix() -> List[StepConfig]:
-    """The builder matrix: every (world, overlap, numerics) cell plus
+    """The builder matrix: every (world, numerics) cell plus
     the shapes that historically hid bugs — a multi-axis mesh (chained
     per-axis psums), a mesh carrying a trivial (size-1) axis (the
     wire-gate class), a bf16 model (flag cannot ride a lossy-count
-    wire: the separate exact f32 vote psum leg), and the eager
-    grouped-allreduce plan."""
+    wire: the separate exact f32 vote psum leg), a bf16 wire cast
+    (check (e)), and the eager grouped-allreduce plan."""
     out: List[StepConfig] = []
     for world in _WORLDS:
-        for overlap in (True, False):
-            for numerics in (False, True):
-                out.append(StepConfig(
-                    name=(f"world={world},overlap="
-                          f"{'on' if overlap else 'off'},numerics="
-                          f"{'on' if numerics else 'off'}"),
-                    mesh_axes=(("data", world),),
-                    overlap=overlap, numerics=numerics))
+        for numerics in (False, True):
+            out.append(StepConfig(
+                name=(f"world={world},numerics="
+                      f"{'on' if numerics else 'off'}"),
+                mesh_axes=(("data", world),), numerics=numerics))
     out.append(StepConfig(
-        name="world=8,mesh=data4xseq2,overlap=on,numerics=on",
-        mesh_axes=(("data", 4), ("seq", 2)),
-        overlap=True, numerics=True))
+        name="world=8,mesh=data4xseq2,numerics=on",
+        mesh_axes=(("data", 4), ("seq", 2)), numerics=True))
     out.append(StepConfig(
-        name="world=2,mesh=data2xtensor1,overlap=on,numerics=on",
-        mesh_axes=(("data", 2), ("tensor", 1)),
-        overlap=True, numerics=True))
+        name="world=2,mesh=data2xtensor1,numerics=on",
+        mesh_axes=(("data", 2), ("tensor", 1)), numerics=True))
     out.append(StepConfig(
-        name="world=2,overlap=on,numerics=on,dtype=bfloat16",
-        mesh_axes=(("data", 2),),
-        overlap=True, numerics=True, dtype="bfloat16"))
-    # Compressed-wire cells (check (e)): the finite-flag vote must be
-    # a separate exact f32 psum — never ride a lossy carrier — and
-    # the factor/cast wire groups must still match the plan in
+        name="world=2,numerics=on,dtype=bfloat16",
+        mesh_axes=(("data", 2),), numerics=True, dtype="bfloat16"))
+    # Cast-wire cell (check (e)): the finite-flag vote must be a
+    # separate exact f32 psum — never ride a lossy carrier — and the
+    # cast wire groups must still match the plan in
     # reverse-topological order.
     out.append(StepConfig(
-        name="world=2,overlap=on,numerics=on,compression=powersgd:2",
-        mesh_axes=(("data", 2),),
-        overlap=True, numerics=True, compression="powersgd:2"))
-    out.append(StepConfig(
-        name="world=2,overlap=on,numerics=on,compression=bf16",
-        mesh_axes=(("data", 2),),
-        overlap=True, numerics=True, compression="bf16"))
-    out.append(StepConfig(
-        name="world=8,mesh=data4xseq2,overlap=on,numerics=on,"
-             "compression=powersgd:2",
-        mesh_axes=(("data", 4), ("seq", 2)),
-        overlap=True, numerics=True, compression="powersgd:2"))
+        name="world=2,numerics=on,compression=bf16",
+        mesh_axes=(("data", 2),), numerics=True, compression="bf16"))
     out.append(StepConfig(name="eager-plan,threshold=80",
                           kind="eager-plan", threshold=80))
     out.append(StepConfig(name="eager-plan,threshold=0",
@@ -207,32 +188,20 @@ def _trace_once(cfg: StepConfig, mesh):
     batch = jax.ShapeDtypeStruct((8, 4), params["layer0"]["w"].dtype)
     opt = optax.sgd(0.1)
     opt_state = jax.eval_shape(opt.init, params)
-    cme = 1 if cfg.compression != "none" else None
     saved = _numerics.guard_enabled
     _numerics.guard_enabled = lambda: cfg.numerics
     try:
         step = build_train_step(
             _chain_loss, opt, mesh, donate=False,
-            overlap=cfg.overlap, overlap_threshold=cfg.threshold,
-            compression=cfg.compression,
-            compression_min_elements=cme)
-        if cfg.compression.startswith("powersgd"):
-            from ..parallel.train import init_compression_state
-            cstate, _specs = init_compression_state(
-                params, mesh, overlap_threshold=cfg.threshold,
-                guard=cfg.numerics, compression=cfg.compression,
-                compression_min_elements=cme)
-            jaxpr = jax.make_jaxpr(step)(params, opt_state, batch,
-                                         cstate)
-        else:
-            jaxpr = jax.make_jaxpr(step)(params, opt_state, batch)
+            overlap_threshold=cfg.threshold,
+            compression=cfg.compression)
+        jaxpr = jax.make_jaxpr(step)(params, opt_state, batch)
     finally:
         _numerics.guard_enabled = saved
     plan = plan_overlap(params, mesh,
                         overlap_threshold=cfg.threshold,
                         guard=cfg.numerics,
-                        compression=cfg.compression,
-                        compression_min_elements=cme)
+                        compression=cfg.compression)
     return R.collect_collectives(jaxpr), plan
 
 
@@ -257,15 +226,10 @@ def verify_step_config(cfg: StepConfig) -> List[str]:
                                 R.signature(ops_b))
     msgs += R.check_axes(ops_a, mesh_shape)
     msgs += R.check_dead(ops_a)
-    msgs += R.check_double_reduce(
-        ops_a, exempt=R.compressed_wire_positions(
-            ops_a, plan if cfg.overlap else None))
-    if cfg.overlap:
-        msgs += R.check_plan(ops_a, plan, mesh_shape)
-        msgs += R.check_compression(ops_a, plan, mesh_shape,
-                                    cfg.numerics)
-    msgs += R.check_numerics(ops_a, plan if cfg.overlap else None,
-                             mesh_shape, cfg.numerics)
+    msgs += R.check_double_reduce(ops_a)
+    msgs += R.check_plan(ops_a, plan, mesh_shape)
+    msgs += R.check_compression(ops_a, plan, mesh_shape, cfg.numerics)
+    msgs += R.check_numerics(ops_a, plan, mesh_shape, cfg.numerics)
     return msgs
 
 
@@ -329,8 +293,7 @@ def verify_traced(fn, example_args: Sequence[Any],
     msgs: List[str] = []
     msgs += R.check_axes(ops, mesh_shape)
     msgs += R.check_dead(ops)
-    msgs += R.check_double_reduce(
-        ops, exempt=R.compressed_wire_positions(ops, plan))
+    msgs += R.check_double_reduce(ops)
     if plan is not None:
         msgs += R.check_plan(ops, plan, mesh_shape)
         msgs += R.check_compression(ops, plan, mesh_shape,

@@ -295,59 +295,14 @@ def check_dead(ops: Sequence[CollectiveOp]) -> List[str]:
         for op in ops if op.dead]
 
 
-def compressed_wire_positions(ops: Sequence[CollectiveOp],
-                              plan) -> Set[int]:
-    """Trace positions of the psums matched to PowerSGD buckets' wire
-    groups. The low-rank handshake is a deliberate DEPENDENT double
-    reduction — the Q' factor psum's operand (M^T @ orth(P_reduced))
-    is bilinear in the already-reduced P — so these positions are
-    exempt from check_double_reduce's linear-flow approximation
-    (which would otherwise flag every PowerSGD bucket as the r08
-    over-count shape)."""
-    if plan is None:
-        return set()
-    comp = tuple(getattr(plan, "bucket_compression", ()) or ())
-    if not any(t.startswith("powersgd") for t in comp):
-        return set()
-    internal = _chain_internal(ops)
-    by_out = {o.out_id: o for o in ops if o.prim in REDUCE_PRIMS}
-    used: Set[int] = set()
-    for b, groups in enumerate(plan.wire):
-        if not comp[b].startswith("powersgd"):
-            continue
-        raxes = frozenset(plan.bucket_raxes[b])
-        for g in groups:
-            want_shape = (g.natural_shape if g.natural_shape
-                          is not None else (g.n,))
-            got = _match_wire(ops, want_shape, g.dtype, raxes, used,
-                              internal)
-            # exempt the whole chain, not just the terminal — on a
-            # multi-axis mesh the one-psum-per-axis chain's inner
-            # links inherit the dependent-reduction fact too
-            while got is not None:
-                nxt = None
-                for iid in got.in_ids:
-                    if iid in by_out:
-                        nxt = by_out[iid]
-                        used.add(nxt.pos)
-                        break
-                got = nxt
-    return used
-
-
-def check_double_reduce(ops: Sequence[CollectiveOp],
-                        exempt: Optional[Set[int]] = None
-                        ) -> List[str]:
+def check_double_reduce(ops: Sequence[CollectiveOp]) -> List[str]:
     """(d2) psum-of-psum over the same axis: the operand was already
     reduced over an axis this reduce names again — the r08 legacy
     psum-transpose over-count shape (gradients arrive exactly
-    |axis|x too large). `exempt` positions (the PowerSGD factor
-    handshake, see compressed_wire_positions) are skipped."""
+    |axis|x too large)."""
     msgs = []
     for op in ops:
         if op.prim not in REDUCE_PRIMS:
-            continue
-        if exempt and op.pos in exempt:
             continue
         again = sorted(set(op.axes) & op.in_reduced)
         if again:
@@ -417,28 +372,13 @@ def check_plan(ops: Sequence[CollectiveOp], plan,
             elif bucket_first is None or got.pos < bucket_first:
                 bucket_first = got.pos
         first_pos.append(bucket_first)
-    # Ordering is checked per compression family: a lossless plan is
-    # one family (identical to the historical global sweep), but a
-    # powersgd plan splits eligible and bypass leaves into separate
-    # buckets, and a bypass bucket spanning many layers can only fire
-    # once its EARLIEST-layer cotangent exists — cross-family
-    # interleave is scheduling, not drift. Within a family, reverse
-    # topological order remains the cross-rank contract.
-    comp = tuple(getattr(plan, "bucket_compression", None)
-                 or ("none",) * len(plan.wire))
-    families: Dict[str, List[int]] = {}
-    for b, p in enumerate(first_pos):
-        if p is not None:
-            families.setdefault(comp[b], []).append(p)
-    for fam, seq in sorted(families.items()):
-        if seq != sorted(seq):
-            which = (f" within compression family {fam!r}"
-                     if len(families) > 1 else "")
-            msgs.append(
-                "bucket psums are not emitted in plan (reverse "
-                f"topological) order{which} inside the backward — "
-                "the agreed cross-rank collective order and the "
-                "traced order disagree")
+    seq = [p for p in first_pos if p is not None]
+    if seq != sorted(seq):
+        msgs.append(
+            "bucket psums are not emitted in plan (reverse "
+            "topological) order inside the backward — "
+            "the agreed cross-rank collective order and the "
+            "traced order disagree")
     for op in ops:
         if (op.prim in REDUCE_PRIMS and not op.scalar
                 and op.pos not in used and op.pos not in internal
@@ -503,17 +443,12 @@ def check_numerics(ops: Sequence[CollectiveOp], plan,
 def check_compression(ops: Sequence[CollectiveOp], plan,
                       mesh_shape: Dict[str, int],
                       guard: bool) -> List[str]:
-    """(e) compressed buckets and the finite-flag vote: a bucket
-    whose wire is lossy (fp16/bf16 cast or PowerSGD rank-r factors)
-    must NEVER plan the flag riding its carrier — a veto count
-    accumulated in a lossy dtype rounds n-1 up to n past a few
-    hundred ranks, and a veto folded through low-rank factors is not
-    a count at all — and, guard on, each compressed bucket owes a
-    separate exact f32 scalar vote psum covering its reduce axes in
-    the traced program. Decompressed buckets keep reverse-topological
-    emission order via check_plan's first-position sweep (the factor
-    psums inherit the dense bucket's slot in the plan, so order drift
-    shows up there as a plan mismatch)."""
+    """(e) cast buckets and the finite-flag vote: a bucket whose
+    wire is lossy (fp16/bf16 cast) must NEVER plan the flag riding
+    its carrier — a veto count accumulated in a lossy dtype rounds
+    n-1 up to n past a few hundred ranks — and, guard on, each cast
+    bucket owes a separate exact f32 scalar vote psum covering its
+    reduce axes in the traced program."""
     comp = tuple(getattr(plan, "bucket_compression", ()) or ())
     if not comp or all(t == "none" for t in comp):
         return []
@@ -531,8 +466,7 @@ def check_compression(ops: Sequence[CollectiveOp], plan,
                 f"flag riding its lossy wire carrier "
                 f"({riders[0].dtype}, {riders[0].n} elements) — the "
                 f"vote must be a separate exact f32 psum (a lossy-"
-                f"dtype veto count rounds away; low-rank factors "
-                f"cannot carry a count at all)")
+                f"dtype veto count rounds away)")
         if guard:
             raxes = frozenset(plan.bucket_raxes[b])
             sep = [o for o in scalar_votes

@@ -37,54 +37,16 @@ KNOBS: List[Knob] = [
          "Tensor-fusion buffer threshold in bytes; pending gradients are "
          "greedily packed into buckets up to this size before a single "
          "fused allreduce is launched. 0 disables fusion."),
-    Knob("HOROVOD_JIT_OVERLAP", _parse_bool, True,
-         "Bucketed reverse-order gradient reduction in the jitted "
-         "train step (parallel/train.py build_train_step): gradient "
-         "leaves pack into HOROVOD_FUSION_THRESHOLD-sized buckets in "
-         "reverse (last-produced-first) order and each bucket's psum "
-         "is emitted inside the backward pass as soon as its "
-         "cotangents exist, so XLA's async collectives hide the "
-         "reduction under remaining backprop — the jit-path mirror "
-         "of the eager fusion-buffer overlap. On by default; 0 "
-         "restores the monolithic end-of-step reduction (byte-"
-         "identical HLO to the pre-overlap builder, test-pinned). "
-         "Leaves with no wire (reduce axes multiplying out to one "
-         "device — e.g. every leaf on a single-chip mesh) are never "
-         "bucketed: their psum is the identity, so the pack/unpack "
-         "round trip is pure overhead (elided since r08; "
-         "single-chip programs lower with no bucket machinery)."),
     Knob("HOROVOD_COMPRESSION", str, "none",
-         "Per-bucket gradient wire compression applied inside the "
-         "shared bucketing layer, both planes (jit bucketed psums and "
-         "the eager grouped allreduce): none (default; byte-identical "
-         "programs to the uncompressed builder, test-pinned), fp16 / "
-         "bf16 (cast wire, the reference's ceiling), or "
-         "powersgd[:rank] (low-rank factor wire with error feedback "
-         "— Vogels et al. NeurIPS 2019). The numerics finite-flag "
-         "vote never rides a compressed carrier: compressed buckets "
-         "carry the veto as a separate exact f32 psum (HVD007 "
-         "check (e))."),
-    Knob("HOROVOD_COMPRESSION_RANK", int, 4,
-         "PowerSGD approximation rank r when HOROVOD_COMPRESSION="
-         "powersgd carries no explicit :rank suffix. Wire per "
-         "compressed matrix drops from n*m to r*(n+m) elements; "
-         "rank<=4 already clears 4x on the VGG/transformer dense "
-         "buckets (BENCH_compression_ab_r13.json)."),
-    Knob("HOROVOD_COMPRESSION_WARMUP_STEPS", int, 0,
-         "Steps to run the EXACT reduction before switching to the "
-         "compressed wire. The eager plane counts steps in its "
-         "optimizer state and switches in place; the jit plane's "
-         "compressed step is a separate compiled program, so the "
-         "harness (bench.py convergence loop is the template) runs "
-         "the compression=none build for the first N steps and then "
-         "switches — one extra compile, no in-program branch (the "
-         "traced wire stays the plan HVD007 verified)."),
-    Knob("HOROVOD_COMPRESSION_MIN_ELEMENTS", int, 4096,
-         "PowerSGD bypass floor: leaves with fewer elements (and all "
-         "non-2D-reshapeable leaves — biases, scalars, norm gains) "
-         "take the exact path. Low-rank wire only pays for dense "
-         "matrices; below this size the factor handshake costs more "
-         "than it saves."),
+         "Wire cast of the jitted train step's gradient buckets "
+         "(parallel/train.py build_train_step): none (default), fp16 "
+         "or bf16 (upstream's Compression.fp16; floating buckets "
+         "cross the wire in that dtype). Any other value raises when "
+         "the step is built. The numerics finite-flag vote never "
+         "rides a cast carrier: such buckets carry the veto as a "
+         "separate exact f32 psum (HVD007 check (e)). The eager "
+         "plane takes its compressor as the optimizer's "
+         "compression= argument."),
     Knob("HOROVOD_CYCLE_TIME", float, 1.0,
          "Background engine cycle time in milliseconds: how often the "
          "pending-tensor queue is drained and negotiated."),
@@ -729,19 +691,6 @@ KNOBS: List[Knob] = [
     Knob("HOROVOD_TPU_CHIPS_PER_PROCESS_BOUNDS", str, "",
          "Override for TPU_CHIPS_PER_PROCESS_BOUNDS exported to "
          "workers. Empty = '1,1,1' (one chip per process)."),
-    # -- attention kernels ---------------------------------------------------
-    Knob("HOROVOD_FLASH_ATTENTION", str, "auto",
-         "Override of attention()'s path rule "
-         "(parallel/ring_attention.py): 'auto' (default) runs the "
-         "fused Pallas kernels (parallel/fused_attention.py) where "
-         "the call allows it (TPU, causal, bf16, seq in 128-blocks, "
-         "v's head width a multiple of 128, q / k's the same or any "
-         "other, no live seq axis) and the dense "
-         "path elsewhere; '0' keeps the dense path everywhere; '1' "
-         "takes the fused path or raises. The rounds 4-5 rejects in "
-         "docs/benchmarks.md were of JAX's stock kernel at its "
-         "128-block default on the flagship model; PERF.md (PR 30) "
-         "has the block-tuned kernel on the Mistral cells."),
 ]
 
 _KNOBS_BY_ENV: Dict[str, Knob] = {k.env: k for k in KNOBS}
@@ -781,11 +730,7 @@ class Config:
     # Convenience attribute access: cfg.fusion_threshold etc.
     _ATTR_MAP = {
         "fusion_threshold": "HOROVOD_FUSION_THRESHOLD",
-        "jit_overlap": "HOROVOD_JIT_OVERLAP",
         "compression": "HOROVOD_COMPRESSION",
-        "compression_rank": "HOROVOD_COMPRESSION_RANK",
-        "compression_warmup_steps": "HOROVOD_COMPRESSION_WARMUP_STEPS",
-        "compression_min_elements": "HOROVOD_COMPRESSION_MIN_ELEMENTS",
         "cycle_time_ms": "HOROVOD_CYCLE_TIME",
         "batch_quiescence": "HOROVOD_BATCH_QUIESCENCE",
         "cache_capacity": "HOROVOD_CACHE_CAPACITY",

@@ -57,11 +57,6 @@ def _int_or_none(v: Any) -> Optional[int]:
         return None
 
 
-# Snapshot key: the world size whose per-rank optimizer state (PowerSGD
-# residuals) the snapshot stacks on a leading axis.
-_PER_RANK_WORLD = "_per_rank_world"
-
-
 def _to_host(tree: Any) -> Any:
     return jax.tree_util.tree_map(
         lambda x: np.asarray(x) if isinstance(x, (jax.Array, np.ndarray))
@@ -242,21 +237,10 @@ class JaxState(ObjectState):
 
     def __init__(self, params: Any = None, opt_state: Any = None,
                  snapshot_path: Optional[str] = None,
-                 snapshot_backend: str = "auto",
-                 compression_state: Any = None, **kwargs):
+                 snapshot_backend: str = "auto", **kwargs):
         self.params = params
         self.opt_state = opt_state
         self._tree_attrs = ["params", "opt_state"]
-        if compression_state is not None:
-            # PowerSGD error-feedback state (jit plane: the explicit
-            # Q/residual tree build_train_step threads; the eager
-            # plane's lives inside opt_state already). First-class
-            # here so a restart restores the accumulated error
-            # instead of silently resetting it — dropped residual is
-            # gradient signal lost forever, and the convergence
-            # artifact's tolerance assumes it survives.
-            self.compression_state = compression_state
-            self._tree_attrs.append("compression_state")
         # Optional durable snapshot: on TPU a hard worker failure kills
         # the whole gang (the coordination service fatally terminates
         # survivors), so in-memory commits alone cannot recover from
@@ -294,21 +278,6 @@ class JaxState(ObjectState):
         super().save()
         self._tree_saved = {k: _to_host(getattr(self, k))
                             for k in self._tree_attrs}
-        if "compression_state" in self._tree_attrs:
-            # Journal the residual watermark at every commit (the
-            # snapshot is the recovery source; the journal line is
-            # what lets a post-mortem confirm no restart silently
-            # reset the error memory).
-            from .. import journal as _journal
-            cs = self._tree_saved.get("compression_state") or {}
-            es = list((cs.get("e") or {}).values())
-            _journal.record(
-                "compression_commit",
-                step=getattr(self, "step", None),
-                residual_leaves=len(es),
-                residual_norm=float(np.sqrt(sum(
-                    float((np.asarray(e, np.float64) ** 2).sum())
-                    for e in es))))
         # Journal durability marker: only a save that actually issued
         # a snapshot write advances the watermark a RESTARTED gang
         # can restore to (non-writing ranks may run a step ahead of
@@ -320,14 +289,6 @@ class JaxState(ObjectState):
     def _write_snapshot(self) -> None:
         import horovod_tpu as hvd
         known, trees = dict(self._saved), dict(self._tree_saved)
-        if hvd.is_initialized() and hvd.size() > 1:
-            # Only rank 0 writes, so per-rank state must reach it
-            # first (a collective: every rank commits).
-            from ..optim.distributed_optimizer import (
-                gather_per_rank_state)
-            trees = {k: _to_host(gather_per_rank_state(v))
-                     for k, v in trees.items()}
-            known[_PER_RANK_WORLD] = hvd.size()
         if hvd.is_initialized() and hvd.rank() != 0:
             return
         if self._snapshot_backend == "orbax":
@@ -451,15 +412,6 @@ class JaxState(ObjectState):
 
     def _apply_snapshot(self, known: Dict[str, Any],
                         trees: Dict[str, Any]) -> None:
-        saved_world = known.pop(_PER_RANK_WORLD, None)
-        if saved_world is not None:
-            import horovod_tpu as hvd
-            from ..optim.distributed_optimizer import (
-                select_per_rank_state)
-            rank, size = ((hvd.rank(), hvd.size())
-                          if hvd.is_initialized() else (0, 1))
-            trees = {k: select_per_rank_state(v, saved_world, rank, size)
-                     for k, v in trees.items()}
         for k, v in known.items():
             setattr(self, k, v)
         for k, v in trees.items():

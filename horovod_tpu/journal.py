@@ -184,11 +184,6 @@ EVENT_SCHEMAS: List[EventSchema] = [
         required=("seconds", "epoch"), optional=("durable", "step"),
         critical=True),
     EventSchema(
-        "compression_commit", "worker",
-        "Error-feedback residual state committed alongside an "
-        "elastic commit (norm + leaf count for drift audits).",
-        required=("step", "residual_leaves", "residual_norm")),
-    EventSchema(
         "watermark", "worker",
         "Measured loss check: journal watermark vs resumed step "
         "(feeds hvd_committed_step_loss_total).",

@@ -415,7 +415,7 @@ _wire_cache: Dict[str, Tuple[_Bound, _Bound, _Bound]] = {}
 def record_wire(compression: str, raw_bytes: int,
                 wire_bytes: int) -> None:
     """Gradient wire-byte accounting by compression tag ("none",
-    "bf16", "powersgd:4", ...). Called once per submission on the
+    "fp16", "bf16"). Called once per submission on the
     eager plane and once per COMPILE on the jit plane (where the wire
     is static per program — the trace-time record states what each
     step of that program will move). `raw_bytes` is the uncompressed

@@ -130,7 +130,7 @@ def assignment_digest(buckets: Sequence[Bucket],
     Byte-identical assignments have byte-identical digests.
 
     `compression` (optional, one tag per bucket — "none", "bf16",
-    "powersgd:4", ...) extends each bucket's entry with `|c=<tag>`
+    "fp16") extends each bucket's entry with `|c=<tag>`
     when the tag is not "none", so the cross-process contract now
     states the TRANSFORM each bucket's wire takes, not just its
     membership: two processes that agree on the partition but

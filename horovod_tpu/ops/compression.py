@@ -8,38 +8,16 @@ On TPU the natural wire dtype is bfloat16 (same byte savings as fp16,
 no overflow cliff, native MXU dtype), so `Compression.bf16` is added and
 `Compression.fp16` is kept for parity.
 
-Beyond the reference's fixed-2x cast ceiling, this module is also the
-per-bucket compressor REGISTRY the shared bucketing layer consumes
-(`none` / `fp16` / `bf16` / `powersgd(rank=r)`): `resolve_compression`
-parses the HOROVOD_COMPRESSION knob family into a `CompressionSpec`,
-and the PowerSGD half implements low-rank gradient compression with
-error feedback (Vogels et al., NeurIPS 2019; error-feedback
-convergence per Karimireddy et al., ICML 2019):
-
-    M   = grad.reshape(n, m) + residual        # error feedback in
-    P   = M @ Q                                # all-reduce (n x r wire)
-    P   = gram_orthogonalize(P)                # ONE Gram-matrix orth
-    Q'  = M.T @ P                              # all-reduce (m x r wire)
-    out = P @ Q'.T                             # ~= sum_ranks(M)
-    e'  = M - out / n_ranks                    # error feedback out
-
-Both reduction planes consume the same pure helpers here — the jit
-bucketed path (parallel/train.py threads Q/e as explicit loop state
-through `build_train_step`) and the eager grouped allreduce
-(optim/distributed_optimizer.py keeps Q/e in its optax state, which
-elastic `JaxState` persists like any other state tree). Matrices
-below HOROVOD_COMPRESSION_MIN_ELEMENTS and non-2D-reshapeable leaves
-bypass to the exact path; the numerics finite-flag vote never rides a
-compressed carrier (HVD007 check (e)).
+`resolve_compression` parses the HOROVOD_COMPRESSION knob (`none` /
+`fp16` / `bf16`) for the jit plane's per-bucket wire cast
+(parallel/train.py); the numerics finite-flag vote never rides a cast
+carrier (HVD007 check (e)).
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional
 
-import numpy as np
-
-import jax
 import jax.numpy as jnp
 
 
@@ -105,7 +83,7 @@ def wire_dtype_of(compression, dtype) -> jnp.dtype:
 
 def tag_of(compression) -> str:
     """Canonical metric/digest tag of an eager-API compressor value
-    ("none" / "fp16" / "bf16" / "powersgd:<r>") — the label
+    ("none" / "fp16" / "bf16") — the label
     `hvd_wire_bytes_total{compression=...}` carries."""
     if compression is NoneCompressor:
         return "none"
@@ -113,8 +91,6 @@ def tag_of(compression) -> str:
         return "fp16"
     if compression is BF16Compressor:
         return "bf16"
-    if isinstance(compression, PowerSGD):
-        return compression.spec.tag()
     name = getattr(compression, "__name__",
                    type(compression).__name__)
     return str(name).lower()
@@ -137,274 +113,28 @@ def compressor_for(raw_dtype, wire_dtype):
         f"no compressor maps {raw} to wire dtype {wire}")
 
 
-class PowerSGD:
-    """PowerSGD low-rank compression marker for the eager plane
-    (`DistributedGradientTransformation(compression=
-    Compression.powersgd(rank=4))`). Carries the config only — the
-    warm Q factors and the error-feedback residual live in the
-    transformation's optax state (so elastic `JaxState` persists them
-    with the rest of the optimizer state), never on this object.
-
-    `wire_dtype` is intentionally ABSENT: the negotiation layer's
-    cast-fusion keys (`wire_dtype_of`) do not apply — PowerSGD's wire
-    is the rank-r factor pair, reduced as exact f32."""
-
-    def __init__(self, rank: Optional[int] = None,
-                 min_elements: Optional[int] = None,
-                 warmup_steps: Optional[int] = None):
-        spec = resolve_compression(
-            "powersgd", rank=rank, min_elements=min_elements,
-            warmup_steps=warmup_steps)
-        self.rank = spec.rank
-        self.min_elements = spec.min_elements
-        self.warmup_steps = spec.warmup_steps
-
-    @property
-    def spec(self) -> "CompressionSpec":
-        return CompressionSpec("powersgd", self.rank,
-                               self.min_elements, self.warmup_steps)
-
-    def __repr__(self):  # pragma: no cover - debugging aid
-        return (f"PowerSGD(rank={self.rank}, "
-                f"min_elements={self.min_elements}, "
-                f"warmup_steps={self.warmup_steps})")
-
-
 class Compression:
     """Namespace matching hvd.Compression."""
     none = NoneCompressor
     fp16 = FP16Compressor
     bf16 = BF16Compressor
-    powersgd = PowerSGD
 
 
-# ---------------------------------------------------------------------------
-# Registry: the HOROVOD_COMPRESSION knob family -> CompressionSpec
-# ---------------------------------------------------------------------------
-
-class CompressionSpec(NamedTuple):
-    """Parsed per-bucket compression config, the registry's currency.
-
-    `kind` is one of "none" / "fp16" / "bf16" / "powersgd";
-    `rank`/`min_elements`/`warmup_steps` only matter for powersgd.
-    `tag()` is the canonical short form the extended bucket digest and
-    `OverlapPlan.bucket_compression` carry ("powersgd:4")."""
-    kind: str
-    rank: int
-    min_elements: int
-    warmup_steps: int
-
-    def tag(self) -> str:
-        return (f"powersgd:{self.rank}" if self.kind == "powersgd"
-                else self.kind)
+ACCEPTED = ("none", "fp16", "bf16")
 
 
-def _knob(env: str):
-    """Config-aware knob read (matches numerics._cfg semantics
-    without importing numerics — ops must stay import-light)."""
-    from ..common.config import env_value, knob_default
-    try:
-        return env_value(env)
-    except Exception:
-        return knob_default(env)
-
-
-def resolve_compression(name: Optional[str] = None, *,
-                        rank: Optional[int] = None,
-                        min_elements: Optional[int] = None,
-                        warmup_steps: Optional[int] = None
-                        ) -> CompressionSpec:
-    """Parse the HOROVOD_COMPRESSION knob family (or explicit
-    overrides) into a CompressionSpec. Accepted spellings:
-    "none", "fp16", "bf16", "powersgd", "powersgd:4",
-    "powersgd(rank=4)". Unknown names raise — a typo'd knob must not
-    silently train uncompressed."""
-    raw = (str(_knob("HOROVOD_COMPRESSION")) if name is None
-           else str(name)).strip().lower()
-    r = None
-    if raw.startswith("powersgd"):
-        rest = raw[len("powersgd"):]
-        kind = "powersgd"
-        if rest.startswith(":"):
-            r = int(rest[1:])
-        elif rest.startswith("(") and rest.endswith(")"):
-            body = rest[1:-1].strip()
-            if body.startswith("rank="):
-                body = body[len("rank="):]
-            r = int(body)
-        elif rest:
-            raise ValueError(
-                f"unparseable HOROVOD_COMPRESSION value {raw!r}")
-    elif raw in ("none", "fp16", "bf16"):
-        kind = raw
-    else:
+def resolve_compression(name: Optional[str] = None) -> str:
+    """The HOROVOD_COMPRESSION knob (or an explicit value) as one of
+    `ACCEPTED`. Anything else raises, naming the accepted values: a
+    typo'd knob, or a job whose environment still asks for the retired
+    lossy `powersgd`, must not silently train uncompressed."""
+    if name is None:
+        from ..common.config import env_value
+        name = env_value("HOROVOD_COMPRESSION")
+    raw = str(name).strip().lower()
+    if raw not in ACCEPTED:
         raise ValueError(
             f"unknown HOROVOD_COMPRESSION value {raw!r} (expected "
-            f"none / fp16 / bf16 / powersgd[:rank])")
-    if rank is not None:
-        r = int(rank)
-    if r is None:
-        r = int(_knob("HOROVOD_COMPRESSION_RANK"))
-    me = (int(_knob("HOROVOD_COMPRESSION_MIN_ELEMENTS"))
-          if min_elements is None else int(min_elements))
-    ws = (int(_knob("HOROVOD_COMPRESSION_WARMUP_STEPS"))
-          if warmup_steps is None else int(warmup_steps))
-    if kind == "powersgd" and r < 1:
-        raise ValueError(f"powersgd rank must be >= 1, got {r}")
-    return CompressionSpec(kind, r, me, ws)
-
-
-def spec_of(compression) -> CompressionSpec:
-    """CompressionSpec for any eager-API `compression=` value: a
-    Compressor class (none/fp16/bf16), a PowerSGD instance, an
-    existing spec, a registry string, or None (knob default)."""
-    if compression is None:
-        return resolve_compression()
-    if isinstance(compression, CompressionSpec):
-        return compression
-    if isinstance(compression, PowerSGD):
-        return compression.spec
-    if isinstance(compression, str):
-        return resolve_compression(compression)
-    if isinstance(compression, type) and issubclass(compression,
-                                                    Compressor):
-        if compression is NoneCompressor:
-            return resolve_compression("none")
-        if compression is FP16Compressor:
-            return resolve_compression("fp16")
-        if compression is BF16Compressor:
-            return resolve_compression("bf16")
-    raise ValueError(f"unrecognized compression {compression!r}")
-
-
-# ---------------------------------------------------------------------------
-# PowerSGD math (pure, shared by both reduction planes)
-# ---------------------------------------------------------------------------
-
-def matrix_shape(shape: Tuple[int, ...]) -> Tuple[int, int]:
-    """The (n, m) 2-D view PowerSGD compresses: the axis-boundary
-    fold that best balances the two dims. Only defined for ndim >= 2
-    leaves.
-
-    Balance matters twice: factor wire is (n + m) * r elements —
-    minimized when n ~ m for a fixed n*m — and the rank-r
-    approximation of a squarer matrix captures more of the energy.
-    The naive leading-dim fold is catastrophically lopsided for
-    exactly the leaves that dominate wire traffic here: a
-    scan-stacked transformer block (24, 1024, 1024) would become
-    (24, 1048576) — rank-r ACROSS layers, with factors a third the
-    raw bytes — where the balanced fold (24576, 1024) compresses
-    128x at rank 4. The split is a pure function of the static
-    shape, so every rank derives the same fold (SPMD contract)."""
-    dims = tuple(int(s) for s in shape)
-    best = (int(dims[0]), int(np.prod(dims[1:])))
-    for k in range(1, len(dims)):
-        n = int(np.prod(dims[:k]))
-        m = int(np.prod(dims[k:]))
-        if abs(n - m) < abs(best[0] - best[1]):
-            best = (n, m)
-    return best
-
-
-def powersgd_eligible(shape, dtype, min_elements: int) -> bool:
-    """Whether one leaf takes the low-rank path. Requires a
-    2-D-reshapeable floating leaf of at least `min_elements` elements
-    with a non-degenerate matrix view; everything else bypasses to
-    the exact path (the reference behavior for its own fp16
-    compressor is all-or-nothing — the bypass here is what keeps
-    biases/scalars and small kernels exact)."""
-    shape = tuple(int(s) for s in shape)
-    if len(shape) < 2:
-        return False
-    if not jnp.issubdtype(jnp.dtype(dtype), jnp.floating):
-        return False
-    size = int(np.prod(shape)) if shape else 1
-    if size < int(min_elements):
-        return False
-    n, m = matrix_shape(shape)
-    return n >= 2 and m >= 2
-
-
-def effective_rank(shape: Tuple[int, ...], rank: int) -> int:
-    """Rank actually used for one leaf: r capped by both matrix
-    dims (a rank-4 request on a (2, 4096) matrix uses rank 2)."""
-    n, m = matrix_shape(shape)
-    return max(1, min(int(rank), n, m))
-
-
-def gram_orthogonalize(p: jnp.ndarray) -> jnp.ndarray:
-    """Single Gram-matrix orthogonalization of the column space of
-    `p` (n x r): Cholesky of G = p^T p and a triangular solve —
-    O(n r^2) + O(r^3) instead of per-column Gram-Schmidt's r
-    dependent passes, and exactly one fused XLA region inside the
-    backward pass. The jitter term keeps G positive-definite when
-    columns are (near-)zero — e.g. a bucket whose cotangents are all
-    zeros on the first step; the result is then a harmless scaled
-    basis instead of NaNs."""
-    p = p.astype(jnp.float32)
-    r = p.shape[-1]
-    g = p.T @ p
-    jitter = jnp.trace(g) * 1e-7 + 1e-30
-    chol = jnp.linalg.cholesky(g + jitter * jnp.eye(r, dtype=g.dtype))
-    return jax.scipy.linalg.solve_triangular(
-        chol, p.T, lower=True).T
-
-
-def init_q(shape: Tuple[int, ...], rank: int,
-           leaf_index: int) -> jnp.ndarray:
-    """Deterministic warm-start Q factor for one leaf: fixed-seed
-    Gaussian (folded with the leaf index) orthonormalized once.
-    Every process derives the identical factor — the SPMD purity
-    contract the bucketing layer already lives by; the determinism
-    test pins this across fresh interpreters."""
-    n, m = matrix_shape(shape)
-    r = effective_rank(shape, rank)
-    key = jax.random.fold_in(jax.random.PRNGKey(0x9d5c), leaf_index)
-    q = jax.random.normal(key, (m, r), dtype=jnp.float32)
-    return gram_orthogonalize(q)
-
-
-def powersgd_wire_elements(shape: Tuple[int, ...],
-                           rank: int) -> Tuple[int, int]:
-    """(P elements, Q elements) one leaf contributes to the bucket's
-    two f32 factor psums — the plan-level wire accounting."""
-    n, m = matrix_shape(shape)
-    r = effective_rank(shape, rank)
-    return n * r, m * r
-
-
-def powersgd_reduce(mats, qs, es, psum_fn, n_ranks: int):
-    """One PowerSGD round over a bucket of 2-D f32 matrices, shared
-    by both planes. `mats` are the LOCAL (per-rank) gradient matrices
-    (already reshaped (n_i, m_i)), `qs` the warm Q factors, `es` the
-    error-feedback residuals; `psum_fn(flat)` sums one packed 1-D f32
-    wire array across ranks (lax.psum chain in-jit, grouped_allreduce
-    on the eager plane). Returns (sum-semantics decompressed mats,
-    new qs, new es): out_i ~= sum_ranks(mat_i + e_i), and each rank's
-    new residual is its local M minus its 1/n_ranks share of what was
-    actually communicated."""
-    ms = [m.astype(jnp.float32) + e for m, e in zip(mats, es)]
-    ps = [m @ q for m, q in zip(ms, qs)]
-    sizes_p = [int(p.shape[0]) * int(p.shape[1]) for p in ps]
-    flat = (jnp.concatenate([p.reshape(-1) for p in ps])
-            if len(ps) > 1 else ps[0].reshape(-1))
-    red = psum_fn(flat)
-    out_ps, off = [], 0
-    for p, sz in zip(ps, sizes_p):
-        out_ps.append(gram_orthogonalize(
-            red[off:off + sz].reshape(p.shape)))
-        off += sz
-    qns = [m.T @ p for m, p in zip(ms, out_ps)]
-    sizes_q = [int(q.shape[0]) * int(q.shape[1]) for q in qns]
-    flat_q = (jnp.concatenate([q.reshape(-1) for q in qns])
-              if len(qns) > 1 else qns[0].reshape(-1))
-    red_q = psum_fn(flat_q)
-    new_qs, off = [], 0
-    for q, sz in zip(qns, sizes_q):
-        new_qs.append(red_q[off:off + sz].reshape(q.shape))
-        off += sz
-    outs = [p @ q.T for p, q in zip(out_ps, new_qs)]
-    inv = 1.0 / float(max(1, n_ranks))
-    new_es = [m - o * jnp.asarray(inv, o.dtype)
-              for m, o in zip(ms, outs)]
-    return outs, new_qs, new_es
+            f"{' / '.join(ACCEPTED)}; powersgd is no longer "
+            f"supported)")
+    return raw

@@ -41,10 +41,7 @@ import optax
 from .. import numerics as _numerics
 from ..ops import collective_ops as C
 from ..ops import sparse as S
-from ..ops.compression import (NoneCompressor, PowerSGD,
-                               matrix_shape, init_q,
-                               powersgd_eligible, powersgd_reduce,
-                               powersgd_wire_elements)
+from ..ops.compression import NoneCompressor
 from ..ops.dispatch import AVERAGE, SUM, ADASUM, MIN
 from ..ops.process_set import ProcessSet
 
@@ -53,56 +50,6 @@ class _AggState(NamedTuple):
     inner: Any
     acc: Any
     counter: jnp.ndarray
-
-
-class _PowerSGDState(NamedTuple):
-    """Optax state of the eager PowerSGD plane: the warm Q factors and
-    error-feedback residuals keyed by flattened-leaf index (string
-    keys — a dict pytree, so elastic `JaxState(opt_state=...)` persists
-    them with the inner optimizer state and a restart resumes with the
-    accumulated error intact), plus the step counter that drives
-    HOROVOD_COMPRESSION_WARMUP_STEPS."""
-    inner: Any
-    q: Any
-    e: Any
-    step: jnp.ndarray
-
-
-def _map_residuals(fn, tree):
-    def is_psgd(x):
-        return isinstance(x, _PowerSGDState)
-    return jax.tree_util.tree_map(
-        lambda x: x._replace(e=fn(x.e)) if is_psgd(x) else x,
-        tree, is_leaf=is_psgd)
-
-
-def gather_per_rank_state(tree):
-    """Snapshot form of an optimizer-state tree: every PowerSGD
-    error-feedback residual — the one piece of optimizer state that
-    differs per rank (the residuals of a bucket nearly cancel across
-    ranks, so handing rank 0's to everyone corrupts the feedback) —
-    stacked across ranks on a new leading axis. Collective: every
-    rank calls it; a tree without residuals issues nothing."""
-    def gather(e):
-        keys = sorted(e)
-        if not keys:
-            return e
-        got = C.grouped_allgather(
-            [jnp.asarray(e[k])[None] for k in keys],
-            name="JaxState.snapshot.residuals")
-        return dict(zip(keys, got))
-    return _map_residuals(gather, tree)
-
-
-def select_per_rank_state(tree, saved_world: int, rank: int, size: int):
-    """Inverse of gather_per_rank_state on load: this rank's own row.
-    After a resize each rank takes an equal share of the summed
-    residual — the trajectory depends on the sum alone."""
-    def pick(v):
-        v = jnp.asarray(v)
-        return v[rank] if size == saved_world else v.sum(0) / size
-    return _map_residuals(
-        lambda e: {k: pick(v) for k, v in e.items()}, tree)
 
 
 def _tree_zeros_like(tree):
@@ -305,36 +252,6 @@ def DistributedGradientTransformation(
     if k < 1:
         raise ValueError("backward_passes_per_step must be >= 1")
 
-    use_powersgd = isinstance(compression, PowerSGD)
-    if use_powersgd:
-        pspec = compression.spec
-        if axis_name is not None:
-            raise ValueError(
-                "compression=Compression.powersgd(...) is stateful and "
-                "eager-only here; inside a jitted step use "
-                "build_train_step(compression='powersgd[:r]') which "
-                "threads the Q/residual state explicitly")
-        if op not in (AVERAGE, SUM):
-            raise ValueError(
-                "PowerSGD compression supports op=Average/Sum (Adasum "
-                "folds are nonlinear in the compressed factors)")
-        if gradient_predivide_factor != 1.0:
-            raise ValueError(
-                "gradient_predivide_factor is incompatible with "
-                "PowerSGD compression (the prescale would scale the "
-                "error-feedback residual out of gradient units)")
-        if k != 1:
-            raise ValueError(
-                "backward_passes_per_step > 1 with PowerSGD "
-                "compression is not supported (the local aggregation "
-                "accumulator and the error residual would double-"
-                "count); aggregate locally before the wrapper instead")
-        if num_groups or groups is not None:
-            raise ValueError(
-                "num_groups/groups fusion control is incompatible with "
-                "PowerSGD compression (compressed leaves ride the "
-                "packed factor wire, not the fusion groups)")
-
     def reduce_grads(grads):
         guard = _numerics.guard_enabled()
         leaves, treedef = jax.tree_util.tree_flatten(
@@ -447,112 +364,14 @@ def DistributedGradientTransformation(
         # static configuration dispatch; all arms uniform)
         return out
 
-    def _reduce_powersgd(grads, state):
-        """Eager PowerSGD round: compressed leaves ride the packed
-        rank-r factor psums of `ops.compression.powersgd_reduce` (two
-        grouped allreduces of f32 factors), ineligible leaves take the
-        exact grouped path unchanged, and the finite-flag vote takes
-        the exact Min allreduce — never the lossy carrier. Returns
-        (reduced_tree, new_state)."""
-        guard = _numerics.guard_enabled()
-        leaves, treedef = jax.tree_util.tree_flatten(
-            grads, is_leaf=S.is_sparse)
-        if any(S.is_sparse(l) for l in leaves):
-            if not sparse_as_dense:
-                raise ValueError(
-                    "BCOO gradients with PowerSGD compression require "
-                    "sparse_as_dense=True (low-rank factors are dense)")
-            leaves = [l.todense() if S.is_sparse(l) else l
-                      for l in leaves]
-        corrupted = _numerics.maybe_corrupt_grads(leaves)
-        if corrupted is not leaves:
-            leaves = corrupted
-        flag = (_numerics.local_finite_flag(leaves) if guard else None)
-        import horovod_tpu as hvd
-        n = process_set.size if process_set is not None else hvd.size()
-        comp_idx = sorted(int(i) for i in state.q)
-        warm = int(state.step) < pspec.warmup_steps
-        new_q, new_e = state.q, state.e
-        if warm or not comp_idx:
-            reduced = _eager_reduce(leaves, op, NoneCompressor,
-                                    process_set, 0, None, 1.0, 1.0)
-        else:
-            from ..metrics import record_wire
-            reduced = [None] * len(leaves)
-            rest = [i for i in range(len(leaves)) if i not in
-                    set(comp_idx)]
-            ms = [leaves[i].astype(jnp.float32).reshape(
-                matrix_shape(leaves[i].shape)) for i in comp_idx]
-            qs = [state.q[str(i)] for i in comp_idx]
-            es = [state.e[str(i)] for i in comp_idx]
-
-            def psum_fn(flat):
-                return C.grouped_allreduce(
-                    [flat], op=SUM, compression=NoneCompressor,
-                    process_set=process_set)[0]
-
-            outs, nqs, nes = powersgd_reduce(ms, qs, es, psum_fn, n)
-            raw_b = sum(
-                int(jnp.size(leaves[i]))
-                * jnp.dtype(leaves[i].dtype).itemsize
-                for i in comp_idx)
-            wire_b = 4 * sum(sum(powersgd_wire_elements(
-                leaves[i].shape, pspec.rank)) for i in comp_idx)
-            record_wire(pspec.tag(), raw_b, wire_b)
-            inv = (1.0 / n) if op == AVERAGE else 1.0
-            for j, i in enumerate(comp_idx):
-                o = outs[j]
-                if inv != 1.0:
-                    o = o * jnp.asarray(inv, o.dtype)
-                reduced[i] = o.reshape(
-                    leaves[i].shape).astype(leaves[i].dtype)
-            if rest:
-                rr = _eager_reduce([leaves[i] for i in rest], op,
-                                   NoneCompressor, process_set, 0,
-                                   None, 1.0, 1.0)
-                for i, r in zip(rest, rr):
-                    reduced[i] = r
-            new_q = {str(i): q for i, q in zip(comp_idx, nqs)}
-            new_e = {str(i): e for i, e in zip(comp_idx, nes)}
-        out = jax.tree_util.tree_unflatten(treedef, reduced)
-        if guard:
-            ok = _flag_min_eager(flag, process_set)
-            out = _numerics.imprint_non_finite(out, ok)
-            # Veto gates the compressor state too: a poisoned step
-            # must not corrupt the error memory (the jit tag and
-            # guard_non_finite freeze their state the same way).
-            new_q = {kk: jnp.where(ok, nv, state.q[kk])
-                     for kk, nv in new_q.items()}
-            new_e = {kk: jnp.where(ok, nv, state.e[kk])
-                     for kk, nv in new_e.items()}
-        return out, state._replace(q=new_q, e=new_e,
-                                   step=state.step + 1)
-
     def init_fn(params):
         inner_state = inner.init(params)
-        if use_powersgd:
-            q, e = {}, {}
-            for i, l in enumerate(jax.tree_util.tree_leaves(params)):
-                if powersgd_eligible(getattr(l, "shape", ()),
-                                     getattr(l, "dtype", None)
-                                     or jnp.float32,
-                                     pspec.min_elements):
-                    q[str(i)] = init_q(tuple(l.shape), pspec.rank, i)
-                    e[str(i)] = jnp.zeros(matrix_shape(tuple(l.shape)),
-                                          jnp.float32)
-            return _PowerSGDState(inner=inner_state, q=q, e=e,
-                                  step=jnp.zeros((), jnp.int32))
         if k == 1:
             return inner_state
         return _AggState(inner=inner_state, acc=_tree_zeros_like(params),
                          counter=jnp.zeros((), jnp.int32))
 
     def update_fn(grads, state, params=None, **extra):
-        if use_powersgd:
-            reduced, state = _reduce_powersgd(grads, state)
-            updates, new_inner = inner.update(reduced, state.inner,
-                                              params, **extra)
-            return updates, state._replace(inner=new_inner)
         if k == 1:
             reduced = reduce_grads(grads)
             return inner.update(reduced, state, params, **extra)

@@ -115,24 +115,11 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # everything else, and every backend but the TPU, runs
 # dense_attention. The kernels declare their outputs' varying-axes
 # types, so shard_map's replication checker stays on around them.
-#
-# HOROVOD_FLASH_ATTENTION overrides the rule: "auto" (default) is the
-# rule, "0" keeps the dense path everywhere, "1" takes the fused path
-# or raises where the shapes do not allow it. Read at trace time, like
-# the Adasum Pallas switch. History: rounds 4 and 5 measured JAX's
-# stock Pallas flash kernel at its default 128-blocks on the flagship
-# model (heads of 64, seq 512) and found it 27-37 % slower inside the
-# remat'd layer scan; docs/benchmarks.md has those notes and PERF.md
-# (PR 30) what the block-tuned kernel measures on the Mistral cells.
-def _flash_mode() -> str:
-    from ..common.config import env_value
-    v = str(env_value("HOROVOD_FLASH_ATTENTION")).lower()
-    v = {"true": "1", "yes": "1", "false": "0", "no": "0",
-         "": "auto"}.get(v, v)
-    if v not in ("0", "1", "auto"):
-        raise ValueError(
-            f"HOROVOD_FLASH_ATTENTION must be 0/1/auto, got {v!r}")
-    return v
+# History: rounds 4 and 5 measured JAX's stock Pallas flash kernel at
+# its default 128-blocks on the flagship model (heads of 64, seq 512)
+# and found it 27-37 % slower inside the remat'd layer scan;
+# docs/benchmarks.md has those notes and PERF.md (PR 30) what the
+# block-tuned kernel measures on the Mistral cells.
 
 
 def flash_possible_cfg(head_dim: int, seq: int,
@@ -242,9 +229,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     else `dense_attention`. k / v may carry fewer (grouped) heads, and
     v another head width than q / k; the default `scale` is that of
     q's own width."""
-    mode = _flash_mode()
-    fused = mode == "1" or (mode == "auto"
-                            and _flash_supported(q, k, v, causal))
+    fused = _flash_supported(q, k, v, causal)
     path = "dense" if not fused else (
         "fused" if _fused_qk_width(q, k, v) == q.shape[-1]
         else "fused_padded_qk")

@@ -150,11 +150,10 @@ class Timeline:
     def span(self, name: str, phase: str, begin_mono_ns: int,
              end_mono_ns: int, args: dict = None) -> None:
         """One closed B/E span on `name`'s lane from raw
-        time.monotonic_ns() readings captured elsewhere — the
-        jit-path overlap probe (tracing.OverlapProbe) records its
-        bucket-reduce edges host-side during step execution and hands
-        them here afterwards, landing them on the same merged-trace
-        axis as the engine's eager lanes."""
+        time.monotonic_ns() readings captured elsewhere (the serving
+        frontend hands over a request's phases afterwards), landing
+        them on the same merged-trace axis as the engine's eager
+        lanes."""
         if self._closed:
             return
         tid = self._tid(name)

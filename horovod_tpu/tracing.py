@@ -123,129 +123,6 @@ def ring_events(limit: Optional[int] = None) -> List[Tuple]:
 
 
 # ---------------------------------------------------------------------------
-# jit-path overlap probe (bucketed reduction verification)
-# ---------------------------------------------------------------------------
-
-class OverlapProbe:
-    """Host-side recorder for `build_train_step(overlap_probe=...)`.
-
-    The bucketed jit path emits each gradient bucket's psum inside the
-    backward pass; this probe timestamps the two edges of every
-    bucket's reduction — wire packed ("ready") and psum complete
-    ("reduced") — via `jax.debug.callback`s data-anchored on those
-    arrays, so the host observes the REAL execution order the runtime
-    chose. The spans land on the rank's timeline lanes
-    (`overlap.bucketN` / REDUCE) next to a STEP lane, which is what
-    `hvdrun --timeline-merge` fuses into the cross-rank artifact
-    showing per-bucket reduce spans inside backprop.
-
-    Arm it only around measured steps: callbacks fire on every
-    execution, but a disarmed probe drops the event, so warmup /
-    compile cycles stay out of the artifact (the merged-timeline
-    acceptance excludes compile cycles)."""
-
-    def __init__(self):
-        self.events: List[Tuple] = []   # (mono_ns, bucket, phase, nb)
-        self.steps: List[Tuple[int, int]] = []
-        self.armed = False
-        self._lock = threading.Lock()
-
-    # The callable handed to build_train_step.
-    def __call__(self, bucket: int, phase: str, nbytes: int) -> None:
-        if not self.armed:
-            return
-        now = time.monotonic_ns()
-        with self._lock:
-            self.events.append((now, int(bucket), phase, int(nbytes)))
-        record("bucket_" + phase, f"overlap.bucket{int(bucket)}",
-               arg=float(nbytes))
-
-    def step_span(self, begin_ns: int, end_ns: int) -> None:
-        """Record one measured step's host-side bounds (the compute
-        envelope the bucket spans are read against)."""
-        if self.armed:
-            self.steps.append((int(begin_ns), int(end_ns)))
-
-    def spans(self) -> List[Tuple[int, int, int, int]]:
-        """[(bucket, ready_ns, reduced_ns, nbytes), ...] — ONE span
-        per bucket per executed step. Under shard_map the callbacks
-        fire once per LOCAL device, so a bucket's edges arrive as a
-        burst of ready events then a burst of reduced events; the
-        span is the device-inclusive envelope — EARLIEST ready to
-        LATEST reduced — and a new ready after any reduced closes the
-        previous step's span for that bucket."""
-        open_: Dict[int, list] = {}   # b -> [ready, last_reduced, nb]
-        out = []
-        with self._lock:
-            evs = list(self.events)
-        for t, b, ph, nb in evs:
-            cur = open_.get(b)
-            if ph == "ready":
-                if cur is not None and cur[1] is not None:
-                    out.append((b, cur[0], cur[1], cur[2]))
-                    cur = None
-                if cur is None:
-                    open_[b] = [t, None, nb]
-                # duplicate ready from another device: keep earliest
-            elif ph == "reduced" and cur is not None:
-                cur[1] = t if cur[1] is None else max(cur[1], t)
-        for b, cur in open_.items():
-            if cur[1] is not None:
-                out.append((b, cur[0], cur[1], cur[2]))
-        out.sort(key=lambda s: s[1])
-        return out
-
-    def hidden_fraction(self) -> Dict[str, float]:
-        """Schedule-placement accounting over the recorded steps:
-        what fraction of total bucket-reduce wall time sits INSIDE a
-        step's backward window (hidden under compute) vs after the
-        last bucket's inputs were ready (structurally exposed — the
-        tail no schedule can hide). `exposed_comm_fraction` is what
-        bench.py's overlap stats publish."""
-        spans = self.spans()
-        if not spans or not self.steps:
-            return {"reduce_total_s": 0.0, "exposed_comm_fraction": 0.0,
-                    "hidden_comm_fraction": 0.0, "spans": 0}
-        # Numerator and denominator over the SAME population: spans
-        # whose ready edge falls inside a recorded step envelope (the
-        # envelope only groups spans to a step and locates that step's
-        # last ready edge). Per step, the hideable window closes at
-        # the LAST bucket-ready edge: reduce time past it — including
-        # any trailing past the envelope end — has no backprop left to
-        # hide under and counts fully exposed, so the fraction cannot
-        # understate exposure on a run with a large exposed tail.
-        total = 0
-        exposed = 0
-        attributed = 0
-        for sb, se in self.steps:
-            inside = [s for s in spans if sb <= s[1] <= se]
-            if not inside:
-                continue
-            attributed += len(inside)
-            last_ready = max(s[1] for s in inside)
-            total += sum(s[2] - s[1] for s in inside)
-            exposed += sum(s[2] - max(s[1], last_ready)
-                           for s in inside if s[2] > last_ready)
-        frac = exposed / total if total else 0.0
-        return {"reduce_total_s": round(total / 1e9, 6),
-                "exposed_comm_fraction": round(frac, 4),
-                "hidden_comm_fraction": round(1.0 - frac, 4),
-                "spans": attributed}
-
-    def to_timeline(self, timeline) -> int:
-        """Write the recorded bucket spans (and STEP envelopes) onto a
-        Timeline's lanes; returns the span count written."""
-        spans = self.spans()
-        for i, (sb, se) in enumerate(self.steps):
-            timeline.span("overlap.step", "STEP", sb, se,
-                          args={"index": i})
-        for b, t0, t1, nb in spans:
-            timeline.span(f"overlap.bucket{b}", "REDUCE", t0, t1,
-                          args={"bucket": b, "nbytes": nb})
-        return len(spans)
-
-
-# ---------------------------------------------------------------------------
 # device-trace scopes (names on the jit plane's instructions)
 # ---------------------------------------------------------------------------
 # The jit plane runs no host code in a step, so what it can give a
@@ -282,10 +159,10 @@ DEVICE_SCOPES: Dict[str, str] = {
     "hvd.head_loss": "final norm, LM head or classifier, cross-entropy",
     "hvd.conv": "ResNet convolutions",
     "hvd.batchnorm": "BatchNorm statistics and normalisation",
-    "hvd.grad_reduce": "gradient scale and guard vote of the "
-                       "monolithic path; hvd.grad_reduce.b<N> is "
-                       "bucket N of plan_overlap (pack, cast, "
-                       "all-reduce, unpack)",
+    "hvd.grad_reduce": "the family of the gradient buckets' scopes "
+                       "(no instruction carries the bare name): "
+                       "hvd.grad_reduce.b<N> is bucket N of "
+                       "plan_overlap (pack, cast, all-reduce, unpack)",
     "hvd.optimizer": "optimizer update and its application",
 }
 # Part of the persistent compile cache's key (common/compile_cache.py):

@@ -6,9 +6,8 @@ coordinator-measured latency must sit below the 5 ms cycle budget in
 steady state (step 0 — the XLA compile cycle — is excluded from the
 claim, marked via the step arg on every span).
 
-The XLA dispatch runs on each rank's OWN 8-virtual-device mesh (the
-same honest arrangement as benchmarks/TIMELINE_overlap_2proc_r06.json:
-this container's jaxlib CPU backend cannot run cross-process
+The XLA dispatch runs on each rank's OWN 8-virtual-device mesh (this
+container's jaxlib CPU backend cannot run cross-process
 computations, so the data plane is local while the control plane —
 negotiation over TCP through the native C++ coordinator, clock
 calibration, per-rank timelines, the merge — is the real

@@ -77,6 +77,21 @@ def test_describe_knobs_lists_everything():
         assert k.env in text
 
 
+@pytest.mark.parametrize("name", [
+    "HOROVOD_JIT_OVERLAP", "HOROVOD_FLASH_ATTENTION",
+    "HOROVOD_COMPRESSION_RANK", "HOROVOD_COMPRESSION_WARMUP_STEPS",
+    "HOROVOD_COMPRESSION_MIN_ELEMENTS"])
+def test_retired_switch_is_no_knob(name):
+    """PR 33: the jit step takes these decisions from what it
+    observes. A read of the name fails loudly and no help text or
+    Config attribute offers it."""
+    from horovod_tpu.common import config
+    with pytest.raises(KeyError, match="not a declared knob"):
+        config.env_value(name)
+    assert name not in config.describe_knobs()
+    assert name not in config.Config._ATTR_MAP.values()
+
+
 def test_metadata_flags():
     import horovod_tpu as hvd
     # The north-star constraint: never NCCL/MPI/Gloo.

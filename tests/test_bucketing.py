@@ -1,15 +1,13 @@
-"""Shared bucketing layer (ops/bucketing.py) + the jit-path bucketed
-overlap it feeds (parallel/train.py): partition determinism (SPMD
+"""Shared bucketing layer (ops/bucketing.py) + the jit step's bucketed
+reduction it feeds (parallel/train.py): partition determinism (SPMD
 safety — byte-identical assignment for the same tree + threshold,
 in-process and across a fresh interpreter), the reverse-order
 property, threshold edge cases (oversized leaf, empty tree, zero
-threshold, mixed dtypes via key_fn), and the train-step equivalences
-the overlap path must preserve — bucketed == monolithic numerics,
-guard flag-ride equivalence, the overlap-off HLO identity (byte-equal
-to the pre-overlap builder) and overlap-on actually changing the
-program, and the probe's span accounting."""
+threshold, mixed dtypes via key_fn), and what the step's builder
+decides from the mesh: no bucket and no collective on one device,
+buckets covering every gradient byte on eight. The step against an
+independent reference is tests/test_step_reference.py's."""
 
-import json
 import os
 import subprocess
 import sys
@@ -20,7 +18,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 import optax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh
 
 from horovod_tpu.ops.bucketing import (Bucket, assignment_digest,
                                        leaf_nbytes, partition_buckets,
@@ -157,38 +155,14 @@ class TestBucketedTrainStep:
         with pytest.raises(ValueError, match="'proc' is not one of"):
             build_train_step(_loss, optax.sgd(0.1), mesh)
 
-    def test_bucketed_matches_monolithic(self):
-        from horovod_tpu.parallel.train import (build_train_step,
-                                                last_overlap_info)
-        mesh = _mesh()
-        opt = optax.sgd(0.1)
-        params = _params()
-        st = opt.init(params)
-        batch = jnp.arange(8.0)
-        s_on = build_train_step(_loss, opt, mesh, donate=False,
-                                overlap=True, overlap_threshold=16)
-        p_on, _, m_on = s_on(params, st, batch)
-        info = last_overlap_info()
-        assert info["enabled"] and info["buckets"] >= 2
-        assert sum(info["bucket_bytes"]) == sum(
-            leaf_nbytes(v) for v in jax.tree_util.tree_leaves(params))
-        s_off = build_train_step(_loss, opt, mesh, donate=False,
-                                 overlap=False)
-        p_off, _, m_off = s_off(params, st, batch)
-        for k in params:
-            np.testing.assert_allclose(np.asarray(p_on[k]),
-                                       np.asarray(p_off[k]), rtol=1e-6)
-        np.testing.assert_allclose(float(m_on["loss"]),
-                                   float(m_off["loss"]), rtol=1e-6)
-
     def test_world1_wire_gate_no_buckets(self, monkeypatch):
         """r08 wire gate: on a single-device mesh every leaf's reduce
-        axes multiply out to 1 — the psum is the identity — so
-        overlap-ON must build ZERO buckets and lower byte-identically
-        to the monolithic program. This pins the fix for the
-        single-chip copy tax the r08 attribution caught (+41 dead
-        pack/psum/unpack instructions on the world-1 transformer
-        step, benchmarks/PROFILE_transformer_r08.json): the bucket
+        axes multiply out to 1 — the psum is the identity — so the
+        step must build ZERO buckets (what stays are the size-1
+        psums the typing asks for, which XLA elides). This pins the fix for the single-chip copy tax the r08
+        attribution caught (+41 dead pack/psum/unpack instructions on
+        the world-1 transformer step,
+        benchmarks/PROFILE_transformer_r08.json): the bucket
         machinery may never again ship wire-less copies."""
         from horovod_tpu.parallel.train import (build_train_step,
                                                 last_overlap_info)
@@ -198,85 +172,20 @@ class TestBucketedTrainStep:
         params = _params()
         st = opt.init(params)
         batch = jnp.arange(8.0)
-        s_on = build_train_step(_loss, opt, mesh, donate=False,
-                                overlap=True, overlap_threshold=16)
-        on = s_on.lower(params, st, batch).as_text()
+        s_one = build_train_step(_loss, opt, mesh, donate=False,
+                                 overlap_threshold=16)
+        one = s_one.lower(params, st, batch).as_text()
         info = last_overlap_info()
-        assert info["enabled"] and info["buckets"] == 0, info
-        s_off = build_train_step(_loss, opt, mesh, donate=False,
-                                 overlap=False)
-        off = s_off.lower(params, st, batch).as_text()
-        assert on == off
-        # and on a REAL multi-device mesh the gate must NOT fire
+        assert info["traced"] and info["buckets"] == 0, info
+        # and on a REAL multi-device mesh the gate must NOT fire: the
+        # buckets hold every gradient byte
         s_multi = build_train_step(_loss, opt, _mesh(), donate=False,
-                                   overlap=True, overlap_threshold=16)
-        s_multi.lower(params, st, batch).as_text()
-        assert last_overlap_info()["buckets"] >= 2
-
-    def test_default_on_and_knob_off(self, monkeypatch):
-        from horovod_tpu.parallel import train as T
-        monkeypatch.delenv("HOROVOD_JIT_OVERLAP", raising=False)
-        assert T.overlap_enabled() is True
-        monkeypatch.setenv("HOROVOD_JIT_OVERLAP", "0")
-        assert T.overlap_enabled() is False
-
-    def test_overlap_off_hlo_identical_to_monolithic(self,
-                                                     monkeypatch):
-        """The off-switch restores TODAY'S program byte-for-byte: an
-        explicitly-off build and a knob-off default build lower to
-        identical HLO text (extends — does not weaken — the numerics
-        HLO-identity test, which pins guard-off equality separately).
-        Overlap ON must also genuinely change the program, or the
-        knob is theater."""
-        from horovod_tpu.parallel.train import build_train_step
-        monkeypatch.delenv("HOROVOD_NUMERICS_GUARD", raising=False)
-        mesh = _mesh()
-        opt = optax.sgd(0.1)
-        params = _params()
-        st = opt.init(params)
-        batch = jnp.arange(8.0)
-        s_off = build_train_step(_loss, opt, mesh, donate=False,
-                                 overlap=False)
-        monkeypatch.setenv("HOROVOD_JIT_OVERLAP", "0")
-        s_knob = build_train_step(_loss, opt, mesh, donate=False)
-        monkeypatch.delenv("HOROVOD_JIT_OVERLAP", raising=False)
-        s_on = build_train_step(_loss, opt, mesh, donate=False,
-                                overlap=True, overlap_threshold=16)
-        off = s_off.lower(params, st, batch).as_text()
-        knob = s_knob.lower(params, st, batch).as_text()
-        on = s_on.lower(params, st, batch).as_text()
-        assert off == knob
-        assert on != off
-
-    def test_guard_flag_rides_buckets_equivalently(self, monkeypatch):
-        """Numerics flag-ride equivalence, bucketed vs monolithic: a
-        NaN batch skips the step (update exactly zero) on both paths,
-        and a clean step produces identical updates."""
-        from horovod_tpu import numerics
-        from horovod_tpu.parallel.train import build_train_step
-        monkeypatch.setenv("HOROVOD_NUMERICS_GUARD", "1")
-        mesh = _mesh()
-        params = _params()
-        g = numerics.guard_non_finite(optax.sgd(0.1), enabled=True)
-        st = g.init(params)
-        bad = jnp.arange(8.0).at[3].set(jnp.nan)
-        clean = jnp.arange(8.0)
-        results = {}
-        for ov in (True, False):
-            s = build_train_step(_loss, g, mesh, donate=False,
-                                 overlap=ov, overlap_threshold=16)
-            p_bad, o_bad, _ = s(params, st, bad)
-            for k in params:
-                np.testing.assert_array_equal(np.asarray(p_bad[k]),
-                                              np.asarray(params[k]))
-            assert numerics.consecutive_skips(o_bad) == 1
-            p_ok, o_ok, _ = s(params, st, clean)
-            assert numerics.consecutive_skips(o_ok) == 0
-            results[ov] = p_ok
-        for k in params:
-            np.testing.assert_allclose(np.asarray(results[True][k]),
-                                       np.asarray(results[False][k]),
-                                       rtol=1e-6)
+                                   overlap_threshold=16)
+        multi = s_multi.lower(params, st, batch).as_text()
+        info = last_overlap_info()
+        assert info["buckets"] >= 2 and multi != one
+        assert sum(info["bucket_bytes"]) == sum(
+            leaf_nbytes(v) for v in jax.tree_util.tree_leaves(params))
 
     def test_mixed_dtype_bucket_and_bf16_flag_routing(self,
                                                       monkeypatch):
@@ -299,7 +208,7 @@ class TestBucketedTrainStep:
         g = numerics.guard_non_finite(optax.sgd(0.1), enabled=True)
         st = g.init(params)
         s = build_train_step(loss2, g, mesh, donate=False,
-                             overlap=True, overlap_threshold=1 << 20)
+                             overlap_threshold=1 << 20)
         p, o, _ = s(params, st, jnp.arange(8.0))
         assert numerics.consecutive_skips(o) == 0
         assert float(jnp.abs(p["wf"] - params["wf"]).max()) > 0
@@ -310,8 +219,8 @@ class TestBucketedTrainStep:
             np.asarray(p2["wf"]), np.asarray(params["wf"]))
 
     def test_custom_grad_reducer_gets_summed_grads(self):
-        """grad_reducer contract unchanged under overlap: it receives
-        SUMMED gradients and owns scaling."""
+        """grad_reducer contract: it receives SUMMED gradients and
+        owns scaling."""
         from horovod_tpu.parallel.train import build_train_step
         mesh = _mesh()
         opt = optax.sgd(1.0)
@@ -328,7 +237,7 @@ class TestBucketedTrainStep:
 
         st = opt.init(params)
         s = build_train_step(loss, opt, mesh, donate=False,
-                             overlap=True, overlap_threshold=4,
+                             overlap_threshold=4,
                              grad_reducer=reducer)
         p, _, _ = s(params, st, jnp.arange(8.0))
         assert seen.get("called")
@@ -336,106 +245,3 @@ class TestBucketedTrainStep:
         # of exactly -1.0 under sgd(1.0)
         np.testing.assert_allclose(np.asarray(p["w"]), -1.0,
                                    rtol=1e-6)
-
-    def test_probe_records_interleaved_bucket_spans(self, tmp_path):
-        """The overlap probe sees every bucket's ready->reduced pair
-        in real execution order, reverse-bucket emission first, and
-        its exposed-comm accounting + timeline spans are well-formed
-        (the single-host face of the 2-proc merged-timeline
-        artifact)."""
-        from horovod_tpu import tracing
-        from horovod_tpu.parallel.train import build_train_step
-        from horovod_tpu.timeline import Timeline
-        import time as _time
-        probe = tracing.OverlapProbe()
-        mesh = _mesh()
-        opt = optax.sgd(0.1)
-        params = _params()
-        st = opt.init(params)
-        batch = jnp.arange(8.0)
-        s = build_train_step(_loss, opt, mesh, donate=False,
-                             overlap=True, overlap_threshold=16,
-                             overlap_probe=probe)
-        s(params, st, batch)          # compile cycle: NOT recorded
-        assert probe.spans() == []
-        probe.armed = True
-        t0 = _time.monotonic_ns()
-        out = s(params, st, batch)
-        jax.block_until_ready(out)
-        probe.step_span(t0, _time.monotonic_ns())
-        probe.armed = False
-        spans = probe.spans()
-        n_buckets = 3
-        assert len(spans) >= n_buckets
-        assert {b for b, *_ in spans} == set(range(n_buckets))
-        for _, t_ready, t_reduced, nb in spans:
-            assert t_reduced >= t_ready and nb > 0
-        acct = probe.hidden_fraction()
-        assert acct["spans"] >= n_buckets
-        assert 0.0 <= acct["exposed_comm_fraction"] <= 1.0
-        tl = Timeline(str(tmp_path / "tl.json"))
-        assert probe.to_timeline(tl) == len(spans)
-        tl.close()
-        doc = json.load(open(tmp_path / "tl.json"))
-        reduces = [e for e in doc if e.get("name") == "REDUCE"]
-        assert len(reduces) == 2 * len(spans)
-        assert any(e.get("name") == "STEP" for e in doc)
-
-
-# ---------------------------------------------------------------------------
-# 2-rank integration: merged timeline with per-bucket reduce spans
-# ---------------------------------------------------------------------------
-
-@pytest.mark.integration
-def test_two_rank_merged_timeline_shows_bucket_overlap(tmp_path):
-    """Acceptance path: a 2-process run of the bucketed jit step with
-    HOROVOD_TIMELINE + an armed OverlapProbe produces per-rank traces
-    that merge into ONE clock-aligned trace whose overlap.bucketN
-    REDUCE spans sit INSIDE the step's STEP envelope on both ranks —
-    per-bucket reduction overlapping backprop compute, compile cycles
-    excluded (the probe records only armed steps)."""
-    tl_path = str(tmp_path / "overlap_tl.json")
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["HOROVOD_TIMELINE"] = tl_path
-    r = subprocess.run(
-        [sys.executable, "-m", "horovod_tpu.runner", "-np", "2",
-         sys.executable, os.path.join("tests", "mp_worker_overlap.py")],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
-    if "Multiprocess computations aren't implemented" in (
-            r.stdout + r.stderr):
-        pytest.skip("this jaxlib's CPU backend cannot run cross-"
-                    "process collectives (affects every multiprocess "
-                    "integration test)")
-    assert r.returncode == 0, r.stdout + "\n" + r.stderr
-    assert r.stdout.count("OVERLAP WORKER OK") == 2
-
-    from horovod_tpu import tracing
-    merged_path, _report = tracing.merge(tl_path)
-    doc = json.load(open(merged_path))
-    evs = doc["traceEvents"]
-    assert {0, 1} <= {e.get("pid") for e in evs}
-
-    # per-rank: REDUCE spans exist and fall inside a STEP envelope
-    for pid in (0, 1):
-        mine = [e for e in evs if e.get("pid") == pid]
-        tids = {}
-        for e in mine:
-            if e.get("name") == "thread_name":
-                tids[e["tid"]] = e["args"]["name"]
-        bucket_tids = {t for t, nm in tids.items()
-                       if nm.startswith("overlap.bucket")}
-        assert len(bucket_tids) >= 2, tids
-        steps = [(b["ts"], e["ts"]) for b, e in zip(
-            [x for x in mine if x.get("name") == "STEP"
-             and x["ph"] == "B"],
-            [x for x in mine if x.get("name") == "STEP"
-             and x["ph"] == "E"])]
-        assert steps
-        reduces = [x for x in mine if x.get("name") == "REDUCE"
-                   and x["ph"] == "B"]
-        inside = [x for x in reduces
-                  if any(b <= x["ts"] <= e for b, e in steps)]
-        assert inside, (steps[:2], [x["ts"] for x in reduces][:4])

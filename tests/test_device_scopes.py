@@ -47,12 +47,12 @@ def variants(names, scope):
 @pytest.fixture(scope="module")
 def transformer_step():
     """A small remat'd, scanned transformer on a 4-device data mesh,
-    bucketed overlap with a threshold that gives several buckets."""
+    with a threshold that gives several buckets."""
     cfg = small_config(remat=True)
     mesh = Mesh(np.array(jax.devices()[:4]), axis_names=("data",))
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     tx = optax.adamw(1e-3)
-    kwargs = dict(batch_spec=P("data"), donate=False, overlap=True,
+    kwargs = dict(batch_spec=P("data"), donate=False,
                   overlap_threshold=8192)
     step = build_train_step(
         lambda p, b: transformer.loss_fn(cfg, p, b), tx, mesh, **kwargs)
@@ -191,16 +191,17 @@ def test_every_all_reduce_carries_exactly_one_bucket(transformer_step):
     assert len(unnamed) == 1 and BACKWARD not in unnamed[0]
 
 
-def test_monolithic_path_names_its_scale(transformer_step):
-    _, args, _ = transformer_step
+def test_one_device_step_has_no_reduce_scope(transformer_step):
+    """Nothing to reduce over: no bucket, no scale, no name of either."""
+    _, (params, state, batch), _ = transformer_step
     cfg = small_config()
-    mesh = Mesh(np.array(jax.devices()[:4]), axis_names=("data",))
+    mesh = Mesh(np.array(jax.devices()[:1]), axis_names=("data",))
     step = build_train_step(
         lambda p, b: transformer.loss_fn(cfg, p, b), optax.adamw(1e-3),
-        mesh, batch_spec=P("data"), donate=False, overlap=False)
-    names = op_names(step.lower(*args).compile())
-    assert variants(names, "hvd.grad_reduce") == {"forward"}
-    assert not any("hvd.grad_reduce.b" in n for n in names)
+        mesh, batch_spec=P("data"), donate=False)
+    names = op_names(step.lower(params, state, batch).compile())
+    assert not any("hvd.grad_reduce" in n for n in names)
+    assert variants(names, "hvd.optimizer")
 
 
 def test_moe_scope(transformer_step):

@@ -18,6 +18,7 @@ from horovod_tpu.metrics import REGISTRY
 from horovod_tpu.parallel import fused_attention as fa
 from horovod_tpu.parallel.ring_attention import (attention,
                                                  dense_attention,
+                                                 flash_attention_path,
                                                  flash_possible_cfg)
 
 # `horovod_tpu.parallel.ring_attention` the attribute is the function.
@@ -337,11 +338,10 @@ def test_path_counter_names_the_padded_form(monkeypatch):
             **dict.fromkeys(after, 0.0), path: 1.0}
 
 
-@pytest.mark.parametrize("mode,backend,path", [
-    ("0", "tpu", "dense"), ("auto", "tpu", "fused"), ("", "tpu", "fused"),
-    ("1", "cpu", "fused"), ("auto", "cpu", "dense")])
-def test_knob_overrides_the_rule(monkeypatch, mode, backend, path):
-    monkeypatch.setenv("HOROVOD_FLASH_ATTENTION", mode)
+@pytest.mark.parametrize("backend,path", [("tpu", "fused"),
+                                          ("cpu", "dense")])
+def test_the_backend_decides_the_path(monkeypatch, backend, path):
+    """No knob: the same call runs fused on the TPU and dense off it."""
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
     before = _traces()
@@ -350,14 +350,17 @@ def test_knob_overrides_the_rule(monkeypatch, mode, backend, path):
     assert after[path] == before[path] + 1
 
 
-def test_knob_forced_on_raises_on_unsupported_shapes(monkeypatch):
-    monkeypatch.setenv("HOROVOD_FLASH_ATTENTION", "1")
+def test_named_fused_path_raises_on_unsupported_calls():
+    """`flash_attention_path`, for callers that name it, keeps its own
+    checks; attention() never sends it such a call."""
     q = jax.ShapeDtypeStruct((1, 200, 4, 128), jnp.bfloat16)
     with pytest.raises(ValueError, match="128-blocks"):
-        _trace_attention(q, q, q)
+        jax.eval_shape(lambda a: flash_attention_path(
+            a, a, a, True, 128 ** -0.5), q)
     with pytest.raises(ValueError, match="causal only"):
         q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
-        _trace_attention(q, q, q, causal=False)
+        jax.eval_shape(lambda a: flash_attention_path(
+            a, a, a, False, 128 ** -0.5), q)
 
 
 def test_attention_takes_grouped_kv_on_the_dense_path():
