@@ -9,6 +9,7 @@ in parametrize arguments) and every compile stays in this process.
 import os
 import re
 import sys
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -162,7 +163,7 @@ def test_grouped_matmul_kernels_compile(topo, k, n):
         assert name in hlo
 
 
-def _flagship_compiled(devices, global_batch):
+def _flagship_lowered(devices, global_batch):
     from horovod_tpu.models import transformer as tfm
     mesh = Mesh(np.array(devices), axis_names=("data",))
     shape = chip_smoke.FLAGSHIP
@@ -174,7 +175,18 @@ def _flagship_compiled(devices, global_batch):
     tok = jax.ShapeDtypeStruct((global_batch, shape["seq"]), jnp.int32,
                                sharding=NamedSharding(mesh, P("data")))
     return step.lower(_on(params, rep), _on(opt_state, rep),
-                      {"tokens": tok, "targets": tok}).compile()
+                      {"tokens": tok, "targets": tok})
+
+
+def _flagship_compiled(devices, global_batch):
+    return _flagship_lowered(devices, global_batch).compile()
+
+
+def _without_options():
+    """`build_train_step` as it was before it chose compile options."""
+    from horovod_tpu.parallel import train
+    return mock.patch.object(train, "async_reduce_hbm_bytes",
+                             lambda mesh: None)
 
 
 def test_flagship_step_fits_one_chip(topo):
@@ -193,6 +205,51 @@ def test_flagship_step_four_chip_data_mesh(topo):
     assert " all-reduce(" in hlo or " all-reduce-start(" in hlo
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_flagship_four_chip_reduction_is_asynchronous(topo):
+    """Under the options `build_train_step` picks for a TPU mesh with
+    a live axis, the compiler makes start / done pairs of gradient
+    all-reduces (it leaves every one synchronous without them), and
+    the step needs no more memory for it than 1 % of what the
+    optionless step needs: the scheduler may not pay for overlap with
+    live ranges across the program's peak."""
+    from horovod_tpu.parallel.train import (ASYNC_REDUCE_OPTIONS,
+                                            MEMORY_LIMIT_OPTION,
+                                            last_overlap_info)
+    batch = 4 * chip_smoke.FLAGSHIP["batch"]
+    lowered = _flagship_lowered(topo.devices, batch)
+    assert last_overlap_info()["compiler_options"] == sorted(
+        ASYNC_REDUCE_OPTIONS) + [MEMORY_LIMIT_OPTION]
+    assert dict(lowered._lowering._compiler_options_kvs) == {
+        **ASYNC_REDUCE_OPTIONS, MEMORY_LIMIT_OPTION: 22}
+    with _without_options():
+        plain = _flagship_lowered(topo.devices, batch)
+    assert last_overlap_info()["compiler_options"] == []
+    assert plain.as_text() == lowered.as_text()   # options, not text
+    shipped, plain = lowered.compile(), plain.compile()
+    pairs = re.compile(r"async-collective-start|all-reduce-start\(")
+    assert pairs.search(shipped.as_text())
+    assert not pairs.search(plain.as_text())
+    mem, base = shipped.memory_analysis(), plain.memory_analysis()
+    assert mem.temp_size_in_bytes - base.temp_size_in_bytes <= 0.01 * (
+        base.argument_size_in_bytes + base.temp_size_in_bytes)
+
+
+def test_one_chip_step_gets_no_compile_option(topo):
+    """No live axis, no option: the one-chip step lowers to the text,
+    and compiles under the (empty) options, it had before the rule."""
+    from horovod_tpu.parallel.train import (async_reduce_hbm_bytes,
+                                            last_overlap_info)
+    one = topo.devices[:1]
+    assert async_reduce_hbm_bytes(
+        Mesh(np.array(one), axis_names=("data",))) is None
+    lowered = _flagship_lowered(one, chip_smoke.FLAGSHIP["batch"])
+    assert last_overlap_info()["compiler_options"] == []
+    with _without_options():
+        plain = _flagship_lowered(one, chip_smoke.FLAGSHIP["batch"])
+    assert lowered.as_text() == plain.as_text()
+    assert not lowered._lowering._compiler_options_kvs
 
 
 def test_resnet50_step_fits_one_chip(topo):

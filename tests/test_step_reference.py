@@ -8,6 +8,8 @@ replicated over) and the bucket sizes it packs to. Then the numerics
 guard's veto on every chip, and the fp16 / bf16 wire casts within the
 error of their dtype."""
 
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
@@ -16,7 +18,11 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from horovod_tpu.parallel.train import (build_train_step,
+from horovod_tpu.metrics import REGISTRY
+from horovod_tpu.parallel import train
+from horovod_tpu.parallel.train import (ASYNC_REDUCE_OPTIONS,
+                                        async_reduce_hbm_bytes,
+                                        build_train_step,
                                         infer_opt_state_specs,
                                         last_overlap_info)
 
@@ -239,3 +245,129 @@ def test_wire_cast_within_its_dtype_error(kind, param_dtype,
         np.testing.assert_allclose(got, np.asarray(g), rtol=0,
                                    atol=slack, err_msg=key)
         assert np.abs(got).max() > 0.5 * scale   # and it is a gradient
+
+
+def _tpu_mesh(n, kind="TPU v5 lite"):
+    """What the options' rule reads of a mesh, for a TPU host that is
+    not here."""
+    chip = SimpleNamespace(platform="tpu", device_kind=kind,
+                           client="a TPU's compiler")
+    return SimpleNamespace(shape={"data": n}, local_devices=[chip] * n)
+
+
+def _builds(label):
+    return REGISTRY.snapshot().get(
+        "hvd_train_step_builds_total", {}).get((label,), 0)
+
+
+@pytest.mark.parametrize("mesh_name", sorted(MESHES))
+def test_cpu_mesh_compiles_under_no_option(mesh_name):
+    """Off the TPU a step is the plain jitted function with no
+    compile option, whatever its mesh, and says so before and after
+    it traces."""
+    mesh = _mesh(mesh_name)
+    assert async_reduce_hbm_bytes(mesh) is None
+    off, on = _builds("off"), _builds("on")
+    opt = optax.sgd(0.1)
+    step = build_train_step(_loss, opt, mesh, donate=False)
+    assert type(step) is type(jax.jit(_loss))
+    assert last_overlap_info() == {
+        "threshold": last_overlap_info()["threshold"], "traced": False,
+        "compiler_options": []}
+    assert (_builds("off"), _builds("on")) == (off + 1, on)
+    params = _params()
+    step(params, opt.init(params), _batches(1)[0])
+    info = last_overlap_info()
+    assert info["traced"] and info["compiler_options"] == []
+
+
+@pytest.mark.parametrize("chips, kind, knows, want", [
+    (1, "TPU v5 lite", True, None), (4, "TPU v5 lite", True, 16 << 30),
+    (4, "TPU v5 lite", False, None), (4, "TPU v9", True, None)],
+    ids=["one-chip", "four-chips", "compiler-refuses", "unknown-chip"])
+def test_tpu_mesh_reduces_asynchronously_by_what_it_is(
+        monkeypatch, chips, kind, knows, want):
+    """The options go to a mesh of known TPU chips with a live axis
+    whose compiler takes them, and to no other; a one-chip mesh is not
+    even probed."""
+    asked = []
+    monkeypatch.setattr(
+        train, "_compiler_knows",
+        lambda device, options: asked.append(sorted(options)) or knows)
+    assert async_reduce_hbm_bytes(_tpu_mesh(chips, kind)) == want
+    probed = chips > 1 and kind in train.HBM_BYTES
+    assert asked == ([sorted(ASYNC_REDUCE_OPTIONS) +
+                      [train.MEMORY_LIMIT_OPTION]] if probed else [])
+
+
+@pytest.mark.parametrize("held, grads, bucket, want", [
+    (6021399040, 2006679552, 469762048, 50),
+    (2617913856, 872600000, 201326592, 22),
+    (12 << 30, 4 << 30, 1 << 30, 100)],
+    ids=["mistral7b-l4-dp4", "flagship-dp4", "does-not-fit"])
+def test_scheduler_memory_limit_is_what_the_tail_holds(held, grads,
+                                                       bucket, want):
+    """Arguments + gradients + one bucket in flight, in whole percent
+    of a v5e's HBM, rounded up: inside the window in which the compiler
+    makes the pairs and sinks nothing into them (46-52 for the Mistral
+    cell's step, 20-26 for the flagship's; PERF.md, PR 34)."""
+    assert train.scheduler_memory_limit_pct(
+        held, grads, bucket, train.HBM_BYTES["TPU v5 lite"]) == want
+
+
+def test_device_bytes_follow_the_specs():
+    """What one device holds of a tree: whole where replicated, a
+    share where a spec names an axis, inexact leaves alone on
+    request."""
+    mesh = _mesh("data2xfsdp2")
+    tree = {"w": jax.ShapeDtypeStruct((8, 4), jnp.float32),
+            "n": jax.ShapeDtypeStruct((6,), jnp.int32)}
+    assert train._device_nbytes(tree, P(), mesh) == (152, 128)
+    assert train._device_nbytes(
+        tree, {"w": P("fsdp", None), "n": P()}, mesh) == (88, 64)
+    assert train._device_nbytes(tree, P(("data", "fsdp")), mesh,
+                                inexact_only=True) == (32, 32)
+
+
+def test_unknown_option_is_found_by_a_probe_compile(monkeypatch):
+    """The probe compiles the identity under the options on the
+    device's own compiler, once: the CPU's knows none of the TPU's, so
+    it answers no (and warns) without a step having failed."""
+    warned = []
+    monkeypatch.setattr(train.hlog, "warning",
+                        lambda *a: warned.append(a))
+    monkeypatch.setattr(train, "_options_known", {})
+    cpu = jax.devices()[0]
+    assert train._compiler_knows(cpu, {}) is True
+    assert train._compiler_knows(cpu, ASYNC_REDUCE_OPTIONS) is False
+    assert train._compiler_knows(cpu, ASYNC_REDUCE_OPTIONS) is False
+    assert len(warned) == 1 and "No such compile option" in str(warned[0])
+    assert len(train._options_known) == 2
+
+
+def test_options_reach_the_call_and_the_ahead_of_time_compile(
+        monkeypatch):
+    """A step that reduces asynchronously computes its options from
+    its arguments and compiles under them however it is compiled:
+    here on the CPU, whose compiler refuses the set, so both the call
+    and `aot_compile` must raise it, and the step's record names it."""
+    from horovod_tpu.parallel.aot import aot_compile
+    monkeypatch.setattr(train, "async_reduce_hbm_bytes",
+                        lambda mesh: 1 << 20)
+    on = _builds("on")
+    opt = optax.sgd(0.1)
+    step = build_train_step(_loss, opt, _mesh("data4"), donate=False)
+    names = sorted(ASYNC_REDUCE_OPTIONS) + [train.MEMORY_LIMIT_OPTION]
+    assert _builds("on") == on + 1
+    assert last_overlap_info()["compiler_options"] == names
+    params = _params()
+    args = (params, opt.init(params), _batches(1)[0])
+    # a few KB of parameters, gradients and batch a device and one
+    # 64 MiB bucket, against an "HBM" of 1 MiB
+    assert step.compiler_options(*args) == {
+        **ASYNC_REDUCE_OPTIONS, train.MEMORY_LIMIT_OPTION: 100}
+    for compile_it in (lambda: step(*args),
+                       lambda: aot_compile(step, *args)):
+        with pytest.raises(Exception, match="No such compile option"):
+            compile_it()
+    assert last_overlap_info()["compiler_options"] == names
