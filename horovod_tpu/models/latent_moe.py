@@ -327,11 +327,11 @@ def dense_ffn(cfg: LatentMoEConfig, p, u: jax.Array) -> jax.Array:
         return _swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
 
 
-def expert_ffn(cfg: LatentMoEConfig, p, u: jax.Array, shared: bool = True
-               ) -> jax.Array:
+def expert_ffn(cfg: LatentMoEConfig, p, u: jax.Array, shared: bool = True,
+               tile_m: Optional[int] = None) -> jax.Array:
     """The held experts' part of the routed sum plus the shared expert
     (`shared=False` leaves it out: another chip of the layer counts
-    it). u: (B, L, D) -> (B, L, D)."""
+    it). `tile_m`: `expert_share_ffn`'s. u: (B, L, D) -> (B, L, D)."""
     B, L, D = u.shape
     with device_scope("hvd.moe.route"):
         h = rmsnorm(u, p["mlp_norm"], cfg.norm_eps)
@@ -342,7 +342,7 @@ def expert_ffn(cfg: LatentMoEConfig, p, u: jax.Array, shared: bool = True
             logits, p["router_bias"], cfg.top_k, cfg.routed_scale)
     y = expert_share_ffn(
         tokens, experts, gates, p["w_gate"], p["w_up"], p["w_down"],
-        first=cfg.experts_first).reshape(B, L, D)
+        first=cfg.experts_first, tile_m=tile_m).reshape(B, L, D)
     if shared:
         with device_scope("hvd.moe.shared"):
             y = y + _swiglu(h, p["s_gate"], p["s_up"],
@@ -385,7 +385,7 @@ def forward(cfg: LatentMoEConfig, params, tokens: jax.Array) -> jax.Array:
 def _head_logits(hidden, head, carried_back: float):
     """hidden (B, L, D) @ head (D, V) -> float32 logits. Backward, the
     cotangent goes into both matmuls as it is and `carried_back`
-    multiplies their float32 results: see `_head_loss`."""
+    multiplies their float32 results: see `head_loss`."""
     return jnp.einsum("bld,dv->blv", hidden, head,
                       preferred_element_type=_F32)
 
@@ -409,7 +409,7 @@ def _head_logits_bwd(carried_back, residuals, ct):
 _head_logits.defvjp(_head_logits_fwd, _head_logits_bwd)
 
 
-def _head_loss(cfg: LatentMoEConfig, params, z, targets, ahead: int,
+def head_loss(cfg: LatentMoEConfig, params, z, targets, ahead: int,
                weight: float = 1.0):
     """Final norm, the untied head over this chip's vocabulary, and
     `weight` times the mean cross-entropy over the positions that have
@@ -465,10 +465,10 @@ def loss_fn(cfg: LatentMoEConfig, params, batch) -> jax.Array:
     tokens = batch["tokens"]
     z = forward(cfg, params, tokens)
     next_tokens = jnp.roll(tokens, -1, axis=1)
-    loss = _head_loss(cfg, params, z, next_tokens, 1)
+    loss = head_loss(cfg, params, z, next_tokens, 1)
     if cfg.mtp and cfg.mtp_lambda > 0:
         z2 = mtp_hidden(cfg, params, z, next_tokens)
-        loss = loss + _head_loss(
+        loss = loss + head_loss(
             cfg, params, z2, jnp.roll(tokens, -2, axis=1), 2,
             weight=cfg.mtp_lambda)
     return loss

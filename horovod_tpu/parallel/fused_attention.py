@@ -28,6 +28,16 @@ heads of it (two or four heads of 192 a step; the caller zero-pads q
 and k where neither holds). With equal widths and grouped kv the
 kernels are what they were with one width.
 
+A sliding window (`window`: the keys a query sees, its own position
+counted, so i - j < window) is the same three kernels with a narrower
+walk: a query block's grid steps start at the oldest key block its
+window reaches (`_key_block`), a key block's at its own query block
+and end where the window does, and the grid has as many steps as a
+window can touch (`walk_steps`), so key blocks wholly older than the
+window are neither loaded nor computed, as blocks above the diagonal
+are; the one block the window's edge cuts is masked. `window=None`
+traces the causal program, instruction for instruction.
+
 Blocks come from the shapes the call sees (`block_size`): the largest
 multiple of 128 up to `BLOCK_CAP` that divides L, so seq 2048 runs
 512-blocks and a seq-256 sample one 256-block. The running max and
@@ -125,13 +135,52 @@ def step_heads(H: int, Hkv: int, Dqk: int, Dv: int):
     return (fits[-1], fits[-1], 1) if fits else None
 
 
-def _visible(q_lo, k_lo, q_axis: int, shape):
-    """Where key position <= query position, for a score block whose
-    first query / key positions are q_lo / k_lo; queries run along
-    `q_axis`."""
+def walk_steps(seq: int, blk: int, window=None) -> int:
+    """Grid steps of one walk: every block of the sequence, or with a
+    window the most blocks one can touch (a query block's keys reach
+    back window - 1 positions from its first row; a key block's
+    queries as far forward from its last)."""
+    if window is None:
+        return seq // blk
+    return min(seq // blk, (window + blk - 2) // blk + 1)
+
+
+def blocks_visited(seq: int, window=None):
+    """(key blocks the forward kernel computes for one head, key
+    blocks on or under the diagonal): what a window skips, as the grid
+    and `_on_visible` have it."""
+    blk = block_size(seq)
+    n = seq // blk
+    reach = seq if window is None else window - 1
+    visited = sum(i - max(i * blk - reach, 0) // blk + 1 for i in range(n))
+    return visited, n * (n + 1) // 2
+
+
+def _key_block(i, j, blk: int, window):
+    """The key block that step j of query block i's walk computes:
+    block j, or with a window the oldest block the window reaches
+    plus j. Steps past the diagonal compute nothing."""
+    if window is None:
+        return j
+    return jnp.maximum(i * blk - (window - 1), 0) // blk + j
+
+
+def _query_block(j, i, window):
+    """The query block that step i of key block j's walk computes:
+    block i (those before j compute nothing), or with a window block
+    j + i (those past the sequence's end compute nothing)."""
+    return i if window is None else j + i
+
+
+def _visible(q_lo, k_lo, q_axis: int, shape, window=None):
+    """Where key position <= query position, and with a window query
+    - key < window, for a score block whose first query / key
+    positions are q_lo / k_lo; queries run along `q_axis`."""
     qpos = q_lo + lax.broadcasted_iota(jnp.int32, shape, q_axis)
     kpos = k_lo + lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
-    return kpos <= qpos
+    if window is None:
+        return kpos <= qpos
+    return jnp.logical_and(kpos <= qpos, qpos - kpos < window)
 
 
 def _tile(x, width: int):
@@ -161,21 +210,30 @@ def _kv_index(heads: int, width: int):
     return [(slice(None), cols) for cols in _head_cols(heads, width)]
 
 
-def _on_visible(q_lo, bq, k_lo, bk, step):
+def _on_visible(q_lo, bq, k_lo, bk, step, window=None, live=None):
     """Run `step(masked)` for a block with any visible key: unmasked
     where every key is visible to every query, masked on the
-    diagonal, not at all above it."""
-    inside = k_lo + bk - 1 <= q_lo
+    diagonal and where the window's edge cuts the block, not at all
+    above the diagonal or wholly older than the window. `live`: the
+    walk's step names a block of the sequence."""
+    def both(seen, reach):
+        """`seen` under the causal mask, and where the walk has them
+        `reach()` within the window and `live`."""
+        if window is not None:
+            seen = jnp.logical_and(seen, reach() < window)
+        return seen if live is None else jnp.logical_and(seen, live)
+    inside = both(k_lo + bk - 1 <= q_lo, lambda: q_lo + bq - 1 - k_lo)
     pl.when(inside)(lambda: step(False))
-    pl.when(jnp.logical_and(jnp.logical_not(inside),
-                            k_lo <= q_lo + bq - 1))(lambda: step(True))
+    outside = jnp.logical_not(inside)
+    some = both(k_lo <= q_lo + bq - 1, lambda: q_lo - (k_lo + bk - 1))
+    pl.when(jnp.logical_and(outside, some))(lambda: step(True))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
                 *, scale: float, bq: int, bk: int, heads: int,
-                kv_heads: int, dqk: int, dv: int):
+                kv_heads: int, dqk: int, dv: int, window=None):
     i, j = pl.program_id(2), pl.program_id(3)
-    q_lo, k_lo = i * bq, j * bk
+    q_lo, k_lo = i * bq, _key_block(i, j, bk, window) * bk
     qcols, vcols = _head_cols(heads, dqk), _head_cols(heads, dv)
 
     @pl.when(j == 0)
@@ -187,7 +245,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
     def step(masked: bool):
         ks = [k_ref[ix] for ix in _kv_index(kv_heads, dqk)]
         vs = [v_ref[ix] for ix in _kv_index(kv_heads, dv)]
-        keep = _visible(q_lo, k_lo, 0, (bq, bk)) if masked else None
+        keep = _visible(q_lo, k_lo, 0, (bq, bk), window) if masked \
+            else None
         for g in range(heads):
             k, v = ks[g % kv_heads], vs[g % kv_heads]
             s = lax.dot_general(q_ref[:, qcols[g]], k, _NT,
@@ -208,7 +267,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
                           preferred_element_type=_F32)
             m_sc[g] = m_next
 
-    _on_visible(q_lo, bq, k_lo, bk, step)
+    _on_visible(q_lo, bq, k_lo, bk, step, window)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _():
@@ -223,9 +282,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc,
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
                dq_sc, *, scale: float, bq: int, bk: int, heads: int,
-               kv_heads: int, dqk: int, dv: int):
+               kv_heads: int, dqk: int, dv: int, window=None):
     i, j = pl.program_id(2), pl.program_id(3)
-    q_lo, k_lo = i * bq, j * bk
+    q_lo, k_lo = i * bq, _key_block(i, j, bk, window) * bk
     qcols, vcols = _head_cols(heads, dqk), _head_cols(heads, dv)
 
     @pl.when(j == 0)
@@ -235,7 +294,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
     def step(masked: bool):
         ks = [k_ref[ix] for ix in _kv_index(kv_heads, dqk)]
         vs = [v_ref[ix] for ix in _kv_index(kv_heads, dv)]
-        keep = _visible(q_lo, k_lo, 0, (bq, bk)) if masked else None
+        keep = _visible(q_lo, k_lo, 0, (bq, bk), window) if masked \
+            else None
         for g in range(heads):
             k, v = ks[g % kv_heads], vs[g % kv_heads]
             s = lax.dot_general(q_ref[:, qcols[g]], k, _NT,
@@ -249,7 +309,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
             dq_sc[:, qcols[g]] += jnp.dot(ds.astype(k.dtype), k,
                                           preferred_element_type=_F32)
 
-    _on_visible(q_lo, bq, k_lo, bk, step)
+    _on_visible(q_lo, bq, k_lo, bk, step, window)
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _():
@@ -258,12 +318,15 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref,
                 dv_ref, dk_sc, dv_sc, *, scale: float, bq: int, bk: int,
-                heads: int, kv_heads: int, dqk: int, dv: int):
+                heads: int, kv_heads: int, dqk: int, dv: int,
+                window=None):
     """Scores transposed, (bk, bq): keys along sublanes, so dV and dK
     are plain p^T @ dO and ds^T @ q, and lse / di broadcast as the
     rows they are stored as."""
     j, c, i = pl.program_id(2), pl.program_id(3), pl.program_id(4)
-    q_lo, k_lo = i * bq, j * bk
+    q_block = _query_block(j, i, window)
+    q_lo, k_lo = q_block * bq, j * bk
+    live = None if window is None else q_block < pl.num_programs(2)
     qcols, vcols = _head_cols(heads, dqk), _head_cols(heads, dv)
 
     @pl.when(jnp.logical_and(c == 0, i == 0))
@@ -275,7 +338,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref,
 
     def step(masked: bool):
         ks, vs = [k_ref[ix] for ix in kidx], [v_ref[ix] for ix in vidx]
-        keep = _visible(q_lo, k_lo, 1, (bk, bq)) if masked else None
+        keep = _visible(q_lo, k_lo, 1, (bk, bq), window) if masked \
+            else None
         for g in range(heads):
             h = g % kv_heads
             k, v = ks[h], vs[h]
@@ -293,7 +357,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref,
             dk_sc[kidx[h]] += jnp.dot(dst.astype(q.dtype), q,
                                       preferred_element_type=_F32)
 
-    _on_visible(q_lo, bq, k_lo, bk, step)
+    _on_visible(q_lo, bq, k_lo, bk, step, window, live)
 
     @pl.when(jnp.logical_and(c == pl.num_programs(3) - 1,
                              i == pl.num_programs(4) - 1))
@@ -322,13 +386,14 @@ def _plan(q, k, v):
             block_size(L))
 
 
-def _q_major(dims, heads, blk: int):
+def _q_major(dims, heads, blk: int, window):
     """Grid and specs of the kernels that walk key blocks for a query
     block (forward, dQ): (grid, q / dQ spec, o / dO spec, k spec,
     v spec, lse / di row spec). One step takes `hs` q heads, a
     (blk, hs * Dqk) column block of q and a (blk, hs * Dv) one of the
     output, and the `kvs` kv heads they read. Keys past the diagonal
-    are not loaded: their steps name the block already resident."""
+    are not loaded: their steps name the block already resident; keys
+    older than a window are no step of the walk."""
     B, L, H, _, Dqk, Dv = dims
     hs, kvs, per_kv = heads
 
@@ -339,23 +404,26 @@ def _q_major(dims, heads, blk: int):
     def kv_cols(width):
         return pl.BlockSpec(
             (None, blk, kvs * width),
-            lambda b, c, i, j: (b, jnp.minimum(j, i), c // per_kv))
+            lambda b, c, i, j: (
+                b, jnp.minimum(_key_block(i, j, blk, window), i),
+                c // per_kv))
     row_spec = pl.BlockSpec((None, hs, 1, blk),
                             lambda b, c, i, j: (b, c, 0, i))
-    return ((B, H // hs, L // blk, L // blk), q_cols(Dqk), q_cols(Dv),
-            kv_cols(Dqk), kv_cols(Dv), row_spec)
+    return ((B, H // hs, L // blk, walk_steps(L, blk, window)),
+            q_cols(Dqk), q_cols(Dv), kv_cols(Dqk), kv_cols(Dv), row_spec)
 
 
-def _forward(q, k, v, scale: float, interpret: bool):
+def _forward(q, k, v, scale: float, interpret: bool, window):
     dims, heads, blk = _plan(q, k, v)
     B, L, H, Hkv, Dqk, Dv = dims
     hs, kvs, _ = heads
     vma = _vma(q, k, v)
     grid, q_spec, o_spec, k_spec, v_spec, row_spec = _q_major(
-        dims, heads, blk)
+        dims, heads, blk, window)
     o, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, bq=blk, bk=blk,
-                          heads=hs, kv_heads=kvs, dqk=Dqk, dv=Dv),
+                          heads=hs, kv_heads=kvs, dqk=Dqk, dv=Dv,
+                          window=window),
         grid=grid,
         in_specs=[q_spec, k_spec, v_spec],
         out_specs=[o_spec, row_spec],
@@ -372,7 +440,8 @@ def _forward(q, k, v, scale: float, interpret: bool):
     return o.reshape(B, L, H, Dv), lse
 
 
-def _backward(q, k, v, o, lse, do, scale: float, interpret: bool):
+def _backward(q, k, v, o, lse, do, scale: float, interpret: bool,
+              window):
     dims, heads, blk = _plan(q, k, v)
     B, L, H, Hkv, Dqk, Dv = dims
     hs, kvs, per_kv = heads
@@ -382,10 +451,10 @@ def _backward(q, k, v, o, lse, do, scale: float, interpret: bool):
     q3, do3 = q.reshape(B, L, H * Dqk), do.reshape(B, L, H * Dv)
     k3, v3 = k.reshape(B, L, Hkv * Dqk), v.reshape(B, L, Hkv * Dv)
     kw = dict(scale=scale, bq=blk, bk=blk, heads=hs, kv_heads=kvs,
-              dqk=Dqk, dv=Dv)
+              dqk=Dqk, dv=Dv, window=window)
 
     grid, q_spec, o_spec, k_spec, v_spec, row_spec = _q_major(
-        dims, heads, blk)
+        dims, heads, blk, window)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, **kw),
         grid=grid,
@@ -401,21 +470,28 @@ def _backward(q, k, v, o, lse, do, scale: float, interpret: bool):
     # dK/dV walks query blocks for a key block of `kvs` kv heads, their
     # q heads in `per_kv` steps of `hs`. Query blocks before the
     # diagonal see nothing of key block j: their steps name the first
-    # that does.
+    # that does. With a window the walk starts there, and its steps
+    # past the sequence's end name the last block.
+    def q_index(j, i):
+        if window is None:
+            return jnp.maximum(i, j)
+        return jnp.minimum(_query_block(j, i, window), L // blk - 1)
+
     def qg_cols(width):
         return pl.BlockSpec(
             (None, blk, hs * width),
-            lambda b, h, j, c, i: (b, jnp.maximum(i, j), h * per_kv + c))
+            lambda b, h, j, c, i: (b, q_index(j, i), h * per_kv + c))
 
     def kvg_cols(width):
         return pl.BlockSpec((None, blk, kvs * width),
                             lambda b, h, j, c, i: (b, j, h))
     rowg_spec = pl.BlockSpec(
         (None, hs, 1, blk),
-        lambda b, h, j, c, i: (b, h * per_kv + c, 0, jnp.maximum(i, j)))
+        lambda b, h, j, c, i: (b, h * per_kv + c, 0, q_index(j, i)))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **kw),
-        grid=(B, Hkv // kvs, L // blk, per_kv, L // blk),
+        grid=(B, Hkv // kvs, L // blk, per_kv,
+              walk_steps(L, blk, window)),
         in_specs=[qg_cols(Dqk), kvg_cols(Dqk), kvg_cols(Dv), qg_cols(Dv),
                   rowg_spec, rowg_spec],
         out_specs=[kvg_cols(Dqk), kvg_cols(Dv)],
@@ -431,35 +507,41 @@ def _backward(q, k, v, o, lse, do, scale: float, interpret: bool):
     return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _attention(q, k, v, scale, interpret):
-    return _forward(q, k, v, scale, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _attention(q, k, v, scale, interpret, window):
+    return _forward(q, k, v, scale, interpret, window)[0]
 
 
-def _attention_fwd(q, k, v, scale, interpret):
-    o, lse = _forward(q, k, v, scale, interpret)
+def _attention_fwd(q, k, v, scale, interpret, window):
+    o, lse = _forward(q, k, v, scale, interpret, window)
     return o, (q, k, v, o, lse)
 
 
-def _attention_bwd(scale, interpret, residuals, do):
+def _attention_bwd(scale, interpret, window, residuals, do):
     q, k, v, o, lse = residuals
-    return _backward(q, k, v, o, lse, do, scale, interpret)
+    return _backward(q, k, v, o, lse, do, scale, interpret, window)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)
 
 
 def fused_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                           scale: float, *,
+                           scale: float, *, window=None,
                            interpret: bool = False) -> jax.Array:
     """Causal self-attention, q (B, L, H, Dqk), k (B, L, Hkv, Dqk),
     v (B, L, Hkv, Dv) with H a multiple of Hkv, for shapes `supported`
-    takes; the output is (B, L, H, Dv). `interpret` runs the kernels
-    in Pallas's interpreter (the CPU tests)."""
+    takes; the output is (B, L, H, Dv). `window`: a query sees its own
+    position and the window - 1 before it (None: all before it).
+    `interpret` runs the kernels in Pallas's interpreter (the CPU
+    tests)."""
+    if window is not None and int(window) < 1:
+        raise ValueError(f"a window holds at least the query's own "
+                         f"position; got {window}")
     if not supported(q.shape, k.shape, v.shape):
         raise ValueError(
             f"fused attention does not take q {q.shape}, k {k.shape}, "
             f"v {v.shape}: it needs equal lengths in 128-blocks, "
             f"head widths (q / k, v) in whole {LANES}-lane column "
             f"blocks, heads in whole groups")
-    return _attention(q, k, v, float(scale), bool(interpret))
+    return _attention(q, k, v, float(scale), bool(interpret),
+                      None if window is None else int(window))

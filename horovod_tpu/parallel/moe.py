@@ -215,7 +215,8 @@ _unpermute.defvjp(_unpermute_fwd, _unpermute_bwd)
 
 def expert_share_ffn(tokens: jax.Array, experts: jax.Array,
                      gates: jax.Array, w_gate: jax.Array, w_up: jax.Array,
-                     w_down: jax.Array, first: int) -> jax.Array:
+                     w_down: jax.Array, first: int,
+                     tile_m: Optional[int] = None) -> jax.Array:
     """The held experts' part of sum_i gate_i * E_i(token), with
     E(h) = (silu(h W_g) * (h W_u)) W_d.
 
@@ -223,7 +224,11 @@ def expert_share_ffn(tokens: jax.Array, experts: jax.Array,
     over the router's full width; w_gate / w_up: (held, D, F), w_down:
     (held, F, D): expert `first + i` of the router is row i. The
     dispatch buffer takes `max_pairs` pairs, which no batch can
-    exceed: no pair is dropped. Returns the output, (T, D) float32."""
+    exceed: no pair is dropped. `tile_m`: rows of a tile of the buffer
+    (`grouped_matmul.TILE_M` if None); a group costs its whole tiles,
+    so a tile well above the rows an expert expects makes a step's
+    time follow the batch's routing less. Returns the output, (T, D)
+    float32."""
     from ..tracing import device_scope
     from . import grouped_matmul as gm
     T, D = tokens.shape
@@ -232,7 +237,7 @@ def expert_share_ffn(tokens: jax.Array, experts: jax.Array,
     bound = max_pairs(T, k, held)
     # The buffer: every group in whole tiles and at least one, so that
     # a row tile of the grouped matmul belongs to one expert.
-    tile = gm.TILE_M
+    tile = tile_m or gm.TILE_M
     n_rows = -(-bound // tile) * tile + held * tile
     kernels = gm.kernels_engage(
         jax.ShapeDtypeStruct((n_rows, D), tokens.dtype), w_gate, tile)

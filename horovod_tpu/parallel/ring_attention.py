@@ -113,8 +113,14 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 # self-attention call whose shapes the kernels of fused_attention.py
 # take runs fused (no f32 (B, H, L, L) scores in HBM, no masked half);
 # everything else, and every backend but the TPU, runs
-# dense_attention. The kernels declare their outputs' varying-axes
-# types, so shard_map's replication checker stays on around them.
+# dense_attention. Both take a sliding window (`window`: a query sees
+# its own position and the window - 1 before it): the fused kernels
+# skip the key blocks wholly older than it as they skip those above
+# the diagonal, the dense path masks them. A window that reaches the
+# whole sequence is no window, and runs the causal program. The ring
+# (a live sequence axis) takes none. The kernels declare their
+# outputs' varying-axes types, so shard_map's replication checker
+# stays on around them.
 # History: rounds 4 and 5 measured JAX's stock Pallas flash kernel at
 # its default 128-blocks on the flagship model (heads of 64, seq 512)
 # and found it 27-37 % slower inside the remat'd layer scan;
@@ -169,33 +175,40 @@ def _flash_supported(q, k, v, causal: bool) -> bool:
             and SEQ_AXIS not in jax.typeof(q).vma)
 
 
-def flash_attention_path(q, k, v, causal: bool, scale: float):
+def flash_attention_path(q, k, v, causal: bool, scale: float,
+                         window: Optional[int] = None):
     """The fused path: (B, L, H, D) in, k / v with H or fewer (grouped)
     heads, the output at v's head width. v may be narrower than q / k
     (latent attention: 192 / 128): it goes to the kernels as it is,
     and q and k do too where the kernels take their width, else
     zero-padded to `_fused_qk_width`. `scale` is the caller's, never
-    derived from a padded width. Causal only."""
+    derived from a padded width. Causal, with or without a sliding
+    `window`; never bidirectional."""
     from .fused_attention import fused_causal_attention
     if not causal:
-        raise ValueError("the fused attention kernels are causal only")
+        raise ValueError("the fused attention kernels are causal, with "
+                         "or without a sliding window; they compute no "
+                         "bidirectional attention")
     extra = _fused_qk_width(q, k, v) - q.shape[-1]
 
     def padded(x):
         return x if extra == 0 else jnp.pad(
             x, ((0, 0),) * (x.ndim - 1) + ((0, extra),))
-    return fused_causal_attention(padded(q), padded(k), v, scale)
+    return fused_causal_attention(padded(q), padded(k), v, scale,
+                                  window=window)
 
 
 def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True,
-                    scale: Optional[float] = None) -> jax.Array:
+                    scale: Optional[float] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Plain attention, (B, L, H, D) in and out: f32 scores, mask,
     softmax, PV, all materialised. The oracle of the ring-attention
     and fused-kernel tests, and attention()'s path wherever the fused
     kernels do not engage. k / v may carry fewer heads than q
     (grouped-query): each is repeated over its group; the output has
-    v's head width."""
+    v's head width. `window` (causal only): query i sees keys j with
+    i - window < j <= i."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     reps = q.shape[2] // k.shape[2]
@@ -206,7 +219,10 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                k.astype(jnp.float32), float(scale))
     if causal:
         L, Lk = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((L, Lk), bool))[None, None]
+        mask = jnp.tril(jnp.ones((L, Lk), bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((L, Lk), bool), -int(window))
+        mask = mask[None, None]
         scores = jnp.where(mask, scores, -jnp.inf)
     p = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
@@ -217,25 +233,50 @@ _m_traces = _METRICS.counter(
     "hvd_attention_traces_total",
     "Times attention() was traced, by the path it took: fused (the "
     "Pallas kernels of parallel/fused_attention.py), fused_padded_qk "
-    "(the same kernels, q and k zero-padded to whole lanes) or dense.",
-    ("path",))
+    "(the same kernels, q and k zero-padded to whole lanes) or dense; "
+    "with a sliding window that masks something, fused_window or "
+    "dense_window.", ("path",))
+_m_key_blocks = _METRICS.counter(
+    "hvd_attention_key_blocks_total",
+    "Key blocks of one head's forward walk, summed over the traces "
+    "of the fused path: visited (computed: on or under the diagonal "
+    "and inside the window) and causal (on or under the diagonal). "
+    "Equal without a window; a window that only masked would leave "
+    "them equal too.", ("blocks",))
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               causal: bool = True,
-              scale: Optional[float] = None) -> jax.Array:
+              scale: Optional[float] = None,
+              window: Optional[int] = None) -> jax.Array:
     """Attention on one device's (B, L, H, D) blocks, for a mesh with
     no live sequence axis: fused where `_flash_supported` says so,
     else `dense_attention`. k / v may carry fewer (grouped) heads, and
     v another head width than q / k; the default `scale` is that of
-    q's own width."""
+    q's own width. `window`: a query sees its own position and the
+    window - 1 before it (causal only); one that reaches the whole
+    sequence is no window."""
+    if window is not None:
+        if not causal or int(window) < 1:
+            raise ValueError(
+                f"a sliding window is causal and holds at least the "
+                f"query's own position; got causal={causal}, "
+                f"window={window}")
+        window = int(window) if window < q.shape[1] else None
     fused = _flash_supported(q, k, v, causal)
     path = "dense" if not fused else (
         "fused" if _fused_qk_width(q, k, v) == q.shape[-1]
         else "fused_padded_qk")
+    if window is not None:
+        path = "fused_window" if fused else "dense_window"
     _m_traces.labels(path=path).inc()
     if fused:
+        from .fused_attention import blocks_visited
+        visited, under = blocks_visited(q.shape[1], window)
+        _m_key_blocks.labels(blocks="visited").inc(visited)
+        _m_key_blocks.labels(blocks="causal").inc(under)
         return flash_attention_path(
             q, k, v, causal,
-            float(q.shape[-1] ** -0.5 if scale is None else scale))
-    return dense_attention(q, k, v, causal, scale)
+            float(q.shape[-1] ** -0.5 if scale is None else scale),
+            window)
+    return dense_attention(q, k, v, causal, scale, window)

@@ -136,17 +136,25 @@ def ring_events(limit: Optional[int] = None) -> List[Tuple]:
 DEVICE_SCOPES: Dict[str, str] = {
     "hvd.embed": "token embedding gather, and its scatter-add in backward",
     "hvd.attn.proj": "attention norm, q/k/v projections, rope, "
-                     "output projection and its tensor psum",
+                     "output projection and its tensor psum; where a "
+                     "model has them, the per-head q / k norms, the "
+                     "output gate and the sub-layer's post-norm",
     "hvd.attn.core": "attention scores, mask, softmax and PV: the "
                      "fused kernels (forward, dQ, dK/dV), the dense "
                      "path or the ring, with the GQA repeat of K / V "
-                     "where the path needs one",
-    "hvd.ffn": "dense FFN: norm and SwiGLU",
+                     "where the path needs one; in a model that mixes "
+                     "layer kinds, the full layers' core",
+    "hvd.attn.window": "the same core in a sliding-window layer "
+                       "(models/window_moe.py): key blocks older than "
+                       "the window are skipped, not masked",
+    "hvd.ffn": "dense FFN: norm and SwiGLU, and the sub-layer's "
+               "post-norm where a model has one",
     "hvd.moe": "MoE FFN: router, dispatch, experts, combine",
     "hvd.moe.route": "dropless expert layer: norm, router scores, "
                      "top-k, the sort and the gathers of the (token, "
                      "expert) pairs held here, and the gated sum back "
-                     "(gathers too)",
+                     "(gathers too); the sub-layer's post-norm where "
+                     "a model has one",
     "hvd.moe.experts": "dropless expert layer: the grouped matmuls "
                        "over the experts held and their SwiGLU",
     "hvd.moe.shared": "the shared expert's SwiGLU",
@@ -169,7 +177,7 @@ DEVICE_SCOPES: Dict[str, str] = {
 # JAX's own key leaves names out, so a cache filled before a scope was
 # added, renamed or moved hands back executables with the old names.
 # Raise it with every such change.
-DEVICE_SCOPES_VERSION = 2
+DEVICE_SCOPES_VERSION = 3
 _BUCKET_SCOPE = "hvd.grad_reduce.b"
 _BUCKET_SCOPE_NAME = re.compile(re.escape(_BUCKET_SCOPE) + "[0-9]+")
 

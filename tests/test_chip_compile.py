@@ -103,6 +103,38 @@ def test_flash_attention_forward_and_backward_compile(topo, shape):
         assert f"[{B},{H},{L},{L}]" not in hlo
 
 
+@pytest.mark.parametrize("L, window, steps", [
+    (16384, 4096, 9), (8192, 4096, 9), (16384, 4000, 9), (2048, 640, 3)],
+    ids=["trinity-window", "trinity-sample", "edge-inside-a-block",
+         "short"])
+def test_windowed_attention_forward_and_backward_compile(topo, L, window,
+                                                         steps):
+    """The fused kernels with a sliding window at the Trinity cell's
+    share (12 q heads in groups of 6 on 2 kv heads of 128, blocks of
+    512): three heads a grid step, a walk of `steps` key blocks a
+    query block where the causal walk has L / 512, one custom call
+    forward and three with backward."""
+    from horovod_tpu.parallel import fused_attention
+    from horovod_tpu.parallel.ring_attention import flash_attention_path
+    assert fused_attention.step_heads(12, 2, 128, 128) == (3, 1, 2)
+    assert fused_attention.walk_steps(L, 512, window) == steps < L // 512
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, L, 12, 128), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((1, L, 2, 128), jnp.bfloat16, sharding=one)
+
+    def fwd(q, k, v):
+        return flash_attention_path(q, k, v, True, 128 ** -0.5, window)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    for fn, calls in ((fwd, 1), (bwd, 3)):
+        hlo = jax.jit(fn).lower(q, k, k).compile().as_text()
+        assert hlo.count('custom_call_target="tpu_custom_call"') == calls
+        assert f"[1,12,{L},{L}]" not in hlo
+
+
 @pytest.mark.parametrize("B, L", [(2, 4096), (1, 256)],
                          ids=["window", "sample"])
 def test_latent_attention_core_compiles_with_v_at_its_own_width(topo, B, L):
@@ -138,22 +170,24 @@ def test_latent_attention_core_compiles_with_v_at_its_own_width(topo, B, L):
     assert calls["_dkv"] == [wide, narrow, wide, wide, narrow, narrow]
 
 
-@pytest.mark.parametrize("k, n", [(3584, 1024), (1024, 3584)],
-                         ids=["gate-up", "down"])
-def test_grouped_matmul_kernels_compile(topo, k, n):
-    """The expert layer's grouped matmuls at the published expert's
-    widths over the cell's dispatch buffer (34,816 rows: 8,192 tokens
-    x 4 choices and a tile of padding an expert): forward, dx and dw
-    are one Mosaic kernel each."""
+@pytest.mark.parametrize("k, n, m, tile", [
+    (3584, 1024, 34816, 256), (1024, 3584, 34816, 256),
+    (3072, 3072, 73728, 1024)], ids=["gate-up", "down", "trinity"])
+def test_grouped_matmul_kernels_compile(topo, k, n, m, tile):
+    """The expert layer's grouped matmuls at the published experts'
+    widths over their cells' dispatch buffers (`xing4`: 34,816 rows,
+    8,192 tokens x 4 choices and a tile of 256 an expert; `trinity`:
+    73,728 rows, 16,384 x 4 and a tile of 1,024 an expert): forward,
+    dx and dw are one Mosaic kernel each."""
     from horovod_tpu.parallel import grouped_matmul as gm
     one = SingleDeviceSharding(topo.devices[0])
-    x = jax.ShapeDtypeStruct((34816, k), jnp.bfloat16, sharding=one)
+    x = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one)
     w = jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16, sharding=one)
     rows = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
 
     def both(x, w, rows):
         def loss(x, w):
-            out = gm.grouped_matmul_kernels(x, w, rows)
+            out = gm.grouped_matmul_kernels(x, w, rows, tile_m=tile)
             return jnp.sum(jnp.square(out.astype(jnp.float32)))
         return jax.value_and_grad(loss, (0, 1))(x, w)
     hlo = jax.jit(both).lower(x, w, rows).compile().as_text()

@@ -235,8 +235,9 @@ def test_registry_names_and_version():
     assert all(name.startswith("hvd.") and SCOPE.fullmatch(name)
                for name in tracing.DEVICE_SCOPES)
     assert (tracing.DEVICE_SCOPES_VERSION,
-            sorted(tracing.DEVICE_SCOPES)) == (2, [
-        "hvd.attn.core", "hvd.attn.proj", "hvd.batchnorm", "hvd.conv",
+            sorted(tracing.DEVICE_SCOPES)) == (3, [
+        "hvd.attn.core", "hvd.attn.proj", "hvd.attn.window",
+        "hvd.batchnorm", "hvd.conv",
         "hvd.embed", "hvd.ffn", "hvd.grad_reduce", "hvd.hc",
         "hvd.head_loss", "hvd.moe", "hvd.moe.experts", "hvd.moe.route",
         "hvd.moe.shared", "hvd.mtp", "hvd.optimizer"])

@@ -298,7 +298,8 @@ def _trace_attention(*shapes, **kw):
 def _traces():
     snap = REGISTRY.snapshot().get("hvd_attention_traces_total", {})
     return {path: snap.get((path,), 0.0)
-            for path in ("fused", "fused_padded_qk", "dense")}
+            for path in ("fused", "fused_padded_qk", "dense",
+                         "fused_window", "dense_window")}
 
 
 def test_path_counter_counts_each_trace(monkeypatch):
@@ -357,7 +358,7 @@ def test_named_fused_path_raises_on_unsupported_calls():
     with pytest.raises(ValueError, match="128-blocks"):
         jax.eval_shape(lambda a: flash_attention_path(
             a, a, a, True, 128 ** -0.5), q)
-    with pytest.raises(ValueError, match="causal only"):
+    with pytest.raises(ValueError, match="no bidirectional attention"):
         q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
         jax.eval_shape(lambda a: flash_attention_path(
             a, a, a, False, 128 ** -0.5), q)
@@ -369,3 +370,156 @@ def test_attention_takes_grouped_kv_on_the_dense_path():
     np.testing.assert_allclose(
         np.asarray(attention(q, k, v)),
         np.asarray(dense_attention(q, rep(k), rep(v))), rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# A sliding window: a query sees its own position and the window - 1
+# before it
+# ---------------------------------------------------------------------------
+
+def _windowed(window):
+    return lambda q, k, v: fa.fused_causal_attention(
+        q, k, v, q.shape[-1] ** -0.5, window=window, interpret=True)
+
+
+def _dense_windowed(window):
+    return lambda q, k, v: dense_attention(q, k, v, True, None, window)
+
+
+# (B, L, H, Hkv, D), window. Seven 128-blocks at a group of 6 and of
+# 1: a window of two whole blocks, one that ends inside a block, one
+# block, a single key. Two 512-blocks: one whole block, and one and a
+# part (nothing to skip, the edge still masked). Three 384-blocks with
+# two heads a step side by side.
+WINDOWED = [((1, 896, 6, 1, 128), 256), ((1, 896, 6, 1, 128), 300),
+            ((1, 896, 2, 2, 128), 256), ((1, 896, 2, 2, 128), 300),
+            ((2, 896, 2, 1, 128), 128), ((1, 896, 2, 1, 128), 1),
+            ((1, 1024, 6, 1, 128), 512), ((1, 1024, 2, 2, 128), 700),
+            ((1, 1152, 4, 4, 256, 128), 400)]
+
+
+@pytest.mark.parametrize("shape,window", WINDOWED, ids=[
+    f"L{s[1]}-h{s[2]}kv{s[3]}-w{w}" for s, w in WINDOWED])
+def test_windowed_fused_matches_dense_forward_and_gradients(shape, window):
+    q, k, v, w = _qkv(*shape)
+    fused, dense = _windowed(window), _dense_windowed(window)
+    np.testing.assert_allclose(np.asarray(fused(q, k, v)),
+                               np.asarray(dense(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(_loss(fused, w), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(_loss(dense, w), argnums=(0, 1, 2))(q, k, v)
+    for g, o, name in zip(got, want, "qkv"):
+        assert g.shape == o.shape and g.dtype == o.dtype
+        np.testing.assert_allclose(np.asarray(g), np.asarray(o),
+                                   rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("window", [1, 5, 64, 200])
+def test_dense_window_is_the_mask_written_out(window):
+    q, k, v, _ = _qkv(1, 64, 2, 1, 16)
+    i, j = np.arange(64)[:, None], np.arange(64)[None, :]
+    seen = (j <= i) & (i - j < window)
+    scores = np.einsum("bqhd,bkhd->bhqk", q, np.repeat(k, 2, 2)) / 4.0
+    scores = np.where(seen, scores, -np.inf)
+    p = np.exp(scores - scores.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    want = np.einsum("bhqk,bkhd->bqhd", p, np.repeat(v, 2, 2))
+    np.testing.assert_allclose(
+        np.asarray(dense_attention(q, k, v, True, None, window)), want,
+        rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(                   # attention() off the TPU
+        np.asarray(attention(q, k, v, window=window)), want,
+        rtol=1e-5, atol=1e-6)
+
+
+def test_no_window_is_the_causal_program_bit_for_bit():
+    """`window=None` is the causal program (its lowered text in the
+    benchmark's cells is the parent's, PERF.md PR 35); a window that
+    reaches the whole sequence is no window to attention(), and in
+    the kernels themselves it masks nothing: equal bits."""
+    q, k, v, w = _qkv(1, 896, 2, 1, 128)
+    causal = _fused(q, k, v)
+    assert np.array_equal(np.asarray(_windowed(None)(q, k, v)),
+                          np.asarray(causal))
+    assert np.array_equal(np.asarray(_windowed(896)(q, k, v)),
+                          np.asarray(causal))
+    got = jax.grad(_loss(_windowed(896), w), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(_loss(_fused, w), argnums=(0, 1, 2))(q, k, v)
+    for g, o in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(o))
+
+
+def _blocks_with_a_visible_pair(seq, window):
+    blk = fa.block_size(seq)
+    i, j = np.arange(seq)[:, None], np.arange(seq)[None, :]
+    seen = (j <= i) & ((i - j < window) if window else True)
+    return int(seen.reshape(seq // blk, blk, seq // blk, blk)
+               .any(axis=(1, 3)).sum())
+
+
+@pytest.mark.parametrize("seq,window,visited,causal", [
+    (16384, 4096, 252, 528), (16384, None, 528, 528),
+    (8192, 4096, 108, 136), (896, 256, 18, 28), (896, 300, 22, 28),
+    (896, 1, 7, 28), (1024, 512, 3, 3), (2048, 4096, 10, 10)])
+def test_a_window_skips_key_blocks(seq, window, visited, causal):
+    """The blocks the forward walk computes are those with a visible
+    pair, and the grid has no step for the others: skipping, not
+    masking."""
+    assert fa.blocks_visited(seq, window) == (visited, causal)
+    if seq <= 2048:
+        assert _blocks_with_a_visible_pair(seq, window) == visited
+    blk = fa.block_size(seq)
+    steps = fa.walk_steps(seq, blk, window)
+    assert steps == (seq // blk if window is None else
+                     min(seq // blk, (window + blk - 2) // blk + 1))
+    q = jax.ShapeDtypeStruct((1, seq, 6, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, seq, 1, 128), jnp.bfloat16)
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(jax.grad(
+        lambda q, k, v: fa.fused_causal_attention(
+            q, k, v, 1.0, window=window).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2)))(q, k, k).jaxpr)
+    n = seq // blk
+    assert sorted(grids) == sorted([(1, 2, n, steps), (1, 2, n, steps),
+                                    (1, 1, n, 2, steps)])
+
+
+def _key_blocks():
+    snap = REGISTRY.snapshot().get("hvd_attention_key_blocks_total", {})
+    return {b: snap.get((b,), 0.0) for b in ("visited", "causal")}
+
+
+@pytest.mark.parametrize("backend,window,path,visited", [
+    ("tpu", 4096, "fused_window", 252), ("tpu", None, "fused", 528),
+    ("tpu", 16384, "fused", 528), ("tpu", 1 << 30, "fused", 528),
+    ("cpu", 4096, "dense_window", 0), ("cpu", 16384, "dense", 0)])
+def test_window_paths_and_the_block_counter(monkeypatch, backend, window,
+                                            path, visited):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    q = jax.ShapeDtypeStruct((1, 16384, 12, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, 16384, 2, 128), jnp.bfloat16)
+    paths, blocks = _traces(), _key_blocks()
+    assert _trace_attention(q, k, k, window=window).shape == q.shape
+    paths_after, blocks_after = _traces(), _key_blocks()
+    assert {p: paths_after[p] - paths[p] for p in paths} == {
+        **dict.fromkeys(paths, 0.0), path: 1.0}
+    assert {b: blocks_after[b] - blocks[b] for b in blocks} == {
+        "visited": visited, "causal": 528.0 if visited else 0.0}
+
+
+@pytest.mark.parametrize("kw", [dict(causal=False, window=8),
+                                dict(window=0), dict(window=-4)])
+def test_a_window_is_causal_and_holds_the_query(kw):
+    q, k, v, _ = _qkv(1, 64, 2, 1, 16)
+    with pytest.raises(ValueError, match="sliding window"):
+        attention(q, k, v, **kw)
+    if kw.get("causal", True):
+        with pytest.raises(ValueError, match="window holds at least"):
+            fa.fused_causal_attention(q, k, v, 1.0, interpret=True, **kw)

@@ -153,9 +153,10 @@ def test_fused_core_takes_latent_widths(monkeypatch):
     from horovod_tpu.parallel import fused_attention
     seen = []
 
-    def interpreted(q, k, v, scale):
+    def interpreted(q, k, v, scale, window=None):
         seen.append((q.shape, k.shape, v.shape, scale))
-        return fused_causal_attention(q, k, v, scale, interpret=True)
+        return fused_causal_attention(q, k, v, scale, window=window,
+                                      interpret=True)
     monkeypatch.setattr(fused_attention, "fused_causal_attention",
                         interpreted)
     q, k = activations(5, 1, 128, 2, 192), activations(6, 1, 128, 2, 192)
