@@ -24,9 +24,10 @@ rows of dead tiles are never written: the caller masks them. Outputs
 declare the varying-mesh-axes type of their inputs, so the kernels
 trace inside `shard_map` with the replication checker on.
 
-`grouped_matmul` picks by what it observes: the kernels on a TPU for
-bf16 operands in whole lanes, else `lax.ragged_dot`, which computes
-the same thing from the same layout (the CPU tests' oracle).
+The caller (`moe.expert_share_ffn`) picks by what it observes
+(`kernels_engage`): the kernels on a TPU for bf16 operands in whole
+lanes, else `lax.ragged_dot`, which computes the same thing from the
+same layout (the CPU tests' oracle).
 """
 
 from __future__ import annotations
@@ -248,13 +249,3 @@ def kernels_engage(x: jax.Array, w: jax.Array, tile_m: int = TILE_M) -> bool:
     return (jax.default_backend() == "tpu"
             and x.dtype == w.dtype == jnp.bfloat16
             and supported(x.shape, w.shape, tile_m))
-
-
-def grouped_matmul(x: jax.Array, w: jax.Array, group_rows: jax.Array, *,
-                   tile_m: int = TILE_M) -> jax.Array:
-    """out[r] = x[r] @ w[group of r] for rows laid out as the module
-    docstring says: the kernels where `kernels_engage`, else
-    `lax.ragged_dot` over the same layout."""
-    if kernels_engage(x, w, tile_m):
-        return grouped_matmul_kernels(x, w, group_rows, tile_m=tile_m)
-    return lax.ragged_dot(x, w, group_rows.astype(jnp.int32))

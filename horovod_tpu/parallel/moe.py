@@ -11,23 +11,28 @@ standard TPU-friendly formulation from GShard/Switch).
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import functools
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
 from ..metrics import REGISTRY as _METRICS
+from . import row_movers as rm
 from .mesh import EXPERT_AXIS
 
 
 _m_moe_traces = _METRICS.counter(
     "hvd_moe_traces_total",
     "Times an expert layer was traced, by the dispatch it took: "
-    "sorted_grouped_kernels (expert_share_ffn: pairs sorted by "
-    "expert, gathered, the Pallas grouped matmuls), sorted_ragged "
-    "(the same with lax.ragged_dot, off the TPU) or onehot_capacity "
-    "(top1_route's (T, E, C) einsum).", ("dispatch",))
+    "sorted_live_tiles (expert_share_ffn: pairs sorted by expert, "
+    "their rows moved by the Pallas row movers and multiplied by the "
+    "Pallas grouped matmuls, both over the live tiles only), "
+    "sorted_ragged (the same with gathers over the whole buffer and "
+    "lax.ragged_dot: off the TPU, f32, shapes the kernels do not "
+    "take) or onehot_capacity (top1_route's (T, E, C) einsum).",
+    ("dispatch",))
 _m_moe_bound = _METRICS.gauge(
     "hvd_moe_pairs_bound",
     "Rows of the buffer the last traced expert_share_ffn gathers its "
@@ -144,70 +149,114 @@ def max_pairs(tokens: int, k: int, held: int) -> int:
     return tokens * min(k, held)
 
 
-# Dispatch and combine are gathers of whole rows in both directions:
-# pair p = (token t, choice j) sits in buffer row slots[t, j] where
-# landed[t, j], and buffer row s holds pair order[s] where valid[s].
-# Left to autodiff, each gather's transpose is a scatter-add of tens
-# of thousands of rows, which the TPU serialises (PERF.md, PR 31: 4.6
-# ms a layer each); written out, the transposes are gathers too. One
-# gather a choice j, T rows each: a (T, k, D) intermediate would be
-# tiled with k on the sublanes and copied to be summed.
+# Dispatch and combine move whole rows in both directions: pair p =
+# (token t, choice j) sits in buffer row slots[t, j] where landed[t, j],
+# and buffer row s holds pair order[s] where valid[s]. Left to
+# autodiff, each gather's transpose is a scatter-add of tens of
+# thousands of rows, which the TPU serialises (PERF.md, PR 31: 4.6 ms a
+# layer each); written out, the transposes are gathers too. Where the
+# grouped matmuls' kernels engage (`tile` is the buffer's tile), the
+# rows are moved by `row_movers.py`: `rows_in` fills the rows of the
+# live tiles that hold a pair (zero in a live tile's padding, dead
+# tiles unwritten), `rows_out` and `rows_dot` read the landed pairs'
+# rows only, so the work follows the batch's routing and not the
+# buffer's bound (PERF.md, PR 36). Else (`tile` None: off the TPU, f32 callers) the
+# same as `jnp` gathers over the whole buffer, one a choice j, T rows
+# each: a (T, k, D) intermediate would be tiled with k on the sublanes
+# and copied to be summed.
 
-def _choice_rows(x, slots, landed, j):
+class _Routing(NamedTuple):
+    """Where a batch's pairs sit. order, valid: (rows,) of the buffer;
+    slots, landed: (T, k) of the pairs; live: (1,) the rows of the
+    tiles that hold a group, the rest of the buffer is dead."""
+    order: jax.Array
+    valid: jax.Array
+    slots: jax.Array
+    landed: jax.Array
+    live: jax.Array
+
+
+def _choice_rows(x, route, j):
     """x[slots[:, j]] in float32, zero where choice j did not land."""
-    return jnp.where(landed[:, j, None], x[slots[:, j]], 0).astype(
-        jnp.float32)
+    return jnp.where(route.landed[:, j, None], x[route.slots[:, j]],
+                     0).astype(jnp.float32)
 
 
-@jax.custom_vjp
-def _permute(tokens, order, slots, landed):
+def _token_of_row(route):
+    """Buffer row -> the token whose pair it holds, -1 where none."""
+    return jnp.where(route.valid, route.order // route.slots.shape[1], -1)
+
+
+def _row_of_pair(route):
+    """(T, k) pair -> its buffer row, -1 where it did not land."""
+    return jnp.where(route.landed, route.slots, -1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _permute(tokens, route, tile):
     """tokens (T, D) -> the dispatch buffer (rows, D): row s holds the
-    token of pair order[s]. Rows that hold no pair hold some token's
-    row too: finite, and nothing downstream reads what comes of
-    them."""
-    return tokens[order // slots.shape[1]]
+    token of pair order[s]. A row of a live tile that holds no pair is
+    finite (some token's row, or zero from the kernel), and nothing
+    downstream reads what comes of it; a dead tile's rows are
+    unwritten where the kernel moves the rows."""
+    if tile is None:
+        return tokens[route.order // route.slots.shape[1]]
+    return rm.rows_in(rm.pack_rows(tokens), _token_of_row(route), route.live,
+                      width=tokens.shape[1], tile_m=tile)
 
 
-def _permute_fwd(tokens, order, slots, landed):
-    return _permute(tokens, order, slots, landed), (slots, landed)
+def _permute_fwd(tokens, route, tile):
+    return _permute(tokens, route, tile), route
 
 
-def _permute_bwd(res, d_xs):
-    slots, landed = res
-    d_tokens = sum(_choice_rows(d_xs, slots, landed, j)
-                   for j in range(slots.shape[1]))
-    return d_tokens.astype(d_xs.dtype), None, None, None
+def _permute_bwd(tile, route, d_xs):
+    if tile is None:
+        d_tokens = sum(_choice_rows(d_xs, route, j)
+                       for j in range(route.slots.shape[1])
+                       ).astype(d_xs.dtype)
+    else:
+        d_tokens = rm.rows_out(d_xs, _row_of_pair(route))
+    return d_tokens, None
 
 
 _permute.defvjp(_permute_fwd, _permute_bwd)
 
 
-@jax.custom_vjp
-def _unpermute(ys, gates, order, slots, valid, landed):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _unpermute(ys, gates, route, tile):
     """The gated sum back at the tokens, float32: out[t] = sum over the
     landed choices j of gates[t, j] * ys[slots[t, j]]. ys: (rows, D),
     gates: (T, k). Only rows that hold a pair are read."""
-    return sum(gates[:, j, None] * _choice_rows(ys, slots, landed, j)
-               for j in range(slots.shape[1]))
+    if tile is None:
+        return sum(gates[:, j, None] * _choice_rows(ys, route, j)
+                   for j in range(gates.shape[1]))
+    return rm.rows_out(ys, _row_of_pair(route), gates,
+                       out_dtype=jnp.float32)
 
 
-def _unpermute_fwd(ys, gates, order, slots, valid, landed):
-    return (_unpermute(ys, gates, order, slots, valid, landed),
-            (ys, gates, order, slots, valid, landed))
+def _unpermute_fwd(ys, gates, route, tile):
+    return _unpermute(ys, gates, route, tile), (ys, gates, route)
 
 
-def _unpermute_bwd(res, d_out):
+def _unpermute_bwd(tile, res, d_out):
     """d_ys is exactly zero in every row that holds no pair: that is
     what keeps such rows out of the weights' gradients."""
-    ys, gates, order, slots, valid, landed = res
+    ys, gates, route = res
     k = gates.shape[1]
-    row_gate = jnp.where(valid, gates.reshape(-1)[order], 0.0)
-    d_ys = d_out.astype(ys.dtype)[order // k].astype(jnp.float32) \
-        * row_gate[:, None]
-    d_gates = jnp.stack(
-        [jnp.sum(_choice_rows(ys, slots, landed, j) * d_out, axis=-1)
-         for j in range(k)], axis=1)
-    return d_ys.astype(ys.dtype), d_gates, None, None, None, None
+    row_gate = jnp.where(route.valid, gates.reshape(-1)[route.order], 0.0)
+    if tile is None:
+        d_ys = d_out.astype(ys.dtype)[route.order // k].astype(
+            jnp.float32) * row_gate[:, None]
+        d_gates = jnp.stack(
+            [jnp.sum(_choice_rows(ys, route, j) * d_out, axis=-1)
+             for j in range(k)], axis=1)
+        return d_ys.astype(ys.dtype), d_gates, None
+    # the products first: they are the last reader of ys, whose buffer
+    # is then free for d_ys (the order the gathers above leave XLA, too)
+    d_gates = rm.rows_dot(ys, _row_of_pair(route), d_out)
+    d_ys = rm.rows_in(rm.pack_rows(d_out), _token_of_row(route), route.live,
+                      width=ys.shape[1], tile_m=tile, scale=row_gate)
+    return d_ys, d_gates, None
 
 
 _unpermute.defvjp(_unpermute_fwd, _unpermute_bwd)
@@ -239,9 +288,11 @@ def expert_share_ffn(tokens: jax.Array, experts: jax.Array,
     # a row tile of the grouped matmul belongs to one expert.
     tile = tile_m or gm.TILE_M
     n_rows = -(-bound // tile) * tile + held * tile
+    # one rule for the grouped matmuls and the row movers
     kernels = gm.kernels_engage(
-        jax.ShapeDtypeStruct((n_rows, D), tokens.dtype), w_gate, tile)
-    _m_moe_traces.labels(dispatch="sorted_grouped_kernels" if kernels
+        jax.ShapeDtypeStruct((n_rows, D), tokens.dtype), w_gate, tile
+    ) and rm.supported(T, k, D)
+    _m_moe_traces.labels(dispatch="sorted_live_tiles" if kernels
                          else "sorted_ragged").inc()
     _m_moe_bound.set(bound)
     i32 = jnp.int32
@@ -268,27 +319,33 @@ def expert_share_ffn(tokens: jax.Array, experts: jax.Array,
         ).reshape(T, k)
         # buffer row -> pair
         row = jnp.arange(n_rows, dtype=i32)
-        row_group = gm.tile_groups(padded, n_rows // tile, tile)[0][
-            row // tile]
+        tile_group, live_tiles = gm.tile_groups(padded, n_rows // tile,
+                                                tile)
+        row_group = tile_group[row // tile]
         rank = row - padded_starts[row_group]
         valid = (rank >= 0) & (rank < sizes[row_group])
         order = by_expert[jnp.clip(starts[row_group] + rank, 0, T * k - 1)]
-        xs = _permute(tokens, order, slots, landed)          # (n_rows, D)
+        route = _Routing(order, valid, slots, landed, live_tiles * tile)
+        mover_tile = tile if kernels else None
+        xs = _permute(tokens, route, mover_tile)             # (n_rows, D)
 
     with device_scope("hvd.moe.experts"):
         # bf16 in and out, f32 accumulation inside, like every other
         # matmul of the model; the SwiGLU and the gated sum are f32.
         # No mask on these buffers: a row that holds no pair is a dead
-        # tile's, which the kernels neither compute nor read back, or
-        # padding inside a live tile, finite, whose outputs no pair
-        # reads and whose cotangent `_unpermute` makes exactly zero.
+        # tile's, which the kernels neither write, compute nor read
+        # back (the SwiGLU between them is elementwise), or padding
+        # inside a live tile, finite, whose outputs no pair reads and
+        # whose cotangent `_unpermute` makes exactly zero.
         def grouped(x, w):
-            return gm.grouped_matmul(x, w, padded, tile_m=tile)
+            if kernels:
+                return gm.grouped_matmul_kernels(x, w, padded, tile_m=tile)
+            return lax.ragged_dot(x, w, padded)
         gate = jax.nn.silu(grouped(xs, w_gate).astype(jnp.float32))
         act = (gate * grouped(xs, w_up).astype(jnp.float32)
                ).astype(tokens.dtype)
         ys = grouped(act, w_down)
 
     with device_scope("hvd.moe.route"):
-        out = _unpermute(ys, gates, order, slots, valid, landed)
+        out = _unpermute(ys, gates, route, mover_tile)
     return out

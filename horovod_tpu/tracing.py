@@ -151,10 +151,12 @@ DEVICE_SCOPES: Dict[str, str] = {
                "post-norm where a model has one",
     "hvd.moe": "MoE FFN: router, dispatch, experts, combine",
     "hvd.moe.route": "dropless expert layer: norm, router scores, "
-                     "top-k, the sort and the gathers of the (token, "
-                     "expert) pairs held here, and the gated sum back "
-                     "(gathers too); the sub-layer's post-norm where "
-                     "a model has one",
+                     "top-k, the sort of the (token, expert) pairs held "
+                     "here, their rows moved into the dispatch buffer "
+                     "and the gated sum back (on the TPU the row "
+                     "movers' kernels over the live tiles and landed "
+                     "pairs, else gathers over the whole buffer); the "
+                     "sub-layer's post-norm where a model has one",
     "hvd.moe.experts": "dropless expert layer: the grouped matmuls "
                        "over the experts held and their SwiGLU",
     "hvd.moe.shared": "the shared expert's SwiGLU",

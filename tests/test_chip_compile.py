@@ -197,6 +197,94 @@ def test_grouped_matmul_kernels_compile(topo, k, n, m, tile):
         assert name in hlo
 
 
+@pytest.mark.parametrize("T, D, n, tile", [
+    (8192, 3584, 34816, 256), (16384, 3072, 73728, 1024),
+    (8192, 3072, 40960, 1024)], ids=["xing4", "trinity", "trinity-sample"])
+def test_row_movers_compile(topo, T, D, n, tile):
+    """The expert layer's row movers and their pack at both cells' shapes (T
+    tokens of 4 choices, a buffer of n rows in tiles of `tile`): each
+    is one Mosaic kernel under its own name, within the VMEM the call
+    allows itself (a compile that passes: the fetch scratch, the f32
+    stage and the double-buffered blocks)."""
+    from horovod_tpu.parallel import row_movers as rm
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    bf16, f32, i32, u32 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.uint32
+    assert rm.supported(T, 4, D)
+    movers = {
+        "pack": (rm.pack_rows, (on((T, D), f32),), "hvd_moe_rows_pack"),
+        "dispatch": (lambda tok, index, live: rm.rows_in(
+            tok, index, live, width=D, tile_m=tile),
+            (on((T, 8, 256), u32), on((n,), i32), on((1,), i32)),
+            "hvd_moe_rows_in"),
+        "combine-backward": (lambda d_out, index, live, gate: rm.rows_in(
+            d_out, index, live, width=D, tile_m=tile, scale=gate),
+            (on((T, 8, 256), u32), on((n,), i32), on((1,), i32),
+             on((n,), f32)), "hvd_moe_rows_in"),
+        "combine-backward-gates": (rm.rows_dot,
+                                   (on((n, D), bf16), on((T, 4), i32),
+                                    on((T, D), f32)), "hvd_moe_rows_dot"),
+        "combine": (lambda ys, code, gates: rm.rows_out(
+            ys, code, gates, out_dtype=f32),
+            (on((n, D), bf16), on((T, 4), i32), on((T, 4), f32)),
+            "hvd_moe_rows_out"),
+        "dispatch-backward": (lambda d_xs, code: rm.rows_out(d_xs, code),
+                              (on((n, D), bf16), on((T, 4), i32)),
+                              "hvd_moe_rows_out"),
+    }
+    for fn, args, name in movers.values():
+        hlo = jax.jit(fn).lower(*args).compile().as_text()
+        assert hlo.count('custom_call_target="tpu_custom_call"') == 1
+        assert name in hlo
+        assert f"[{n},{D}]" not in hlo.replace(
+            f"bf16[{n},{D}]", "")          # the buffer only in bf16
+        assert not re.search(rf"\[\d+,{D}\][^=]* gather\(", hlo)
+
+
+def test_expert_layer_on_the_tpu_takes_the_live_tiles(topo):
+    """What a TPU trace of `expert_share_ffn` takes at the `xing4`
+    cell's shapes: the counter says `sorted_live_tiles`, and forward
+    and backward are sixteen Mosaic kernels (the pack, rows in and
+    rows out twice each, the gates' products once, the grouped
+    matmuls' forward, dx and dw three times each) with no gather of a
+    row of the model's width left in the program."""
+    import horovod_tpu as hvd
+    from horovod_tpu.parallel import moe
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    T, D, F, held, k = 8192, 3584, 1024, 8, 4
+    bf16 = jnp.bfloat16
+
+    def both(tokens, experts, gates, w_gate, w_up, w_down):
+        def loss(tokens, gates, w_gate, w_up, w_down):
+            return jnp.sum(moe.expert_share_ffn(
+                tokens, experts, gates, w_gate, w_up, w_down, 0))
+        return jax.value_and_grad(loss, (0, 1, 2, 3, 4))(
+            tokens, gates, w_gate, w_up, w_down)
+    label = ("sorted_live_tiles",)
+    before = hvd.metrics().get("hvd_moe_traces_total", {}).get(label, 0)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = jax.jit(both).lower(
+            on((T, D), bf16), on((T, k), jnp.int32),
+            on((T, k), jnp.float32), on((held, D, F), bf16),
+            on((held, D, F), bf16), on((held, F, D), bf16))
+    assert hvd.metrics()["hvd_moe_traces_total"][label] == before + 1
+    hlo = lowered.compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 16
+    for name, calls in (("hvd_moe_rows_pack", 2), ("hvd_moe_rows_in", 2),
+                        ("hvd_moe_rows_out", 2),
+                        ("hvd_moe_rows_dot", 1),
+                        ("hvd_grouped_matmul_fwd", 3),
+                        ("hvd_grouped_matmul_dx", 3),
+                        ("hvd_grouped_matmul_dw", 3)):
+        assert len(re.findall(rf"%{name}[.\d]* = ", hlo)) == calls, name
+    assert not re.search(rf"\[\d+,{D}\][^=]* gather\(", hlo)
+
+
 def _flagship_lowered(devices, global_batch):
     from horovod_tpu.models import transformer as tfm
     mesh = Mesh(np.array(devices), axis_names=("data",))
