@@ -231,31 +231,31 @@ def dense_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 _m_traces = _METRICS.counter(
     "hvd_attention_traces_total",
-    "Times attention() was traced, by the path it took: fused (the "
-    "Pallas kernels of parallel/fused_attention.py), fused_padded_qk "
-    "(the same kernels, q and k zero-padded to whole lanes) or dense; "
-    "with a sliding window that masks something, fused_window or "
-    "dense_window.", ("path",))
+    "Times attention() was traced, by path: fused (the kernels of "
+    "parallel/fused_attention.py), fused_padded_qk (q and k padded to "
+    "whole lanes) or dense; with a window that masks something, "
+    "fused_window or dense_window. sparse_attention() counts its own: "
+    "sparse_dense (at or under dense_len) or sparse_blocks.", ("path",))
 _m_key_blocks = _METRICS.counter(
     "hvd_attention_key_blocks_total",
-    "Key blocks of one head's forward walk, summed over the traces "
-    "of the fused path: visited (computed: on or under the diagonal "
-    "and inside the window) and causal (on or under the diagonal). "
-    "Equal without a window; a window that only masked would leave "
-    "them equal too.", ("blocks",))
+    "Key blocks of one head's forward walk over the fused path's "
+    "traces: visited (computed: on or under the diagonal and inside "
+    "the window) and causal (on or under the diagonal), equal without "
+    "a window. selected: blocks of SparseSpec.block keys the queries "
+    "of a sparse_blocks trace keep, from shapes.", ("blocks",))
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,
               causal: bool = True,
               scale: Optional[float] = None,
               window: Optional[int] = None) -> jax.Array:
-    """Attention on one device's (B, L, H, D) blocks, for a mesh with
-    no live sequence axis: fused where `_flash_supported` says so,
-    else `dense_attention`. k / v may carry fewer (grouped) heads, and
-    v another head width than q / k; the default `scale` is that of
-    q's own width. `window`: a query sees its own position and the
-    window - 1 before it (causal only); one that reaches the whole
-    sequence is no window."""
+    """Softmax attention on one device's (B, L, H, D) blocks over keys
+    known when traced (no live sequence axis): fused where
+    `_flash_supported` says so, else `dense_attention`. k / v may carry
+    fewer (grouped) heads, v another head width than q / k; `scale`
+    defaults to q's own width's. `window`: a query sees its own position
+    and the window - 1 before it (causal only; one that reaches the whole
+    sequence is none). Keys chosen from the data: sparse_attention.py."""
     if window is not None:
         if not causal or int(window) < 1:
             raise ValueError(

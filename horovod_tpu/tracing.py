@@ -147,6 +147,22 @@ DEVICE_SCOPES: Dict[str, str] = {
     "hvd.attn.window": "the same core in a sliding-window layer "
                        "(models/window_moe.py): key blocks older than "
                        "the window are skipped, not masked",
+    "hvd.attn.linear": "linear attention with a decay a head "
+                       "(parallel/linear_attention.py): the products "
+                       "inside a chunk, the state carried from chunk to "
+                       "chunk and its cotangent carried back, all passes",
+    "hvd.attn.select": "block-sparse attention's selection "
+                       "(parallel/sparse_attention.py select_blocks): "
+                       "pooled keys, their softmax, the sum over a "
+                       "group's heads, the max over a block, top-k; on "
+                       "the TPU also the words and walk tables the "
+                       "kernels read (fused with it or beside it)",
+    "hvd.attn.sparse": "attention over the key blocks each query "
+                       "selected: the kernels that walk the table of "
+                       "visited blocks (forward, dQ, dK/dV) with their "
+                       "token-level mask, or one masked softmax; the "
+                       "layer at or under its dense length runs "
+                       "hvd.attn.core",
     "hvd.ffn": "dense FFN: norm and SwiGLU, and the sub-layer's "
                "post-norm where a model has one",
     "hvd.moe": "MoE FFN: router, dispatch, experts, combine",
@@ -179,7 +195,7 @@ DEVICE_SCOPES: Dict[str, str] = {
 # JAX's own key leaves names out, so a cache filled before a scope was
 # added, renamed or moved hands back executables with the old names.
 # Raise it with every such change.
-DEVICE_SCOPES_VERSION = 3
+DEVICE_SCOPES_VERSION = 4
 _BUCKET_SCOPE = "hvd.grad_reduce.b"
 _BUCKET_SCOPE_NAME = re.compile(re.escape(_BUCKET_SCOPE) + "[0-9]+")
 

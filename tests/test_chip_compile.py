@@ -135,6 +135,113 @@ def test_windowed_attention_forward_and_backward_compile(topo, L, window,
         assert f"[1,12,{L},{L}]" not in hlo
 
 
+@pytest.mark.parametrize("L", [32768, 16384], ids=["cell", "half"])
+def test_sparse_attention_kernels_compile_at_the_cell_s_shapes(topo, L):
+    """The block-sparse layer beyond its dense length, as a TPU traces
+    it at the MiniCPM-SALA cell's share (16 q heads on one kv head of
+    128, 64 blocks of 64 keys a query): the selection in XLA, then the
+    three kernels that walk the table of visited blocks, four heads a
+    grid step at blocks of 512; no (L x L) array and no whole pooled
+    score tensor (16 heads x L x 2,047) left in the program."""
+    import horovod_tpu as hvd
+    from horovod_tpu.parallel import sparse_attention as sa
+    spec = sa.SparseSpec()
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, L, 16, 128), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((1, L, 1, 128), jnp.bfloat16, sharding=one)
+    assert sa.kernel_block(L, spec) == 512
+
+    def fwd(q, k, v):
+        return sa.sparse_attention(q, k, v, spec)
+
+    def bwd(q, k, v):
+        return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    def traces():
+        return hvd.metrics().get("hvd_attention_traces_total", {}).get(
+            ("sparse_blocks",), 0)
+    for fn, calls in ((fwd, ("fwd",)), (bwd, ("fwd", "dq", "dkv"))):
+        before = traces()
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+            lowered = jax.jit(fn).lower(q, k, k)
+        assert traces() == before + 1
+        hlo = lowered.compile().as_text()
+        assert hlo.count('custom_call_target="tpu_custom_call"') \
+            == len(calls)
+        for name in calls:
+            assert len(re.findall(
+                rf"%hvd_sparse_attention_{name}[.\d]* = ", hlo)) == 1, name
+        assert "hvd_fused_attention" not in hlo
+        assert f",{L},{L}]" not in hlo and f"[{L},{L}" not in hlo
+        assert f"{L},{L // 16 - 1}]" not in hlo
+
+
+def test_sparse_layer_at_its_dense_length_runs_the_fused_kernels(topo):
+    """At or under `dense_len` the layer is `attention()`: on a TPU
+    the causal fused kernels, as they are."""
+    from horovod_tpu.parallel import sparse_attention as sa
+    one = SingleDeviceSharding(topo.devices[0])
+    q = jax.ShapeDtypeStruct((1, 8192, 16, 128), jnp.bfloat16, sharding=one)
+    k = jax.ShapeDtypeStruct((1, 8192, 1, 128), jnp.bfloat16, sharding=one)
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        lowered = jax.jit(lambda q, k, v: sa.sparse_attention(
+            q, k, v, sa.SparseSpec())).lower(q, k, k)
+    hlo = lowered.compile().as_text()
+    assert len(re.findall(r"%hvd_fused_attention_fwd[.\d]* = ", hlo)) == 1
+    assert "hvd_sparse_attention" not in hlo
+
+
+@pytest.mark.parametrize("kernels", [True, False],
+                         ids=["kernels", "chunks"])
+def test_linear_attention_compiles_at_the_cell_s_shapes(topo, kernels):
+    """The lightning core forward and backward at 16 heads of 128 and
+    32,768 positions, chunks of 256, no (L x L) array. As a TPU traces
+    it in bf16: three kernels (forward, dQ, dK/dV) with the state in
+    VMEM and no state in HBM. The `jax.numpy` path (any other backend
+    or dtype): the states of 128 chunks (134 MB a pass), under 1.5 GB
+    of temporaries."""
+    import horovod_tpu as hvd
+    from horovod_tpu.parallel import linear_attention as la
+    from horovod_tpu.tracing import device_scope
+    one = SingleDeviceSharding(topo.devices[0])
+    L = 32768
+    x = jax.ShapeDtypeStruct((1, L, 16, 128), jnp.bfloat16, sharding=one)
+    slopes = jax.ShapeDtypeStruct((16,), jnp.float32, sharding=one)
+
+    def core(*a):
+        # XLA names a custom call after the innermost name of its
+        # scope: under the model's scope the kernel's own
+        with device_scope("hvd.attn.linear"):
+            return la.linear_attention(*a)
+
+    def both(q, k, v, slopes):
+        return jax.value_and_grad(
+            lambda *a: core(*a, slopes).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+    label = ("kernel" if kernels else "chunks",)
+    before = hvd.metrics().get("hvd_linear_attention_traces_total",
+                               {}).get(label, 0)
+    with mock.patch.object(jax, "default_backend",
+                           lambda: "tpu" if kernels else "cpu"):
+        lowered = jax.jit(both).lower(x, x, x, slopes)
+    assert hvd.metrics()["hvd_linear_attention_traces_total"][label] \
+        == before + 1
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    assert f",{L},{L}]" not in hlo and f"[{L},{L}" not in hlo
+    # a state a chunk and head, whatever order XLA lays them in
+    states = re.search(r"f32\[(1,)?(128,16|16,128|2048),128,128\]", hlo)
+    if kernels:
+        for name in ("fwd", "dq", "dkv"):
+            assert len(re.findall(
+                rf"%hvd_linear_attention_{name}[.\d]* = ", hlo)) == 1, name
+        assert not states
+    else:
+        assert "tpu_custom_call" not in hlo and states
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
 @pytest.mark.parametrize("B, L", [(2, 4096), (1, 256)],
                          ids=["window", "sample"])
 def test_latent_attention_core_compiles_with_v_at_its_own_width(topo, B, L):

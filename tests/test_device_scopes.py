@@ -235,8 +235,9 @@ def test_registry_names_and_version():
     assert all(name.startswith("hvd.") and SCOPE.fullmatch(name)
                for name in tracing.DEVICE_SCOPES)
     assert (tracing.DEVICE_SCOPES_VERSION,
-            sorted(tracing.DEVICE_SCOPES)) == (3, [
-        "hvd.attn.core", "hvd.attn.proj", "hvd.attn.window",
+            sorted(tracing.DEVICE_SCOPES)) == (4, [
+        "hvd.attn.core", "hvd.attn.linear", "hvd.attn.proj",
+        "hvd.attn.select", "hvd.attn.sparse", "hvd.attn.window",
         "hvd.batchnorm", "hvd.conv",
         "hvd.embed", "hvd.ffn", "hvd.grad_reduce", "hvd.hc",
         "hvd.head_loss", "hvd.moe", "hvd.moe.experts", "hvd.moe.route",
