@@ -334,17 +334,19 @@ def expert_share_ffn(tokens: jax.Array, experts: jax.Array,
         # matmul of the model; the SwiGLU and the gated sum are f32.
         # No mask on these buffers: a row that holds no pair is a dead
         # tile's, which the kernels neither write, compute nor read
-        # back (the SwiGLU between them is elementwise), or padding
-        # inside a live tile, finite, whose outputs no pair reads and
-        # whose cotangent `_unpermute` makes exactly zero.
-        def grouped(x, w):
-            if kernels:
-                return gm.grouped_matmul_kernels(x, w, padded, tile_m=tile)
-            return lax.ragged_dot(x, w, padded)
-        gate = jax.nn.silu(grouped(xs, w_gate).astype(jnp.float32))
-        act = (gate * grouped(xs, w_up).astype(jnp.float32)
-               ).astype(tokens.dtype)
-        ys = grouped(act, w_down)
+        # back (the SwiGLU and its backward run inside them, on live
+        # tiles alone), or padding inside a live tile, finite, whose
+        # outputs no pair reads and whose cotangent `_unpermute` makes
+        # exactly zero.
+        if kernels:
+            act = gm.grouped_swiglu_kernels(xs, w_gate, w_up, padded,
+                                            tile_m=tile)
+            ys = gm.grouped_matmul_kernels(act, w_down, padded, tile_m=tile)
+        else:
+            act = gm.swiglu(lax.ragged_dot(xs, w_gate, padded),
+                            lax.ragged_dot(xs, w_up, padded)
+                            ).astype(tokens.dtype)
+            ys = lax.ragged_dot(act, w_down, padded)
 
     with device_scope("hvd.moe.route"):
         out = _unpermute(ys, gates, route, mover_tile)

@@ -174,7 +174,9 @@ DEVICE_SCOPES: Dict[str, str] = {
                      "pairs, else gathers over the whole buffer); the "
                      "sub-layer's post-norm where a model has one",
     "hvd.moe.experts": "dropless expert layer: the grouped matmuls "
-                       "over the experts held and their SwiGLU",
+                       "over the experts held and their SwiGLU (on "
+                       "the TPU inside the gate / up kernel pair, "
+                       "over the live tiles alone)",
     "hvd.moe.shared": "the shared expert's SwiGLU",
     "hvd.hc": "residual streams (hyper-connections): coefficients, "
               "Sinkhorn, the mix into a sub-layer's input, the "

@@ -350,26 +350,72 @@ def test_row_movers_compile(topo, T, D, n, tile):
         assert not re.search(rf"\[\d+,{D}\][^=]* gather\(", hlo)
 
 
-def test_expert_layer_on_the_tpu_takes_the_live_tiles(topo):
-    """What a TPU trace of `expert_share_ffn` takes at the `xing4`
-    cell's shapes: the counter says `sorted_live_tiles`, and forward
-    and backward are sixteen Mosaic kernels (the pack, rows in and
-    rows out twice each, the gates' products once, the grouped
-    matmuls' forward, dx and dw three times each) with no gather of a
-    row of the model's width left in the program."""
+@pytest.mark.parametrize("m, k, n, tile", [
+    (34816, 3584, 1024, 256), (73728, 3072, 3072, 1024)],
+    ids=["xing4", "trinity"])
+def test_grouped_swiglu_kernels_compile(topo, m, k, n, tile):
+    """Gate and up with the SwiGLU inside at both cells' shapes:
+    forward (both products and the activation), the two cotangents,
+    dx as one sum and dw twice are five Mosaic kernels, each within
+    the VMEM the call allows itself, and the cotangents take the
+    products' buffers."""
+    from horovod_tpu.parallel import grouped_matmul as gm
+    one = SingleDeviceSharding(topo.devices[0])
+    x = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one)
+    w = jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16, sharding=one)
+    rows = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one)
+
+    def both(x, w_gate, w_up, rows, d_act):
+        act, pullback = jax.vjp(
+            lambda *a: gm.grouped_swiglu_kernels(*a, rows, tile_m=tile),
+            x, w_gate, w_up)
+        return act, pullback(d_act)
+    hlo = jax.jit(both).lower(
+        x, w, w, rows, jax.ShapeDtypeStruct((m, n), jnp.bfloat16,
+                                            sharding=one)).compile().as_text()
+    kernels = [line for line in hlo.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 5
+    # outside a scope XLA names the call after the pass as well
+    for name, calls in (("hvd_grouped_matmul_swiglu_fwd", 1),
+                        ("hvd_grouped_matmul_swiglu_dh", 1),
+                        ("hvd_grouped_matmul_swiglu_dx", 1),
+                        ("hvd_grouped_matmul_dw", 2)):
+        found = [line for line in kernels
+                 if re.match(rf"\s*%\w*{name}_*[.\d]* = ", line)]
+        assert len(found) == calls, name
+    assert "output_to_operand_aliasing={{0}: (3, {}), {1}: (4, {})}" in \
+        next(line for line in kernels if "swiglu_dh" in line)
+
+
+@pytest.mark.parametrize("T, D, F, tile, n_rows", [
+    (8192, 3584, 1024, None, 34816), (16384, 3072, 3072, 1024, 73728)],
+    ids=["xing4", "trinity"])
+def test_expert_layer_on_the_tpu_takes_the_live_tiles(topo, T, D, F, tile,
+                                                      n_rows):
+    """What a TPU trace of `expert_share_ffn` takes at both cells'
+    shapes: the counter says `sorted_live_tiles`, and forward and
+    backward are fifteen Mosaic kernels (the pack, rows in and rows
+    out twice each, the gates' products once; gate and up with the
+    SwiGLU inside: forward, cotangents, dx; the down projection's
+    forward and dx; dw three times) with no gather of a row of the
+    model's width left in the program, and nothing elementwise on an
+    operand of the dispatch buffer's shape: neither the SwiGLU, its
+    backward nor a sum of two dx is XLA's, whose loop fusions would
+    walk the dead tiles too."""
     import horovod_tpu as hvd
     from horovod_tpu.parallel import moe
     one = SingleDeviceSharding(topo.devices[0])
 
     def on(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-    T, D, F, held, k = 8192, 3584, 1024, 8, 4
+    held, k = 8, 4
     bf16 = jnp.bfloat16
 
     def both(tokens, experts, gates, w_gate, w_up, w_down):
         def loss(tokens, gates, w_gate, w_up, w_down):
             return jnp.sum(moe.expert_share_ffn(
-                tokens, experts, gates, w_gate, w_up, w_down, 0))
+                tokens, experts, gates, w_gate, w_up, w_down, 0, tile))
         return jax.value_and_grad(loss, (0, 1, 2, 3, 4))(
             tokens, gates, w_gate, w_up, w_down)
     label = ("sorted_live_tiles",)
@@ -380,16 +426,31 @@ def test_expert_layer_on_the_tpu_takes_the_live_tiles(topo):
             on((T, k), jnp.float32), on((held, D, F), bf16),
             on((held, D, F), bf16), on((held, F, D), bf16))
     assert hvd.metrics()["hvd_moe_traces_total"][label] == before + 1
+    buffer = rf"tensor<{n_rows}x({D}|{F})x"
+    text = lowered.as_text()
+    assert re.search(buffer, text)
+    assert not [line for line in text.splitlines()
+                if re.search(buffer, line) and re.search(
+                    r"stablehlo\.(logistic|multiply|add|convert)\b", line)]
     hlo = lowered.compile().as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 16
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 15
     for name, calls in (("hvd_moe_rows_pack", 2), ("hvd_moe_rows_in", 2),
                         ("hvd_moe_rows_out", 2),
                         ("hvd_moe_rows_dot", 1),
-                        ("hvd_grouped_matmul_fwd", 3),
-                        ("hvd_grouped_matmul_dx", 3),
+                        ("hvd_grouped_matmul_swiglu_fwd", 1),
+                        ("hvd_grouped_matmul_swiglu_dh", 1),
+                        ("hvd_grouped_matmul_swiglu_dx", 1),
+                        ("hvd_grouped_matmul_fwd", 1),
+                        ("hvd_grouped_matmul_dx", 1),
                         ("hvd_grouped_matmul_dw", 3)):
         assert len(re.findall(rf"%{name}[.\d]* = ", hlo)) == calls, name
     assert not re.search(rf"\[\d+,{D}\][^=]* gather\(", hlo)
+    # the buffer's shape only on the kernels and on what names their
+    # results: no fusion, copy or add of XLA's over it
+    for line in hlo.splitlines():
+        if re.search(rf"= [a-z0-9]+\[{n_rows},({D}|{F})\]", line):
+            assert re.search(r" (custom-call|get-tuple-element|parameter)\(",
+                             line), line
 
 
 def _flagship_lowered(devices, global_batch):

@@ -1,9 +1,10 @@
-"""`parallel/grouped_matmul.py`: the three kernels in Pallas's
-interpreter against `lax.ragged_dot` over the same layout, forward and
-both gradients; dead tiles cost nothing and are never written; the
-layout helpers; the engagement rule; under `shard_map` with the
-replication checker on, where replicated weights get their gradient
-summed."""
+"""`parallel/grouped_matmul.py`: the kernels in Pallas's interpreter
+against `lax.ragged_dot` over the same layout, forward and both
+gradients, the plain product and the gate / up pair with the SwiGLU
+inside; dead tiles cost nothing, are never written and never read
+(NaN in them reaches nothing); the layout helpers; the engagement
+rule; under `shard_map` with the replication checker on, where
+replicated weights get their gradient summed."""
 
 from unittest import mock
 
@@ -125,6 +126,152 @@ def test_under_shard_map_replicated_weights_get_a_summed_gradient():
             x[i * 384:(i + 1) * 384], w, sizes)[:256])) for i in (0, 1))
     dx_want, dw_want = jax.grad(whole, (0, 1))(x, w)
     np.testing.assert_allclose(dw, dw_want, rtol=1e-4, atol=1e-4)
+    for i in (0, 1):
+        lo = i * 384
+        np.testing.assert_allclose(dx[lo:lo + 256], dx_want[lo:lo + 256],
+                                   rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# Gate and up as one kernel pair with the SwiGLU inside
+# ---------------------------------------------------------------------------
+
+SWIGLU_CASES = {
+    # tile, rows of every group, k, n, dead tiles
+    "tile256-several": (256, [512, 256, 768], 256, 384, 2),
+    "tile256-one-each": (256, [256, 256], 128, 256, 3),
+    "tile1024-one-each": (1024, [1024, 1024], 128, 128, 1),
+    "tile1024-several": (1024, [2048, 1024], 128, 256, 2),
+    "tile128-one-group": (128, [640], 384, 128, 3),
+}
+swiglu_cases = pytest.mark.parametrize("case", list(SWIGLU_CASES))
+
+
+def swiglu_operands(case, dtype):
+    """(tile, sizes, live rows, x, w_gate, w_up, cotangent of act)."""
+    tile, rows, k, n, dead = SWIGLU_CASES[case]
+    live = sum(rows)
+    key = jax.random.split(jax.random.PRNGKey(5), 4)
+    shapes = [(live + dead * tile, k), (len(rows), k, n), (len(rows), k, n),
+              (live + dead * tile, n)]
+    x, w_gate, w_up, cot = (
+        (jax.random.normal(kk, shape, jnp.float32) * scale).astype(dtype)
+        for kk, shape, scale in zip(key, shapes, (1.0, 0.1, 0.1, 1.0)))
+    return tile, jnp.asarray(rows, jnp.int32), live, x, w_gate, w_up, cot
+
+
+def dead_rows(a, live, value):
+    return a.at[live:].set(value)
+
+
+def oracle_swiglu(sizes):
+    def fn(x, w_gate, w_up):
+        return gm.swiglu(jax.lax.ragged_dot(x, w_gate, sizes),
+                         jax.lax.ragged_dot(x, w_up, sizes)).astype(x.dtype)
+    return fn
+
+
+@swiglu_cases
+def test_swiglu_kernels_against_ragged_dot_with_nan_in_dead_tiles(case):
+    """act, d_x and both dW against `lax.ragged_dot` + `jax.numpy`,
+    with every row of a dead tile NaN in x and in act's cotangent (the
+    interpreter leaves NaN in what a kernel never writes, so the
+    residuals' dead tiles are NaN too): nothing of a dead tile is
+    read, so the live rows and the weights' gradients stay finite and
+    equal to the oracle's over zeros."""
+    tile, sizes, live, x, w_gate, w_up, cot = swiglu_operands(
+        case, jnp.float32)
+    assert x.shape[0] > live
+    got, pullback = jax.vjp(
+        lambda *a: gm.grouped_swiglu_kernels(*a, sizes, tile_m=tile,
+                                             interpret=True),
+        dead_rows(x, live, jnp.nan), w_gate, w_up)
+    dx, dw_gate, dw_up = pullback(dead_rows(cot, live, jnp.nan))
+    want, pullback = jax.vjp(oracle_swiglu(sizes), dead_rows(x, live, 0.0),
+                             w_gate, w_up)
+    dx_want, dw_gate_want, dw_up_want = pullback(dead_rows(cot, live, 0.0))
+    for a in (got[:live], dx[:live], dw_gate, dw_up):
+        assert np.isfinite(a).all()
+    np.testing.assert_allclose(got[:live], want[:live], rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(dx[:live], dx_want[:live], rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_allclose(dw_gate, dw_gate_want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(dw_up, dw_up_want, rtol=1e-4, atol=1e-4)
+
+
+@swiglu_cases
+def test_swiglu_kernels_round_where_three_grouped_matmuls_did(case):
+    """bf16: each product rounded, the SwiGLU in f32 from those, act
+    rounded once, as two `grouped_matmul_kernels` with `jax.numpy`
+    between them gave it: act bit for bit on the live rows. The
+    gradients within bf16's roundings of that path's: the cotangents
+    of the two products are written as one formula in f32 and rounded
+    once like autodiff's, d_x has one rounding of the f32 sum where
+    that path rounds each half and their sum."""
+    tile, sizes, live, x, w_gate, w_up, cot = swiglu_operands(
+        case, jnp.bfloat16)
+
+    def separate(x, w_gate, w_up):
+        h_gate, h_up = (gm.grouped_matmul_kernels(x, w, sizes, tile_m=tile,
+                                                  interpret=True)
+                        for w in (w_gate, w_up))
+        return gm.swiglu(h_gate, h_up).astype(x.dtype)
+    cot = dead_rows(cot, live, 0.0)
+    got, pullback = jax.vjp(
+        lambda *a: gm.grouped_swiglu_kernels(*a, sizes, tile_m=tile,
+                                             interpret=True),
+        x, w_gate, w_up)
+    dx, dw_gate, dw_up = pullback(cot)
+    want, pullback = jax.vjp(separate, x, w_gate, w_up)
+    dx_want, dw_gate_want, dw_up_want = pullback(cot)
+    assert got.dtype == dx.dtype == dw_gate.dtype == jnp.bfloat16
+
+    def f32(a):
+        return np.asarray(a, np.float32)
+    np.testing.assert_array_equal(f32(got[:live]), f32(want[:live]))
+    for g, w_ in ((dx[:live], dx_want[:live]), (dw_gate, dw_gate_want),
+                  (dw_up, dw_up_want)):
+        # bf16 keeps 8 bits: a rounding is 2**-8 of the value
+        np.testing.assert_allclose(
+            f32(g), f32(w_), rtol=2 ** -6,
+            atol=2 ** -7 * float(np.abs(f32(w_)).max()))
+    # and against the oracle at bf16's own tolerance
+    oracle = oracle_swiglu(sizes)(x, w_gate, w_up)
+    np.testing.assert_allclose(f32(got[:live]), f32(oracle[:live]),
+                               rtol=1e-2, atol=1e-2)
+
+
+def test_swiglu_kernels_refuse_what_the_matmuls_refuse():
+    with pytest.raises(ValueError, match="grouped matmul does not take"):
+        gm.grouped_swiglu_kernels(
+            jnp.zeros((256, 128)), jnp.zeros((2, 128, 128)),
+            jnp.zeros((2, 128, 96)), jnp.asarray([128, 128]), tile_m=128)
+
+
+def test_swiglu_under_shard_map_replicated_weights_get_a_summed_gradient():
+    mesh = Mesh(np.array(jax.devices()[:2]), axis_names=("data",))
+    sizes = jnp.asarray([128, 128], jnp.int32)
+    x, w_gate = operands(2 * 384, 128, 128, 2)
+    w_up = jnp.flip(w_gate, axis=2)
+
+    def local(x, w_gate, w_up):
+        def loss(x, w_gate, w_up):
+            out = gm.grouped_swiglu_kernels(x, w_gate, w_up, sizes,
+                                            tile_m=TILE, interpret=True)
+            return jnp.sum(jnp.square(out[:256]))
+        return jax.grad(loss, (0, 1, 2))(x, w_gate, w_up)
+    dx, dw_gate, dw_up = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P("data"), P(), P()),
+        out_specs=(P("data"), P(), P())))(x, w_gate, w_up)
+
+    def whole(x, w_gate, w_up):
+        return sum(jnp.sum(jnp.square(oracle_swiglu(sizes)(
+            x[i * 384:(i + 1) * 384], w_gate, w_up)[:256])) for i in (0, 1))
+    dx_want, dw_gate_want, dw_up_want = jax.grad(whole, (0, 1, 2))(
+        x, w_gate, w_up)
+    np.testing.assert_allclose(dw_gate, dw_gate_want, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(dw_up, dw_up_want, rtol=1e-4, atol=1e-4)
     for i in (0, 1):
         lo = i * 384
         np.testing.assert_allclose(dx[lo:lo + 256], dx_want[lo:lo + 256],
