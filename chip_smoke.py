@@ -58,25 +58,25 @@ def say(phase: str, **kv) -> None:
 
 class CompileCounter:
     """Programs handed to the backend compiler (cache hits included)
-    while the block runs."""
+    while the block runs: the rise of the program's own
+    `hvd_jit_programs_total` (common/compile_cache.py)."""
 
     def __init__(self):
+        from horovod_tpu.common import compile_cache
+        compile_cache.listen()
         self.n = 0
-        self._on = False
-        import jax.monitoring
-        jax.monitoring.register_event_duration_secs_listener(self._hit)
 
-    def _hit(self, event, _secs, **_kw):
-        if self._on and event == \
-                "/jax/core/compile/backend_compile_duration":
-            self.n += 1
+    @staticmethod
+    def _total() -> int:
+        from horovod_tpu.metrics import snapshot
+        return int(sum(snapshot()["hvd_jit_programs_total"].values()))
 
     def __enter__(self):
-        self.n, self._on = 0, True
+        self.n, self._before = 0, self._total()
         return self
 
     def __exit__(self, *exc):
-        self._on = False
+        self.n = self._total() - self._before
 
 
 def device_record() -> dict:

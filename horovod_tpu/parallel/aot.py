@@ -16,13 +16,11 @@ backend does not report it).
 
 from __future__ import annotations
 
-import time
 from typing import Any, Callable, Tuple
-
-import jax
 
 from ..common import logging as hlog
 from ..metrics import REGISTRY as _METRICS
+from ..tracing import host_span
 
 _m_lower = _METRICS.counter(
     "hvd_aot_lower_seconds_total",
@@ -44,16 +42,12 @@ def aot_compile(step_fn: Callable[..., Any], *args
     exact-shape, exact-placement: callers must feed arguments matching
     ``args``. flops is 0.0 whenever cost analysis is unavailable.
     """
-    # Set-up code: both halves are timed where they happen and show as
-    # spans in a profiler capture taken over set-up.
-    t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation("hvd::aot.lower"):
+    with host_span("aot.lower") as lower:
         lowered = step_fn.lower(*args)
-    t1 = time.perf_counter()
-    with jax.profiler.TraceAnnotation("hvd::aot.compile"):
+    with host_span("aot.compile") as compile_:
         compiled = lowered.compile()
-    _m_lower.inc(t1 - t0)
-    _m_compile.inc(time.perf_counter() - t1)
+    _m_lower.inc(lower.seconds)
+    _m_compile.inc(compile_.seconds)
     _m_programs.inc()
     flops = 0.0
     try:

@@ -45,6 +45,7 @@ doing?* Four pieces:
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import os
 import re
@@ -77,6 +78,8 @@ _m_postmortems = _METRICS.counter(
 
 # One tuple per span event: (mono_ns, kind, name, seq, arg). The deque
 # append is the entire enabled hot path — GIL-atomic, no lock, no IO.
+# What seq and arg hold is the kind's own (host_span below: ids and
+# seconds).
 _ring: Optional[collections.deque] = None
 _ring_size = 0
 
@@ -222,6 +225,86 @@ def bucket_scope(bucket_id: int):
 
 
 # ---------------------------------------------------------------------------
+# host spans (set-up and control code)
+# ---------------------------------------------------------------------------
+# Set-up is host code the device trace cannot see: `hvd.init()`, the
+# lowering and compiling of programs. One `with host_span(name)` is the
+# one way to time such a phase: the ring gets its begin and end (a dump
+# of a job that hangs in set-up says in which phase), the registry its
+# seconds (readable with no capture running), and a live
+# profiler capture an `hvd::<name>` annotation on the capture's clock.
+# Not for the eager plane's per-collective path: its annotations stay
+# gated where they are (ops/engine.py, ops/controller.py).
+
+HOST_SPANS: Dict[str, str] = {
+    "init": "the whole of hvd.init(), the phases below and the knob "
+            "checks around them",
+    "init.distributed": "jax.distributed.initialize() and the exchange "
+                        "of process indices (multi-process worlds)",
+    "init.topology": "topology.detect: the first look at the devices, "
+                     "so the backend's bring-up where nothing touched "
+                     "JAX before",
+    "init.engine": "process sets, the eager engine, the negotiated "
+                   "controller and its native core's load or make, "
+                   "the autotuner, the hierarchical factor",
+    "init.observability": "timeline, metrics server, tracing.on_init, "
+                          "journal, telemetry",
+    "aot.lower": "aot_compile: tracing and lowering a step to StableHLO",
+    "aot.compile": "aot_compile: the backend compiler, or the load of "
+                   "its result from the persistent compile cache",
+}
+SPAN_BEGIN, SPAN_END = "span_begin", "span_end"
+
+_m_span_seconds = _METRICS.counter(
+    "hvd_host_span_seconds_total",
+    "Host seconds spent inside tracing.host_span blocks, by span "
+    "(a span's own time is this less its children's).", ("span",))
+
+_span_ids = itertools.count()
+_open_span = threading.local()   # .id: the thread's innermost open span
+
+
+class host_span:
+    """Time one phase of set-up or control code under a name of
+    `HOST_SPANS`. Ring entries: (`SPAN_BEGIN`, name, seq = the span's
+    id, arg = the id of the thread's enclosing span or -1) and
+    (`SPAN_END`, name, seq = the id, arg = seconds). `seconds` holds
+    the duration once the block has ended, raised or not."""
+
+    __slots__ = ("name", "seconds", "_id", "_parent", "_begin_ns",
+                 "_annotation")
+
+    def __init__(self, name: str):
+        if name not in HOST_SPANS:
+            raise ValueError(
+                f"{name!r} is no registered host span: add it to "
+                f"tracing.HOST_SPANS (known: {', '.join(HOST_SPANS)})")
+        self.name = name
+        self.seconds = 0.0
+
+    def __enter__(self) -> "host_span":
+        self._parent = getattr(_open_span, "id", -1)
+        self._id = _open_span.id = next(_span_ids)
+        self._annotation = None
+        if profiler_active():
+            import jax
+            self._annotation = jax.profiler.TraceAnnotation(
+                "hvd::" + self.name)
+            self._annotation.__enter__()
+        record(SPAN_BEGIN, self.name, self._id, float(self._parent))
+        self._begin_ns = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = (time.monotonic_ns() - self._begin_ns) / 1e9
+        record(SPAN_END, self.name, self._id, self.seconds)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        _open_span.id = self._parent
+        _m_span_seconds.labels(span=self.name).inc(self.seconds)
+
+
+# ---------------------------------------------------------------------------
 # trace context: step id + agreed collective sequence id
 # ---------------------------------------------------------------------------
 
@@ -313,6 +396,10 @@ def trace_digest() -> Dict[str, Any]:
     from the flight-recorder ring."""
     phases: Dict[str, Dict[str, float]] = {}
     for _, kind, _, _, arg in ring_events():
+        if kind in (SPAN_BEGIN, SPAN_END):
+            # Host spans nest, so one total would count the children
+            # twice: hvd_host_span_seconds_total holds them by name.
+            continue
         d = phases.setdefault(kind, {"count": 0, "total_s": 0.0})
         d["count"] += 1
         d["total_s"] += float(arg)
@@ -495,8 +582,10 @@ def _resolve_profiler_probe():
     layout => always True (keep annotating, the pre-gate
     behavior)."""
     try:
-        from jax._src.lib import xla_client
-        probe = xla_client._xla.profiler.TraceMe.is_enabled
+        import jax
+        # TraceAnnotation is a TraceMe: the public name outlives the
+        # moves of the extension module under it.
+        probe = jax.profiler.TraceAnnotation.is_enabled
         probe()  # must be callable without args
         return probe
     except Exception:  # noqa: BLE001 — unknown jax layout
