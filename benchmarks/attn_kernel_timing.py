@@ -1,14 +1,16 @@
 """Device time of the fused attention kernels alone, on the chip.
 
     python3 benchmarks/attn_kernel_timing.py [--steps 10] [--block-cap N]
-        [--heads-cap N] [B,L,H,Hkv,Dqk,Dv ...]
+        [--heads-cap N] [B,L,H,Hkv,Dqk,Dv[,window] ...]
 
 For each shape: seeded bf16 q, k, v and dO, one jitted program that
 runs forward and backward, `--steps` calls of it under the profiler,
 and from the trace the mean device time a call of each kernel and of
 everything else in the program (the `di` row sums, pads, cuts). The
 forms: `kernels` is `fused_attention._forward` and `_backward` on the
-shapes as given (where `supported` takes them); a shape whose q / k
+shapes as given (where `supported` takes them), the backward as
+`one_kernel_backward` decides; `kernels_two` the same with the backward
+as the two kernels dQ and dK/dV, whatever the rule says; a shape whose q / k
 and v widths differ also runs `path` (`flash_attention_path` as it
 is), `path_padded_qk` (q and k zero-padded to whole lanes, v at its
 own width) and `path_one_width` (the path before PR 32: q, k and v
@@ -18,7 +20,8 @@ Fails where JAX finds no TPU: a CPU time is no device time.
 
 The default shapes are the `xing4-29b-ep8.jit-dp1` cell's core as the
 model calls it (q / k 192, v 128), with q / k at 256, with all at 256
-(what the kernels ran before PR 32), and the Mistral cells' core.
+(what the kernels ran before PR 32), the Mistral cells' core, and the
+`trinity-large-ep32tp4.jit-dp1` cell's window and full layers.
 `--block-cap` and `--heads-cap` are for sweeps only: they override
 `BLOCK_CAP` and `HEADS_CAP` in this process.
 """
@@ -45,9 +48,10 @@ from perfbench.trace_reduce import (OPS_LINE, instruction,  # noqa: E402
                                     newest_xplane, read_events)
 
 DEFAULT = ["2,4096,32,32,192,128", "2,4096,32,32,256,128",
-           "2,4096,32,32,256,256", "2,2048,32,8,128,128"]
+           "2,4096,32,32,256,256", "2,2048,32,8,128,128",
+           "1,16384,12,2,128,128,4096", "1,16384,12,2,128,128"]
 KERNELS = ("hvd_fused_attention_fwd", "hvd_fused_attention_dq",
-           "hvd_fused_attention_dkv")
+           "hvd_fused_attention_dkv", "hvd_fused_attention_bwd")
 OUT = os.path.join("chiprun_out", "attn_kernel_timing.jsonl")
 
 
@@ -58,9 +62,15 @@ def _inputs(B, L, H, Hkv, Dqk, Dv):
             draw(ks[2], (B, L, Hkv, Dv)), draw(ks[3], (B, L, H, Dv)))
 
 
-def _kernels(q, k, v, do, scale):
-    o, lse = fa._forward(q, k, v, scale, False)
-    return o, fa._backward(q, k, v, o, lse, do, scale, False)
+def _kernels(q, k, v, do, scale, window=None, one=None):
+    o, lse = fa._forward(q, k, v, scale, False, window)
+    if one is None:
+        one = fa.one_kernel_backward(q.shape, k.shape, v.shape)
+    return o, fa._backward(q, k, v, o, lse, do, scale, False, window, one)
+
+
+def _kernels_two(q, k, v, do, scale, window=None):
+    return _kernels(q, k, v, do, scale, window, one=False)
 
 
 def _path(q, k, v, do, scale):
@@ -111,10 +121,11 @@ def _device_ms(trace_dir: str, steps: int):
     return by
 
 
-def measure(form: str, fn, shape, steps: int):
+def measure(form: str, fn, shape, steps: int, window=None):
     args = _inputs(*shape)
     scale = float(shape[4]) ** -0.5
-    step = jax.jit(functools.partial(fn, scale=scale))
+    kw = {} if window is None else {"window": window}
+    step = jax.jit(functools.partial(fn, scale=scale, **kw))
     jax.block_until_ready(step(*args))
     jax.block_until_ready(step(*args))
     with tempfile.TemporaryDirectory() as d:
@@ -124,12 +135,13 @@ def measure(form: str, fn, shape, steps: int):
             jax.block_until_ready(out)
         by = _device_ms(d, steps)
     dev = jax.devices()[0]
-    line = {"form": form, "shape": list(shape),
+    line = {"form": form, "shape": list(shape), "window": window,
             "block": fa.block_size(shape[1]),
             "step_heads": fa.step_heads(*shape[2:]),
             "steps": steps,
             "fwd_ms": by[KERNELS[0]], "dq_ms": by[KERNELS[1]],
-            "dkv_ms": by[KERNELS[2]], "other_ms": by["other"],
+            "dkv_ms": by[KERNELS[2]], "bwd_ms": by[KERNELS[3]],
+            "other_ms": by["other"],
             "sum_ms": sum(by.values()),
             "device": {"platform": dev.platform, "kind": dev.device_kind}}
     print(json.dumps(line), flush=True)
@@ -155,11 +167,14 @@ def main() -> int:
         fa.heads_per_step = functools.partial(fa.heads_per_step,
                                               cap=a.heads_cap)
     for text in a.shapes:
-        B, L, H, Hkv, Dqk, Dv = shape = tuple(
+        B, L, H, Hkv, Dqk, Dv, *window = tuple(
             int(x) for x in text.split(","))
+        shape, window = (B, L, H, Hkv, Dqk, Dv), (window or [None])[0]
         if fa.supported((B, L, H, Dqk), (B, L, Hkv, Dqk), (B, L, Hkv, Dv)):
-            measure("kernels", _kernels, shape, a.steps)
-        if Dqk != Dv:
+            for form, fn in (("kernels", _kernels),
+                             ("kernels_two", _kernels_two)):
+                measure(form, fn, shape, a.steps, window)
+        if Dqk != Dv and window is None:
             for form, fn in (("path", _path),
                              ("path_padded_qk", _path_padded_qk),
                              ("path_one_width", _path_one_width)):

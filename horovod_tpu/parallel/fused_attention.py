@@ -3,19 +3,26 @@ the f32 (B, H, L, L) scores and probabilities never reach HBM and the
 key blocks above the diagonal are neither loaded nor computed.
 
 Same mathematics as `ring_attention.dense_attention`: operands in the
-dtype given (bf16 in training), f32 accumulation in both matmuls, f32
-running max and sum, exact softmax. Three kernels under one
-`custom_vjp`: forward (also what `jax.checkpoint` runs again), dK/dV,
-dQ; the backward kernels rebuild the probabilities from the saved
-log-sum-exp, the one residual besides q, k, v and the output.
+dtype given (bf16 in training), f32 accumulation in every matmul, f32
+running max and sum, exact softmax. Two kernels under one `custom_vjp`:
+the forward (also what `jax.checkpoint` runs again) and the backward,
+which rebuilds a block's scores and probabilities once from the saved
+log-sum-exp (the one residual besides q, k, v and the output) and
+gives dQ, dK and dV from them: 5 products a block and q head. It walks
+as the forward does and holds a kv group's dK / dV for the whole
+sequence in VMEM (`_bwd_kernel`). Where those would pass
+`RESIDENT_KV_CAP` (`one_kernel_backward`: the sequence, the kv heads a
+step and the two widths decide), the backward is two kernels, dQ and
+dK/dV, that each rebuild the scores (7 products).
+`hvd_attention_backward_traces_total{kernels}` counts which a trace got.
 
 Layout. q, k, v arrive as the projections leave them, (B, L, H, D),
 and are read as (B, L, H*D) with a head a D-wide column block: no
 transpose to (B, H, L, D) and back, and K / V keep their own heads
 (grouped-query attention: q head h reads kv head h // (H // Hkv)).
 One grid step takes the q heads that share a kv head (`heads_per_step`),
-so their K / V block is loaded once, and the dK/dV kernel sums the
-group in VMEM; where every q head has a kv head of its own, a step
+so their K / V block is loaded once, and their dK / dV are summed
+in VMEM; where every q head has a kv head of its own, a step
 takes several heads of both side by side (`step_heads`).
 
 Two head widths, both read from the shapes of the call: `dqk` of q and
@@ -29,14 +36,15 @@ and k where neither holds). With equal widths and grouped kv the
 kernels are what they were with one width.
 
 A sliding window (`window`: the keys a query sees, its own position
-counted, so i - j < window) is the same three kernels with a narrower
+counted, so i - j < window) is the same kernels with a narrower
 walk: a query block's grid steps start at the oldest key block its
-window reaches (`_key_block`), a key block's at its own query block
-and end where the window does, and the grid has as many steps as a
-window can touch (`walk_steps`), so key blocks wholly older than the
-window are neither loaded nor computed, as blocks above the diagonal
-are; the one block the window's edge cuts is masked. `window=None`
-traces the causal program, instruction for instruction.
+window reaches (`_key_block`), a key block's (the dK/dV kernel's) at
+its own query block and end where the window does, and the grid has
+as many steps as a window can touch (`walk_steps`), so key blocks
+wholly older than the window are neither loaded nor computed, as
+blocks above the diagonal are; the one block the window's edge cuts
+is masked. `window=None` traces the causal program, instruction for
+instruction.
 
 Blocks come from the shapes the call sees (`block_size`): the largest
 multiple of 128 up to `BLOCK_CAP` that divides L, so seq 2048 runs
@@ -59,6 +67,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..metrics import REGISTRY as _METRICS
+
 LANES = 128
 # Query and key block. Swept on the v5e at (2, 32, 2048, 128) and
 # (1, 32, 256, 128): PERF.md section 6, PR 30.
@@ -80,8 +90,27 @@ KV_COLS_CAP = 1536
 # Finite, so that a fully masked row of a diagonal block gives
 # exp(MASK - m) = 0 and never inf - inf.
 MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
+# The one backward kernel holds a grid step's kv heads' dK and dV for
+# the whole sequence in VMEM as f32: it engages where they take at most
+# this many bytes (`one_kernel_backward`). The decoder cells' take 2
+# (Mistral), 16 (`trinity`) and 21 MB (`xing4`); seq 65,536 on one kv
+# head of 128 / 128 would take 64 and keeps the two kernels.
+RESIDENT_KV_CAP = 32 << 20
+# The compiler's scoped VMEM budget for a kernel that declares none (the
+# v5e's). The one backward kernel declares a limit only where it needs
+# more (`_bwd_vmem`): a declared limit, of any size, moves what XLA keeps
+# in VMEM around the call, which cost the Mistral cell's FFN backward
+# 3 ms a step (PERF.md section 6, PR 40).
+SCOPED_VMEM_DEFAULT = 16 << 20
 _NT = (((1,), (1,)), ((), ()))          # a @ b.T
 _F32 = jnp.float32
+
+_m_backward = _METRICS.counter(
+    "hvd_attention_backward_traces_total",
+    "Backward passes of the fused attention kernels traced, by the "
+    "kernels that run them: one (dQ, dK and dV from one rebuild of the "
+    "scores) or two (dQ, then dK/dV: a kv group's dK / dV over the "
+    "sequence exceed RESIDENT_KV_CAP).", ("kernels",))
 
 
 def block_size(seq: int, cap: int = BLOCK_CAP) -> int:
@@ -133,6 +162,17 @@ def step_heads(H: int, Hkv: int, Dqk: int, Dv: int):
             if Hkv % n == 0 and n * Dqk % LANES == 0
             and (n == 1 or n * (Dqk + Dv) <= KV_COLS_CAP)]
     return (fits[-1], fits[-1], 1) if fits else None
+
+
+def one_kernel_backward(q_shape, k_shape, v_shape) -> bool:
+    """Whether one kernel takes the backward of a call `supported`
+    takes: where the f32 dK / dV it holds for a grid step's kv heads
+    over the whole sequence fit `RESIDENT_KV_CAP`; else dQ and dK/dV
+    are two kernels that each rebuild the scores."""
+    _, L, H, Dqk = q_shape
+    Hkv, Dv = k_shape[2], v_shape[3]
+    kvs = step_heads(H, Hkv, Dqk, Dv)[1]
+    return L * kvs * (Dqk + Dv) * 4 <= RESIDENT_KV_CAP
 
 
 def walk_steps(seq: int, blk: int, window=None) -> int:
@@ -366,14 +406,82 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dk_ref,
         dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
 
 
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref,
+                dk_ref, dv_ref, dq_sc, dk_sc, dv_sc, *, scale: float,
+                bq: int, bk: int, heads: int, kv_heads: int, dqk: int,
+                dv: int, window=None):
+    """dQ, dK and dV from one rebuild of a block's scores and
+    probabilities: 5 products a block and q head where `_dq_kernel`
+    and `_dkv_kernel` do 7. The walk is `_dq_kernel`'s; the scores are
+    transposed, (bk, bq), as `_dkv_kernel` has them, so dV and dK are
+    plain p^T @ dO and ds^T @ q into the kv group's accumulators over
+    the whole sequence, at the key block's rows, and lse / di
+    broadcast as the rows they are stored as. dQ is accumulated
+    transposed, K^T @ ds^T: the step transposes its K block once for
+    all its q heads, not a score block a head, and dQ^T is turned back
+    once a query block."""
+    c, i, j = pl.program_id(2), pl.program_id(3), pl.program_id(4)
+    q_lo, k_lo = i * bq, _key_block(i, j, bk, window) * bk
+    qcols, vcols = _head_cols(heads, dqk), _head_cols(heads, dv)
+    kcols, vkcols = _head_cols(kv_heads, dqk), _head_cols(kv_heads, dv)
+
+    @pl.when((c == 0) & (i == 0) & (j == 0))
+    def _():
+        dk_sc[...] = jnp.zeros_like(dk_sc)
+        dv_sc[...] = jnp.zeros_like(dv_sc)
+
+    @pl.when(j == 0)
+    def _():
+        dq_sc[...] = jnp.zeros_like(dq_sc)
+
+    def step(masked: bool):
+        rows = pl.ds(pl.multiple_of(k_lo, bk), bk)
+        kt = k_ref[...].T                       # (kv_heads * dqk, bk)
+        ks = [k_ref[ix] for ix in _kv_index(kv_heads, dqk)]
+        vs = [v_ref[ix] for ix in _kv_index(kv_heads, dv)]
+        keep = _visible(q_lo, k_lo, 1, (bk, bq), window) if masked \
+            else None
+        for g in range(heads):
+            h = g % kv_heads
+            k, v = ks[h], vs[h]
+            q, do = q_ref[:, qcols[g]], do_ref[:, vcols[g]]
+            st = lax.dot_general(k, q, _NT,
+                                 preferred_element_type=_F32) * scale
+            if masked:
+                st = jnp.where(keep, st, MASK_VALUE)
+            pt = jnp.exp(st - lse_ref[g])
+            dv_sc[rows, vkcols[h]] += jnp.dot(pt.astype(do.dtype), do,
+                                              preferred_element_type=_F32)
+            dpt = lax.dot_general(v, do, _NT,
+                                  preferred_element_type=_F32)
+            dst = (pt * (dpt - di_ref[g])).astype(q.dtype)
+            dk_sc[rows, kcols[h]] += jnp.dot(dst, q,
+                                             preferred_element_type=_F32)
+            dq_sc[qcols[g], :] += jnp.dot(kt[kcols[h]], dst,
+                                          preferred_element_type=_F32)
+
+    _on_visible(q_lo, bq, k_lo, bk, step, window)
+
+    @pl.when(j == pl.num_programs(4) - 1)
+    def _():
+        dq_ref[...] = (dq_sc[...].T * scale).astype(dq_ref.dtype)
+
+    @pl.when((c == pl.num_programs(2) - 1) & (i == pl.num_programs(3) - 1)
+             & (j == pl.num_programs(4) - 1))
+    def _():
+        dk_ref[...] = (dk_sc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
+
+
 def _vma(*xs):
     return frozenset().union(*(jax.typeof(x).vma for x in xs))
 
 
-def _params(n_parallel: int, n_grid: int):
+def _params(n_parallel: int, n_grid: int, vmem_limit_bytes=None):
     return pltpu.CompilerParams(dimension_semantics=(
         ("parallel",) * n_parallel
-        + ("arbitrary",) * (n_grid - n_parallel)))
+        + ("arbitrary",) * (n_grid - n_parallel)),
+        vmem_limit_bytes=vmem_limit_bytes)
 
 
 def _plan(q, k, v):
@@ -386,30 +494,40 @@ def _plan(q, k, v):
             block_size(L))
 
 
-def _q_major(dims, heads, blk: int, window):
+def _q_major(dims, heads, blk: int, window, by_kv: bool = False):
     """Grid and specs of the kernels that walk key blocks for a query
-    block (forward, dQ): (grid, q / dQ spec, o / dO spec, k spec,
-    v spec, lse / di row spec). One step takes `hs` q heads, a
-    (blk, hs * Dqk) column block of q and a (blk, hs * Dv) one of the
-    output, and the `kvs` kv heads they read. Keys past the diagonal
-    are not loaded: their steps name the block already resident; keys
-    older than a window are no step of the walk."""
-    B, L, H, _, Dqk, Dv = dims
+    block (forward, dQ, the one backward kernel): (grid, q / dQ spec,
+    o / dO spec, k spec, v spec, lse / di row spec). One step takes
+    `hs` q heads, a (blk, hs * Dqk) column block of q and a
+    (blk, hs * Dv) one of the output, and the `kvs` kv heads they read.
+    Keys past the diagonal are not loaded: their steps name the block
+    already resident; keys older than a window are no step of the walk.
+    The grid is (B, q head steps, query blocks, walk), or `by_kv`
+    (B, kv head steps, per_kv, query blocks, walk): the same steps in
+    the same order, with the q heads of one kv group innermost but
+    for the walk, so that what a kv group accumulates stays resident."""
+    B, L, H, Hkv, Dqk, Dv = dims
     hs, kvs, per_kv = heads
+
+    def index(f):
+        if not by_kv:
+            return f
+        return lambda b, h, c, i, j: f(b, h * per_kv + c, i, j)
 
     def q_cols(width):
         return pl.BlockSpec((None, blk, hs * width),
-                            lambda b, c, i, j: (b, i, c))
+                            index(lambda b, c, i, j: (b, i, c)))
 
     def kv_cols(width):
         return pl.BlockSpec(
             (None, blk, kvs * width),
-            lambda b, c, i, j: (
+            index(lambda b, c, i, j: (
                 b, jnp.minimum(_key_block(i, j, blk, window), i),
-                c // per_kv))
+                c // per_kv)))
     row_spec = pl.BlockSpec((None, hs, 1, blk),
-                            lambda b, c, i, j: (b, c, 0, i))
-    return ((B, H // hs, L // blk, walk_steps(L, blk, window)),
+                            index(lambda b, c, i, j: (b, c, 0, i)))
+    heads_axes = (Hkv // kvs, per_kv) if by_kv else (H // hs,)
+    return ((B, *heads_axes, L // blk, walk_steps(L, blk, window)),
             q_cols(Dqk), q_cols(Dv), kv_cols(Dqk), kv_cols(Dv), row_spec)
 
 
@@ -441,18 +559,76 @@ def _forward(q, k, v, scale: float, interpret: bool, window):
 
 
 def _backward(q, k, v, o, lse, do, scale: float, interpret: bool,
-              window):
+              window, one: bool):
+    """dQ, dK, dV: with `one` from `_bwd_kernel`, else from
+    `_dq_kernel` and `_dkv_kernel` (`one_kernel_backward` decides)."""
     dims, heads, blk = _plan(q, k, v)
     B, L, H, Hkv, Dqk, Dv = dims
-    hs, kvs, per_kv = heads
     vma = _vma(q, k, v, do)
     di = jnp.sum(o.astype(_F32) * do.astype(_F32), axis=-1)   # (B, L, H)
     di = jnp.swapaxes(di, 1, 2)[:, :, None, :]                # (B, H, 1, L)
-    q3, do3 = q.reshape(B, L, H * Dqk), do.reshape(B, L, H * Dv)
-    k3, v3 = k.reshape(B, L, Hkv * Dqk), v.reshape(B, L, Hkv * Dv)
-    kw = dict(scale=scale, bq=blk, bk=blk, heads=hs, kv_heads=kvs,
-              dqk=Dqk, dv=Dv, window=window)
+    args = (q.reshape(B, L, H * Dqk), k.reshape(B, L, Hkv * Dqk),
+            v.reshape(B, L, Hkv * Dv), do.reshape(B, L, H * Dv), lse, di)
+    kw = dict(scale=scale, bq=blk, bk=blk, heads=heads[0],
+              kv_heads=heads[1], dqk=Dqk, dv=Dv, window=window)
+    out_shape = [
+        jax.ShapeDtypeStruct((B, L, H * Dqk), q.dtype, vma=vma),
+        jax.ShapeDtypeStruct((B, L, Hkv * Dqk), k.dtype, vma=vma),
+        jax.ShapeDtypeStruct((B, L, Hkv * Dv), v.dtype, vma=vma)]
+    split = _one_kernel if one else _two_kernels
+    dq, dk, dv = split(dims, heads, blk, window, kw, out_shape, interpret,
+                       args)
+    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
 
+
+def _bwd_vmem(dims, heads, blk: int, itemsize: int):
+    """VMEM the one backward kernel declares: None where it fits
+    `SCOPED_VMEM_DEFAULT`, else what it holds: the f32 dK / dV and
+    their output blocks (two buffers each), the q / dQ / dO / k / v
+    blocks (two buffers each), dQ^T and six f32 score-sized blocks
+    (the step at the cells' shapes needs 5-12 MiB beside the resident
+    part; this counts 9.5-14)."""
+    _, L, _, _, Dqk, Dv = dims
+    hs, kvs, _ = heads
+    need = (L * kvs * (Dqk + Dv) * (4 + 2 * itemsize)
+            + 2 * blk * (hs * (2 * Dqk + Dv) + kvs * (Dqk + Dv)) * itemsize
+            + blk * hs * Dqk * 4 + 6 * blk * blk * 4)
+    return need if need > SCOPED_VMEM_DEFAULT else None
+
+
+def _one_kernel(dims, heads, blk, window, kw, out_shape, interpret, args):
+    """dQ, dK, dV as one call: q-major like dQ, a kv group's dK / dV
+    over the whole sequence resident (their output blocks change with
+    (b, kv group) alone, so Pallas writes them back once a group)."""
+    _, L, _, _, Dqk, Dv = dims
+    hs, kvs, _ = heads
+    grid, q_spec, o_spec, k_spec, v_spec, row_spec = _q_major(
+        dims, heads, blk, window, by_kv=True)
+
+    def whole(width):
+        return pl.BlockSpec((None, L, kvs * width),
+                            lambda b, h, c, i, j: (b, 0, h))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, **kw),
+        grid=grid,
+        in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
+        out_specs=[q_spec, whole(Dqk), whole(Dv)],
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((hs * Dqk, blk), _F32),
+                        pltpu.VMEM((L, kvs * Dqk), _F32),
+                        pltpu.VMEM((L, kvs * Dv), _F32)],
+        compiler_params=_params(2, 5, _bwd_vmem(
+            dims, heads, blk, out_shape[1].dtype.itemsize)),
+        interpret=interpret,
+        name="hvd_fused_attention_bwd",
+    )(*args)
+
+
+def _two_kernels(dims, heads, blk, window, kw, out_shape, interpret,
+                 args):
+    """dQ, then dK/dV: each rebuilds the scores of every visible block."""
+    B, L, H, Hkv, Dqk, Dv = dims
+    hs, kvs, per_kv = heads
     grid, q_spec, o_spec, k_spec, v_spec, row_spec = _q_major(
         dims, heads, blk, window)
     dq = pl.pallas_call(
@@ -460,12 +636,12 @@ def _backward(q, k, v, o, lse, do, scale: float, interpret: bool,
         grid=grid,
         in_specs=[q_spec, k_spec, v_spec, o_spec, row_spec, row_spec],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((B, L, H * Dqk), q.dtype, vma=vma),
+        out_shape=out_shape[0],
         scratch_shapes=[pltpu.VMEM((blk, hs * Dqk), _F32)],
         compiler_params=_params(3, 4),
         interpret=interpret,
         name="hvd_fused_attention_dq",
-    )(q3, k3, v3, do3, lse, di)
+    )(*args)
 
     # dK/dV walks query blocks for a key block of `kvs` kv heads, their
     # q heads in `per_kv` steps of `hs`. Query blocks before the
@@ -495,16 +671,14 @@ def _backward(q, k, v, o, lse, do, scale: float, interpret: bool,
         in_specs=[qg_cols(Dqk), kvg_cols(Dqk), kvg_cols(Dv), qg_cols(Dv),
                   rowg_spec, rowg_spec],
         out_specs=[kvg_cols(Dqk), kvg_cols(Dv)],
-        out_shape=[
-            jax.ShapeDtypeStruct((B, L, Hkv * Dqk), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((B, L, Hkv * Dv), v.dtype, vma=vma)],
+        out_shape=out_shape[1:],
         scratch_shapes=[pltpu.VMEM((blk, kvs * Dqk), _F32),
                         pltpu.VMEM((blk, kvs * Dv), _F32)],
         compiler_params=_params(3, 5),
         interpret=interpret,
         name="hvd_fused_attention_dkv",
-    )(q3, k3, v3, do3, lse, di)
-    return (dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape))
+    )(*args)
+    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
@@ -519,7 +693,9 @@ def _attention_fwd(q, k, v, scale, interpret, window):
 
 def _attention_bwd(scale, interpret, window, residuals, do):
     q, k, v, o, lse = residuals
-    return _backward(q, k, v, o, lse, do, scale, interpret, window)
+    one = one_kernel_backward(q.shape, k.shape, v.shape)
+    _m_backward.labels(kernels="one" if one else "two").inc()
+    return _backward(q, k, v, o, lse, do, scale, interpret, window, one)
 
 
 _attention.defvjp(_attention_fwd, _attention_bwd)

@@ -73,19 +73,25 @@ def test_adasum_pair_combine_compiles_to_custom_call(topo, n, dtype):
                                    (1, 256, 32, 8, 128),
                                    (2, 2048, 32, 32, 128),
                                    (1, 2048, 8, 2, 256),
-                                   (1, 640, 16, 2, 128)],
+                                   (1, 640, 16, 2, 128),
+                                   (1, 32768, 6, 1, 128),
+                                   (1, 65536, 6, 1, 128)],
                          ids=["mistral-window", "mistral-sample", "mha",
-                              "head256", "group8-seq640"])
+                              "head256", "group8-seq640",
+                              "longest-one-kernel", "over-budget-split"])
 def test_flash_attention_forward_and_backward_compile(topo, shape):
     """The fused kernels at the Mistral cells' shapes and the blocks
     the rule gives them (512 at seq 2048, 256 at the seq-256 sample):
-    one custom call forward, three with backward, and no
-    (B, H, L, L) tensor left in the program."""
+    one custom call forward, two with backward (the one backward
+    kernel), and no (B, H, L, L) tensor left in the program. At the
+    longest sequence whose resident dK / dV fit `RESIDENT_KV_CAP` the
+    one kernel still fits the v5e's VMEM; over it the backward is the
+    two kernels dQ and dK/dV, three calls, and they fit too."""
     from horovod_tpu.parallel import fused_attention
     from horovod_tpu.parallel.ring_attention import flash_attention_path
     B, L, H, Hkv, D = shape
     assert fused_attention.block_size(L) == {2048: 512, 256: 256,
-                                             640: 128}[L]
+                                             640: 128}.get(L, 512)
     one = SingleDeviceSharding(topo.devices[0])
     q = jax.ShapeDtypeStruct((B, L, H, D), jnp.bfloat16, sharding=one)
     k = jax.ShapeDtypeStruct((B, L, Hkv, D), jnp.bfloat16, sharding=one)
@@ -97,10 +103,19 @@ def test_flash_attention_forward_and_backward_compile(topo, shape):
         return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    for fn, calls in ((fwd, 1), (bwd, 3)):
+    one = fused_attention.one_kernel_backward(q.shape, k.shape, k.shape)
+    assert one is (L <= 32768)
+    for fn, calls in ((fwd, 1), (bwd, 2 if one else 3)):
         hlo = jax.jit(fn).lower(q, k, k).compile().as_text()
         assert hlo.count('custom_call_target="tpu_custom_call"') == calls
         assert f"[{B},{H},{L},{L}]" not in hlo
+    names = {re.search(r"hvd_fused_attention_[a-z]+", line).group()
+             for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line}
+    assert names == ({"hvd_fused_attention_fwd", "hvd_fused_attention_bwd"}
+                     if one else {"hvd_fused_attention_fwd",
+                                  "hvd_fused_attention_dq",
+                                  "hvd_fused_attention_dkv"})
 
 
 @pytest.mark.parametrize("L, window, steps", [
@@ -113,7 +128,7 @@ def test_windowed_attention_forward_and_backward_compile(topo, L, window,
     share (12 q heads in groups of 6 on 2 kv heads of 128, blocks of
     512): three heads a grid step, a walk of `steps` key blocks a
     query block where the causal walk has L / 512, one custom call
-    forward and three with backward."""
+    forward and two with backward."""
     from horovod_tpu.parallel import fused_attention
     from horovod_tpu.parallel.ring_attention import flash_attention_path
     assert fused_attention.step_heads(12, 2, 128, 128) == (3, 1, 2)
@@ -129,7 +144,7 @@ def test_windowed_attention_forward_and_backward_compile(topo, L, window,
         return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
-    for fn, calls in ((fwd, 1), (bwd, 3)):
+    for fn, calls in ((fwd, 1), (bwd, 2)):
         hlo = jax.jit(fn).lower(q, k, k).compile().as_text()
         assert hlo.count('custom_call_target="tpu_custom_call"') == calls
         assert f"[1,12,{L},{L}]" not in hlo
@@ -259,22 +274,23 @@ def test_latent_attention_core_compiles_with_v_at_its_own_width(topo, B, L):
             *a, True, 0.1447).astype(jnp.float32).sum(),
             argnums=(0, 1, 2))(q, k, v)
     hlo = jax.jit(bwd).lower(q, q, v).compile().as_text()
-    assert hlo.count('custom_call_target="tpu_custom_call"') == 3
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
     assert f"[{B},32,{L},{L}]" not in hlo
     dq, dk, dv = jax.eval_shape(bwd, q, q, v)
     assert (dq.shape, dk.shape, dv.shape) == (q.shape, q.shape, v.shape)
     assert f"[{B},{L},32,256]" not in hlo and f"[{B},{L},8192]" not in hlo
-    # What each kernel is handed and gives back, in operand order.
+    # What each kernel gives back and is handed, in operand order: the
+    # backward's dQ, dK, dV, then q, k, v, dO.
     wide, narrow = f"bf16[{B},{L},6144]", f"bf16[{B},{L},4096]"
     calls = {}
     for line in hlo.splitlines():
         if 'custom_call_target="tpu_custom_call"' in line:
-            name = next(n for n in ("_fwd", "_dq", "_dkv")
+            name = next(n for n in ("_fwd", "_bwd")
                         if f"hvd_fused_attention{n}" in line)
             calls[name] = re.findall(r"bf16\[[0-9,]+\]", line)
     assert calls["_fwd"] == [narrow, wide, wide, narrow]
-    assert calls["_dq"] == [wide, wide, wide, narrow, narrow]
-    assert calls["_dkv"] == [wide, narrow, wide, wide, narrow, narrow]
+    assert calls["_bwd"] == [wide, wide, narrow, wide, wide, narrow,
+                             narrow]
 
 
 @pytest.mark.parametrize("k, n, m, tile", [

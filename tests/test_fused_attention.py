@@ -72,15 +72,22 @@ def _loss(f, w):
 # borders, with grouped kv, and the latent call through
 # `flash_attention_path` (192 / 128: four and two heads a step
 # unpadded, and with grouped kv, where q and k are padded to 256).
+# Then the head layouts of the cells' backward across block borders:
+# the Mistral group of 4 (32 / 8 scaled down to 8 / 2), `trinity`'s 12
+# q heads on 2 kv heads (3 a step, two steps a kv head), and `xing4`'s
+# four heads of 192 / 128 side by side.
 SHAPES = [(1, 256, 4, 4, 128), (1, 256, 4, 2, 128),
           (1, 1024, 2, 1, 128), (2, 640, 2, 2, 128),
           (1, 256, 8, 1, 128),
           (1, 256, 4, 4, 256, 128), (2, 640, 2, 2, 256, 128),
           (1, 256, 4, 2, 256, 128), (1, 256, 4, 4, 192, 128),
-          (1, 256, 2, 2, 192, 128), (1, 256, 4, 2, 192, 128)]
+          (1, 256, 2, 2, 192, 128), (1, 256, 4, 2, 192, 128),
+          (1, 512, 8, 2, 128), (1, 640, 12, 2, 128),
+          (1, 512, 4, 4, 192, 128)]
 IDS = ["mha256", "gqa256", "gqa1024", "mha640", "mqa256",
        "mha256-v128", "mha640-v128", "gqa256-v128", "latent192-v128",
-       "latent192-two-heads", "latent192-gqa-padded"]
+       "latent192-two-heads", "latent192-gqa-padded",
+       "gqa512-group4", "gqa640-12on2", "latent512-four-heads"]
 
 
 def _path(shape):
@@ -121,6 +128,95 @@ def test_fused_bf16_within_rounding_of_dense(shape):
         assert g.shape == o.shape and g.dtype == jnp.bfloat16
         assert float(jnp.max(jnp.abs(g.astype(jnp.float32)
                                      - o.astype(jnp.float32)))) < 0.25
+
+
+# (B, L, H, Hkv, Dqk, Dv), window: the backward's head layouts as the
+# cells run them, scaled down (the Mistral group of 4; 12 q heads on 2
+# kv heads, two steps a kv head; four heads of 192 / 128 side by side;
+# grouped kv with v narrower), a single block, and windows whose edge
+# cuts a block (one of them with two steps a kv head).
+BACKWARDS = [((1, 512, 8, 2, 128, 128), None),
+             ((1, 768, 12, 2, 128, 128), None),
+             ((2, 512, 4, 4, 192, 128), None),
+             ((1, 512, 4, 2, 256, 128), None),
+             ((1, 256, 4, 1, 128, 128), None),
+             ((1, 896, 2, 2, 128, 128), 300),
+             ((1, 640, 12, 2, 128, 128), 200)]
+
+
+@pytest.mark.parametrize("shape,window", BACKWARDS, ids=[
+    f"L{s[1]}-h{s[2]}kv{s[3]}-{s[4]}x{s[5]}-w{w}" for s, w in BACKWARDS])
+def test_one_backward_kernel_matches_two_and_dense(shape, window):
+    """The one kernel's dQ, dK, dV against the two kernels' from the
+    same residuals (the same f32 sums in another order) and against
+    `dense_attention`'s gradients."""
+    q, k, v, do = _qkv(*shape)
+    scale = shape[4] ** -0.5
+    o, lse = fa._forward(q, k, v, scale, True, window)
+    one = fa._backward(q, k, v, o, lse, do, scale, True, window, True)
+    two = fa._backward(q, k, v, o, lse, do, scale, True, window, False)
+    _, vjp = jax.vjp(_dense_windowed(window), q, k, v)
+    for a, b, want, name in zip(one, two, vjp(do), "qkv"):
+        assert a.shape == b.shape == want.shape, name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5, err_msg=name)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(want),
+                                   rtol=1e-4, atol=1e-4, err_msg=name)
+
+
+# (B, L, H, Hkv, Dqk, Dv) -> one backward kernel, and the VMEM it
+# declares (MiB; None: the compiler's default budget): the cells' cores
+# (Mistral 2 MB of resident f32 dK / dV, `trinity` 16, `xing4` 20 MiB)
+# and samples, the longest sequence one kv head of 128 / 128 fits
+# (32 MiB), and over the budget: seq 65,536 (64 MiB), four kv heads a
+# step at 32,768.
+ONE_KERNEL = [((2, 2048, 32, 8, 128, 128), True, None),
+              ((1, 256, 32, 8, 128, 128), True, None),
+              ((1, 16384, 12, 2, 128, 128), True, 41.5),
+              ((1, 8192, 12, 2, 128, 128), True, 25.5),
+              ((2, 4096, 32, 32, 192, 128), True, 54.0),
+              ((1, 256, 32, 32, 192, 128), True, None),
+              ((1, 32768, 6, 1, 128, 128), True, 73.5),
+              ((1, 65536, 6, 1, 128, 128), False, None),
+              ((1, 32768, 8, 8, 128, 128), False, None)]
+
+
+@pytest.mark.parametrize("shape,one,vmem_mib", ONE_KERNEL)
+def test_one_backward_kernel_rule(shape, one, vmem_mib):
+    B, L, H, Hkv, Dqk, Dv = shape
+    q, k, v = (B, L, H, Dqk), (B, L, Hkv, Dqk), (B, L, Hkv, Dv)
+    assert fa.supported(q, k, v)
+    assert fa.one_kernel_backward(q, k, v) is one
+    if one:
+        vmem = fa._bwd_vmem(shape, fa.step_heads(H, Hkv, Dqk, Dv),
+                            fa.block_size(L), 2)
+        assert vmem == (None if vmem_mib is None else vmem_mib * 2**20)
+
+
+def _backward_traces():
+    snap = REGISTRY.snapshot().get("hvd_attention_backward_traces_total",
+                                   {})
+    return {n: snap.get((n,), 0.0) for n in ("one", "two")}
+
+
+@pytest.mark.parametrize("seq,kernels", [(256, "one"), (16384, "one"),
+                                         (65536, "two")])
+def test_backward_counter_counts_each_trace(seq, kernels):
+    """Each trace of the backward counts the kernels it got; a forward
+    alone counts nothing."""
+    q = jax.ShapeDtypeStruct((1, seq, 6, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, seq, 1, 128), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return fa.fused_causal_attention(q, k, v, 1.0).astype(
+            jnp.float32).sum()
+    before = _backward_traces()
+    jax.eval_shape(fwd, q, k, k)
+    assert _backward_traces() == before
+    jax.eval_shape(jax.grad(fwd, argnums=(0, 1, 2)), q, k, k)
+    after = _backward_traces()
+    assert {n: after[n] - before[n] for n in after} == {
+        **dict.fromkeys(after, 0.0), kernels: 1.0}
 
 
 def test_fused_under_shard_map_with_the_replication_checker_on():
@@ -487,8 +583,7 @@ def test_a_window_skips_key_blocks(seq, window, visited, causal):
             q, k, v, 1.0, window=window).astype(jnp.float32).sum(),
         argnums=(0, 1, 2)))(q, k, k).jaxpr)
     n = seq // blk
-    assert sorted(grids) == sorted([(1, 2, n, steps), (1, 2, n, steps),
-                                    (1, 1, n, 2, steps)])
+    assert sorted(grids) == sorted([(1, 2, n, steps), (1, 1, 2, n, steps)])
 
 
 def _key_blocks():
