@@ -166,6 +166,14 @@ DEVICE_SCOPES: Dict[str, str] = {
                        "token-level mask, or one masked softmax; the "
                        "layer at or under its dense length runs "
                        "hvd.attn.core",
+    "hvd.ssm.proj": "a Mamba mixer around its scan (models/jamba.py): "
+                    "the norm, in_proj, the causal conv, x_proj, the "
+                    "dt / B / C norms, dt_proj and softplus, A, "
+                    "out_proj and the residual add",
+    "hvd.ssm.scan": "the selective scan (parallel/selective_scan.py): "
+                    "the recurrence over chunks with the state in VMEM "
+                    "(or jax.numpy chunks), the D skip and the silu(z) "
+                    "gate, all passes",
     "hvd.ffn": "dense FFN: norm and SwiGLU, and the sub-layer's "
                "post-norm where a model has one",
     "hvd.moe": "MoE FFN: router, dispatch, experts, combine",
@@ -200,7 +208,7 @@ DEVICE_SCOPES: Dict[str, str] = {
 # JAX's own key leaves names out, so a cache filled before a scope was
 # added, renamed or moved hands back executables with the old names.
 # Raise it with every such change.
-DEVICE_SCOPES_VERSION = 4
+DEVICE_SCOPES_VERSION = 5
 _BUCKET_SCOPE = "hvd.grad_reduce.b"
 _BUCKET_SCOPE_NAME = re.compile(re.escape(_BUCKET_SCOPE) + "[0-9]+")
 

@@ -75,10 +75,12 @@ def test_adasum_pair_combine_compiles_to_custom_call(topo, n, dtype):
                                    (1, 2048, 8, 2, 256),
                                    (1, 640, 16, 2, 128),
                                    (1, 32768, 6, 1, 128),
-                                   (1, 65536, 6, 1, 128)],
+                                   (1, 65536, 6, 1, 128),
+                                   (1, 16384, 10, 1, 128)],
                          ids=["mistral-window", "mistral-sample", "mha",
                               "head256", "group8-seq640",
-                              "longest-one-kernel", "over-budget-split"])
+                              "longest-one-kernel", "over-budget-split",
+                              "jamba-group10"])
 def test_flash_attention_forward_and_backward_compile(topo, shape):
     """The fused kernels at the Mistral cells' shapes and the blocks
     the rule gives them (512 at seq 2048, 256 at the seq-256 sample):
@@ -254,6 +256,56 @@ def test_linear_attention_compiles_at_the_cell_s_shapes(topo, kernels):
         assert not states
     else:
         assert "tpu_custom_call" not in hlo and states
+        assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+@pytest.mark.parametrize("kernels", [True, False],
+                         ids=["kernels", "chunks"])
+def test_selective_scan_compiles_at_the_cell_s_shapes(topo, kernels):
+    """The Mamba mixers' scan forward and backward at 2,560 channels of
+    16 states and 16,384 positions, chunks of 32. As a TPU traces it in
+    bf16: two kernels (forward, backward) with the state in VMEM and no
+    (L x C x 16) array in HBM. The `jax.numpy` path (any other backend
+    or dtype): a chunk's (32 x C x 16) arrays at a time, under 1.5 GB
+    of temporaries."""
+    import horovod_tpu as hvd
+    from horovod_tpu.parallel import selective_scan as ss
+    from horovod_tpu.tracing import device_scope
+    one = SingleDeviceSharding(topo.devices[0])
+    L, C, N = 16384, 2560, 16
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+    x = shape(1, L, C, dtype=jnp.bfloat16)
+    args = (x, shape(1, L, C), shape(C, N), shape(1, L, N), shape(1, L, N),
+            shape(C), x)
+
+    def both(*a):
+        def scan(*a):
+            with device_scope("hvd.ssm.scan"):
+                return ss.selective_scan(*a)
+        return jax.value_and_grad(
+            lambda *a: scan(*a).astype(jnp.float32).sum(),
+            argnums=tuple(range(7)))(*a)
+    label = ("kernel" if kernels else "chunks",)
+    before = hvd.metrics().get("hvd_selective_scan_traces_total",
+                               {}).get(label, 0)
+    with mock.patch.object(jax, "default_backend",
+                           lambda: "tpu" if kernels else "cpu"):
+        lowered = jax.jit(both).lower(*args)
+    assert hvd.metrics()["hvd_selective_scan_traces_total"][label] \
+        == before + 1
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    # a state a position and channel, whatever order XLA lays it in
+    assert not re.search(rf"\[(1,)?({L},{C},{N}|{L},{N},{C}|{C},{L},{N})\]",
+                         hlo)
+    if kernels:
+        for name in ("fwd", "bwd"):
+            assert len(re.findall(
+                rf"%hvd_selective_scan_{name}[.\d]* = ", hlo)) == 1, name
+    else:
+        assert "tpu_custom_call" not in hlo
         assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 

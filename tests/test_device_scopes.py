@@ -154,6 +154,39 @@ def test_latent_moe_scopes_reach_the_compiled_program(latent_moe_names,
     assert not variants(latent_moe_names, "hvd.moe")
 
 
+@pytest.fixture(scope="module")
+def jamba_names():
+    """Mamba mixers and an attention layer in one period, every layer
+    checkpointed, through `build_train_step`."""
+    from horovod_tpu.models import jamba
+    cfg = jamba.JambaConfig(vocab=64, d_model=32, channels=64, dt_rank=4,
+                            d_ff=32, n_heads=2, head_dim=16,
+                            dtype=jnp.float32, remat=True)
+    mesh = Mesh(np.array(jax.devices()[:1]), axis_names=("data",))
+    params = jamba.init_params(cfg, jax.random.PRNGKey(0))
+    tx = optax.sgd(0.1)
+    step = build_train_step(
+        lambda p, b: jamba.loss_fn(cfg, p, b), tx, mesh,
+        batch_spec={"tokens": P("data")}, donate=False)
+    batch = {"tokens": jnp.zeros((2, 16), jnp.int32)}
+    return op_names(step.lower(params, tx.init(params), batch).compile())
+
+
+@pytest.mark.parametrize("scope, want", [
+    ("hvd.ssm.proj", {"forward", "backward", "recompute"}),
+    ("hvd.ssm.scan", {"forward", "backward", "recompute"}),
+    ("hvd.attn.proj", {"forward", "backward", "recompute"}),
+    ("hvd.attn.core", {"forward", "backward", "recompute"}),
+    ("hvd.ffn", {"forward", "backward", "recompute"}),
+    ("hvd.embed", {"forward", "backward"}),
+    ("hvd.head_loss", {"forward", "backward"}),
+])
+def test_jamba_scopes_reach_the_compiled_program(jamba_names, scope, want):
+    assert want <= variants(jamba_names, scope)
+    found = {s for n in jamba_names for s in SCOPE.findall(n)}
+    assert found <= set(tracing.DEVICE_SCOPES)
+
+
 def test_every_name_in_a_program_is_registered(transformer_names,
                                                resnet_names,
                                                latent_moe_names):
@@ -235,13 +268,14 @@ def test_registry_names_and_version():
     assert all(name.startswith("hvd.") and SCOPE.fullmatch(name)
                for name in tracing.DEVICE_SCOPES)
     assert (tracing.DEVICE_SCOPES_VERSION,
-            sorted(tracing.DEVICE_SCOPES)) == (4, [
+            sorted(tracing.DEVICE_SCOPES)) == (5, [
         "hvd.attn.core", "hvd.attn.linear", "hvd.attn.proj",
         "hvd.attn.select", "hvd.attn.sparse", "hvd.attn.window",
         "hvd.batchnorm", "hvd.conv",
         "hvd.embed", "hvd.ffn", "hvd.grad_reduce", "hvd.hc",
         "hvd.head_loss", "hvd.moe", "hvd.moe.experts", "hvd.moe.route",
-        "hvd.moe.shared", "hvd.mtp", "hvd.optimizer"])
+        "hvd.moe.shared", "hvd.mtp", "hvd.optimizer", "hvd.ssm.proj",
+        "hvd.ssm.scan"])
     with tracing.bucket_scope(3):
         pass
 
