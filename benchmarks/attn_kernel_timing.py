@@ -1,7 +1,9 @@
-"""Device time of the fused attention kernels alone, on the chip.
+"""Device time of the fused and block-sparse attention kernels alone,
+on the chip.
 
     python3 benchmarks/attn_kernel_timing.py [--steps 10] [--block-cap N]
-        [--heads-cap N] [B,L,H,Hkv,Dqk,Dv[,window] ...]
+        [--heads-cap N] [B,L,H,Hkv,Dqk,Dv[,window] | sparse:B,L,H,Hkv,D,Dv
+        ...]
 
 For each shape: seeded bf16 q, k, v and dO, one jitted program that
 runs forward and backward, `--steps` calls of it under the profiler,
@@ -14,14 +16,20 @@ as the two kernels dQ and dK/dV, whatever the rule says; a shape whose q / k
 and v widths differ also runs `path` (`flash_attention_path` as it
 is), `path_padded_qk` (q and k zero-padded to whole lanes, v at its
 own width) and `path_one_width` (the path before PR 32: q, k and v
-zero-padded to one width, the output cut back). One JSON line a
+zero-padded to one width, the output cut back). A `sparse:` shape runs
+`sparse_attention._forward` and `_backward` under the default
+`SparseSpec` on a selection that keeps every causal block, so that the
+walk visits every causal kernel block as the seeded weights' selection
+does: `sparse` with the backward as `one_kernel_backward` decides,
+`sparse_two` as dQ and dK/dV whatever it says. One JSON line a
 measurement, also appended to `chiprun_out/attn_kernel_timing.jsonl`.
 Fails where JAX finds no TPU: a CPU time is no device time.
 
 The default shapes are the `xing4-29b-ep8.jit-dp1` cell's core as the
 model calls it (q / k 192, v 128), with q / k at 256, with all at 256
 (what the kernels ran before PR 32), the Mistral cells' core, and the
-`trinity-large-ep32tp4.jit-dp1` cell's window and full layers.
+`trinity-large-ep32tp4.jit-dp1` cell's window and full layers, and
+the `minicpm-sala-tp2vp8.jit-dp1` cell's block-sparse core.
 `--block-cap` and `--heads-cap` are for sweeps only: they override
 `BLOCK_CAP` and `HEADS_CAP` in this process.
 """
@@ -42,6 +50,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from horovod_tpu.parallel import fused_attention as fa  # noqa: E402
+from horovod_tpu.parallel import sparse_attention as sa  # noqa: E402
 # `horovod_tpu.parallel.ring_attention` the attribute is the function.
 ra = importlib.import_module("horovod_tpu.parallel.ring_attention")
 from perfbench.trace_reduce import (OPS_LINE, instruction,  # noqa: E402
@@ -49,9 +58,11 @@ from perfbench.trace_reduce import (OPS_LINE, instruction,  # noqa: E402
 
 DEFAULT = ["2,4096,32,32,192,128", "2,4096,32,32,256,128",
            "2,4096,32,32,256,256", "2,2048,32,8,128,128",
-           "1,16384,12,2,128,128,4096", "1,16384,12,2,128,128"]
+           "1,16384,12,2,128,128,4096", "1,16384,12,2,128,128",
+           "sparse:1,32768,16,1,128,128"]
 KERNELS = ("hvd_fused_attention_fwd", "hvd_fused_attention_dq",
            "hvd_fused_attention_dkv", "hvd_fused_attention_bwd")
+SPARSE_KERNELS = tuple(k.replace("fused", "sparse") for k in KERNELS)
 OUT = os.path.join("chiprun_out", "attn_kernel_timing.jsonl")
 
 
@@ -71,6 +82,24 @@ def _kernels(q, k, v, do, scale, window=None, one=None):
 
 def _kernels_two(q, k, v, do, scale, window=None):
     return _kernels(q, k, v, do, scale, window, one=False)
+
+
+def _causal_tables(B, L, Hkv, one: bool):
+    """The kernels' tables of a selection that keeps, for every query,
+    every block at or before its own."""
+    spec = sa.SparseSpec()
+    n = L // spec.block
+    own = jnp.arange(L)[:, None] // spec.block
+    chosen = jnp.broadcast_to(jnp.arange(n)[None, :] <= own, (B, Hkv, L, n))
+    return sa.block_tables(chosen, sa.kernel_block(L, spec), spec,
+                           transposed=not one)
+
+
+def _sparse(q, k, v, do, words, walk, walk_t, scale):
+    spec = sa.SparseSpec()
+    o, lse = sa._forward(q, k, v, words, walk, spec, scale, False)
+    return o, sa._backward(q, k, v, words, walk, walk_t, o, lse, do, spec,
+                           scale, False)
 
 
 def _path(q, k, v, do, scale):
@@ -107,22 +136,23 @@ def _path_one_width(q, k, v, do, scale):
     return out, vjp(do)
 
 
-def _device_ms(trace_dir: str, steps: int):
+def _device_ms(trace_dir: str, steps: int, kernels=KERNELS):
     """Mean device ms a call by kernel, and of every other
     instruction, on the first chip."""
     planes = read_events(newest_xplane(trace_dir))
     ops = planes[sorted(p for p in planes if p.startswith("/device:TPU:"))[0]]
-    by = dict.fromkeys(KERNELS, 0.0)
+    by = dict.fromkeys(kernels, 0.0)
     by["other"] = 0.0
     for text, start, end in ops[OPS_LINE]:
         name = instruction(text)[0]
-        key = next((k for k in KERNELS if k in name), "other")
+        key = next((k for k in kernels if k in name), "other")
         by[key] += (end - start) / 1e9 / steps
     return by
 
 
-def measure(form: str, fn, shape, steps: int, window=None):
-    args = _inputs(*shape)
+def measure(form: str, fn, shape, steps: int, window=None, tables=(),
+            kernels=KERNELS):
+    args = (*_inputs(*shape), *tables)
     scale = float(shape[4]) ** -0.5
     kw = {} if window is None else {"window": window}
     step = jax.jit(functools.partial(fn, scale=scale, **kw))
@@ -133,14 +163,14 @@ def measure(form: str, fn, shape, steps: int, window=None):
             for _ in range(steps):
                 out = step(*args)
             jax.block_until_ready(out)
-        by = _device_ms(d, steps)
+        by = _device_ms(d, steps, kernels)
     dev = jax.devices()[0]
     line = {"form": form, "shape": list(shape), "window": window,
             "block": fa.block_size(shape[1]),
             "step_heads": fa.step_heads(*shape[2:]),
             "steps": steps,
-            "fwd_ms": by[KERNELS[0]], "dq_ms": by[KERNELS[1]],
-            "dkv_ms": by[KERNELS[2]], "bwd_ms": by[KERNELS[3]],
+            "fwd_ms": by[kernels[0]], "dq_ms": by[kernels[1]],
+            "dkv_ms": by[kernels[2]], "bwd_ms": by[kernels[3]],
             "other_ms": by["other"],
             "sum_ms": sum(by.values()),
             "device": {"platform": dev.platform, "kind": dev.device_kind}}
@@ -167,9 +197,21 @@ def main() -> int:
         fa.heads_per_step = functools.partial(fa.heads_per_step,
                                               cap=a.heads_cap)
     for text in a.shapes:
+        sparse = text.startswith("sparse:")
         B, L, H, Hkv, Dqk, Dv, *window = tuple(
-            int(x) for x in text.split(","))
+            int(x) for x in text.removeprefix("sparse:").split(","))
         shape, window = (B, L, H, Hkv, Dqk, Dv), (window or [None])[0]
+        if sparse:
+            q, k, v = (B, L, H, Dqk), (B, L, Hkv, Dqk), (B, L, Hkv, Dv)
+            if not sa.supported(q, k, v, sa.SparseSpec()):
+                raise SystemExit(f"attn_kernel_timing: the sparse kernels "
+                                 f"do not take {text}")
+            for form, one in (("sparse", fa.one_kernel_backward(q, k, v)),
+                              ("sparse_two", False)):
+                measure(form, _sparse, shape, a.steps,
+                        tables=_causal_tables(B, L, Hkv, one),
+                        kernels=SPARSE_KERNELS)
+            continue
         if fa.supported((B, L, H, Dqk), (B, L, Hkv, Dqk), (B, L, Hkv, Dv)):
             for form, fn in (("kernels", _kernels),
                              ("kernels_two", _kernels_two)):

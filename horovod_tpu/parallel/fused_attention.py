@@ -107,10 +107,10 @@ _F32 = jnp.float32
 
 _m_backward = _METRICS.counter(
     "hvd_attention_backward_traces_total",
-    "Backward passes of the fused attention kernels traced, by the "
-    "kernels that run them: one (dQ, dK and dV from one rebuild of the "
-    "scores) or two (dQ, then dK/dV: a kv group's dK / dV over the "
-    "sequence exceed RESIDENT_KV_CAP).", ("kernels",))
+    "Backward passes of the attention kernels traced, by the kernels "
+    "that run them: one (dQ, dK and dV from one rebuild of the scores) "
+    "or two (dQ, then dK/dV: a kv group's dK / dV exceed RESIDENT_KV_CAP)"
+    "; sparse_one / sparse_two: the block-sparse ones.", ("kernels",))
 
 
 def block_size(seq: int, cap: int = BLOCK_CAP) -> int:

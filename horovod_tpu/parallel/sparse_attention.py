@@ -24,17 +24,24 @@ those calls to `ring_attention.attention` as they are.
 
 The attention over the selection has two paths, picked by what the
 call observes (`kernels_engage`: TPU, bf16, the fused kernels' shapes,
-one kv head a grid step). Three Pallas kernels under one `custom_vjp`
-(`hvd_sparse_attention_fwd`, `_dq`, `_dkv`) that are
-`fused_attention.py`'s with two changes: the walk over key blocks (of
-query blocks, for dK/dV) follows a scalar-prefetched table of the
-blocks that some query of the query block selected, compacted to the
-front with its count, so a kernel block no query of a query block
-chose is neither loaded nor computed; and inside a visited block a
-token-level mask, unpacked from one int32 word a (query, kernel
-block) whose bit s says whether the query selected the kernel block's
-s-th selection block, keeps the result exact where the queries of one
-block chose differently. No (L x L) array reaches HBM. Which blocks a
+one kv head a grid step). Pallas kernels under one `custom_vjp` that
+are `fused_attention.py`'s with two changes: the walk over key blocks
+follows a scalar-prefetched table of the blocks that some query of the
+query block selected, compacted to the front with its count, so a
+kernel block no query of a query block chose is neither loaded nor
+computed; and inside a visited block a token-level mask, unpacked from
+one int32 word a (query, kernel block) whose bit s says whether the
+query selected the kernel block's s-th selection block, keeps the
+result exact where the queries of one block chose differently. The
+forward is `hvd_sparse_attention_fwd`; the backward is one kernel,
+`hvd_sparse_attention_bwd`, that walks the forward's table and gives
+dQ, dK and dV from one rebuild of a visited block's scores, with a kv
+group's f32 dK / dV over the sequence resident in VMEM. Where those
+pass `RESIDENT_KV_CAP` (`fused_attention.one_kernel_backward`, a rule
+on shapes) it is two, `_dq` over the same walk and `_dkv` over the
+query blocks that visited a key block, each rebuilding the scores;
+`hvd_attention_backward_traces_total{kernels="sparse_one"|"sparse_two"}`
+counts which a trace got. No (L x L) array reaches HBM. Which blocks a
 step visits is a device value (`block_tables`); from shapes only the
 selected count is known. Everywhere else `_masked_attention`, the
 same function as one masked softmax in `jax.numpy` (the tests' oracle;
@@ -55,8 +62,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..tracing import device_scope
-from .fused_attention import (BLOCK_CAP, LANES, MASK_VALUE, _column,
-                              _head_cols, _params, _tile, _vma, block_size,
+from .fused_attention import (BLOCK_CAP, LANES, MASK_VALUE, _bwd_vmem,
+                              _column, _head_cols, _m_backward, _params,
+                              _tile, _vma, block_size, one_kernel_backward,
                               step_heads)
 from .fused_attention import supported as _fused_supported
 from .ring_attention import _m_key_blocks, _m_traces, attention
@@ -215,16 +223,17 @@ def kernel_block(seq: int, spec: SparseSpec) -> int:
     return blk if blk and blk % spec.block == 0 else 0
 
 
-def block_tables(chosen: jax.Array, blk: int, spec: SparseSpec):
+def block_tables(chosen: jax.Array, blk: int, spec: SparseSpec,
+                 transposed: bool = True):
     """What the kernels read of a selection (B, Hkv, L, L // block):
 
       words  (B, Hkv, L // blk, 1, L) int32: bit s of words[.., j, 0, t]
              says whether query t keeps selection block j * per + s
       walks  for the kernels that walk key blocks for a query block
-             and for the one that walks query blocks for a key block,
-             each (table (B * Hkv * n * n,) int32, the visited blocks of
-             every row in ascending order at the front; count
-             (B * Hkv * n,) int32).
+             and (`transposed`, else None) for the one that walks query
+             blocks for a key block, each (table (B * Hkv * n * n,)
+             int32, the visited blocks of every row in ascending order
+             at the front; count (B * Hkv * n,) int32).
     """
     B, Hkv, L, _ = chosen.shape
     n, per = L // blk, blk // spec.block
@@ -238,7 +247,8 @@ def block_tables(chosen: jax.Array, blk: int, spec: SparseSpec):
         return (order.astype(_I32).reshape(-1),
                 jnp.sum(rows, axis=-1, dtype=_I32).reshape(-1))
     words = jnp.moveaxis(bits, 2, 3)[:, :, :, None, :]
-    return words, walk(visited), walk(jnp.swapaxes(visited, 2, 3))
+    return words, walk(visited), (walk(jnp.swapaxes(visited, 2, 3))
+                                  if transposed else None)
 
 
 def _keep(word, axis: int, shape, block: int, q_lo=None, k_lo=None):
@@ -398,6 +408,70 @@ def _dkv_kernel(table_ref, count_ref, q_ref, k_ref, v_ref, w_ref, do_ref,
         dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
 
 
+def _bwd_kernel(table_ref, count_ref, q_ref, k_ref, v_ref, w_ref, do_ref,
+                lse_ref, di_ref, dq_ref, dk_ref, dv_ref, dq_sc, dk_sc, dv_sc,
+                *, scale: float, blk: int, heads: int, d: int, dv: int,
+                block: int):
+    """dQ, dK and dV from one rebuild of a visited block's scores and
+    probabilities: 5 products a block and q head where `_dq_kernel` and
+    `_dkv_kernel` do 7, and one unpacking of its token mask. The walk is
+    the forward's table; the scores are transposed, (keys, queries), as
+    `_dkv_kernel` has them, so the word of a query is the row it is
+    stored as, and dV and dK go into the kv group's accumulators over
+    the whole sequence at the visited block's rows, in `_dkv_kernel`'s
+    order (head steps, query blocks ascending, heads). dQ is
+    accumulated transposed, K^T @ ds^T (`fused_attention._bwd_kernel`'s
+    form): one K transpose a step, dQ^T turned back once a query
+    block."""
+    b, h, c, i, j = (pl.program_id(a) for a in range(5))
+    n = pl.num_programs(4)
+    live, k_block = _walked(table_ref, count_ref,
+                            (b * pl.num_programs(1) + h) * n + i, n, j)
+    qcols, vcols = _head_cols(heads, d), _head_cols(heads, dv)
+
+    @pl.when((c == 0) & (i == 0) & (j == 0))
+    def _():
+        dk_sc[...] = jnp.zeros_like(dk_sc)
+        dv_sc[...] = jnp.zeros_like(dv_sc)
+
+    @pl.when(j == 0)
+    def _():
+        dq_sc[...] = jnp.zeros_like(dq_sc)
+
+    def step(diagonal: bool):
+        rows = pl.ds(pl.multiple_of(k_block * blk, blk), blk)
+        k, v = k_ref[...], v_ref[...]
+        kt = k.T
+        word = jnp.broadcast_to(w_ref[...], (blk, blk))
+        keep = _keep(word, 0, (blk, blk), block,
+                     *((i * blk, k_block * blk) if diagonal else ()))
+        for g in range(heads):
+            q, do = q_ref[:, qcols[g]], do_ref[:, vcols[g]]
+            st = lax.dot_general(k, q, _NT,
+                                 preferred_element_type=_F32) * scale
+            st = jnp.where(keep, st, MASK_VALUE)
+            pt = jnp.exp(st - lse_ref[g])
+            dv_sc[rows, :] += jnp.dot(pt.astype(do.dtype), do,
+                                      preferred_element_type=_F32)
+            dpt = lax.dot_general(v, do, _NT,
+                                  preferred_element_type=_F32)
+            dst = (pt * (dpt - di_ref[g])).astype(q.dtype)
+            dk_sc[rows, :] += jnp.dot(dst, q, preferred_element_type=_F32)
+            dq_sc[qcols[g], :] += jnp.dot(kt, dst,
+                                          preferred_element_type=_F32)
+
+    _on_walk(live, k_block == i, step)
+
+    @pl.when(j == n - 1)
+    def _():
+        dq_ref[...] = (dq_sc[...].T * scale).astype(dq_ref.dtype)
+
+    @pl.when((c == pl.num_programs(2) - 1) & (i == n - 1) & (j == n - 1))
+    def _():
+        dk_ref[...] = (dk_sc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
+
+
 def _plan(q, k, v, spec: SparseSpec):
     B, L, H, D = q.shape
     Hkv, Dv = k.shape[2], v.shape[3]
@@ -405,14 +479,22 @@ def _plan(q, k, v, spec: SparseSpec):
             kernel_block(L, spec))
 
 
-def _q_major(dims, heads, blk: int):
+def _q_major(dims, heads, blk: int, by_kv: bool = False):
     """Grid and specs of the kernels that walk a query block's visited
-    key blocks (forward, dQ): (grid, q / dQ, o / dO, k, v, word, lse /
-    di row). Steps past the count name the last visited block, which
-    is resident: nothing is loaded for them."""
+    key blocks (forward, dQ, the one backward kernel): (grid, q / dQ,
+    o / dO, k, v, word, lse / di row). Steps past the count name the
+    last visited block, which is resident: nothing is loaded for them.
+    The grid is (B, q head steps, query blocks, walk), or `by_kv`
+    (B, kv heads, per_kv, query blocks, walk): the same steps in the
+    same order, with what a kv group accumulates resident."""
     B, L, H, Hkv, D, Dv = dims
     hs, _, per_kv = heads
     n = L // blk
+
+    def index(f):
+        if not by_kv:
+            return f
+        return lambda b, h, c, i, j, *tc: f(b, h * per_kv + c, i, j, *tc)
 
     def key_block(b, c, i, j, table, count):
         return _walked(table, count, (b * Hkv + c // per_kv) * n + i, n,
@@ -420,20 +502,21 @@ def _q_major(dims, heads, blk: int):
 
     def q_cols(width):
         return pl.BlockSpec((None, blk, hs * width),
-                            lambda b, c, i, j, *_: (b, i, c))
+                            index(lambda b, c, i, j, *_: (b, i, c)))
 
     def kv_cols(width):
         return pl.BlockSpec(
             (None, blk, width),
-            lambda b, c, i, j, *tc: (b, key_block(b, c, i, j, *tc),
-                                     c // per_kv))
+            index(lambda b, c, i, j, *tc: (b, key_block(b, c, i, j, *tc),
+                                           c // per_kv)))
     word_spec = pl.BlockSpec(
         (None, None, None, 1, blk),
-        lambda b, c, i, j, *tc: (b, c // per_kv,
-                                 key_block(b, c, i, j, *tc), 0, i))
+        index(lambda b, c, i, j, *tc: (b, c // per_kv,
+                                       key_block(b, c, i, j, *tc), 0, i)))
     row_spec = pl.BlockSpec((None, hs, 1, blk),
-                            lambda b, c, i, j, *_: (b, c, 0, i))
-    return ((B, H // hs, n, n), q_cols(D), q_cols(Dv), kv_cols(D),
+                            index(lambda b, c, i, j, *_: (b, c, 0, i)))
+    heads_axes = (Hkv, per_kv) if by_kv else (H // hs,)
+    return ((B, *heads_axes, n, n), q_cols(D), q_cols(Dv), kv_cols(D),
             kv_cols(Dv), word_spec, row_spec)
 
 
@@ -467,17 +550,68 @@ def _forward(q, k, v, words, walk, spec, scale: float, interpret: bool):
 
 def _backward(q, k, v, words, walk, walk_t, o, lse, do, spec,
               scale: float, interpret: bool):
+    """dQ, dK, dV: from `_bwd_kernel` where there is no `walk_t` (the
+    rule left it unbuilt, `one_kernel_backward`), else from `_dq_kernel`
+    and `_dkv_kernel`."""
     dims, heads, blk = _plan(q, k, v, spec)
     B, L, H, Hkv, D, Dv = dims
-    hs, _, per_kv = heads
-    n = L // blk
     vma = _vma(q, k, v, do)
     di = jnp.sum(o.astype(_F32) * do.astype(_F32), axis=-1)   # (B, L, H)
     di = jnp.swapaxes(di, 1, 2)[:, :, None, :]                # (B, H, 1, L)
-    q3, do3 = q.reshape(B, L, H * D), do.reshape(B, L, H * Dv)
-    k3, v3 = k.reshape(B, L, Hkv * D), v.reshape(B, L, Hkv * Dv)
-    kw = dict(scale=scale, blk=blk, heads=hs, d=D, dv=Dv, block=spec.block)
+    args = (q.reshape(B, L, H * D), k.reshape(B, L, Hkv * D),
+            v.reshape(B, L, Hkv * Dv), words, do.reshape(B, L, H * Dv),
+            lse, di)
+    kw = dict(scale=scale, blk=blk, heads=heads[0], d=D, dv=Dv,
+              block=spec.block)
+    out_shape = [
+        jax.ShapeDtypeStruct((B, L, H * D), q.dtype, vma=vma),
+        jax.ShapeDtypeStruct((B, L, Hkv * D), k.dtype, vma=vma),
+        jax.ShapeDtypeStruct((B, L, Hkv * Dv), v.dtype, vma=vma)]
+    if walk_t is None:
+        dq, dk, dv = _one_kernel(dims, heads, blk, kw, out_shape,
+                                 interpret, walk, args)
+    else:
+        dq, dk, dv = _two_kernels(dims, heads, blk, kw, out_shape,
+                                  interpret, walk, walk_t, args)
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
 
+
+def _one_kernel(dims, heads, blk, kw, out_shape, interpret, walk, args):
+    """dQ, dK, dV as one call over the forward's walk, a kv group's dK /
+    dV over the whole sequence resident (their output blocks change
+    with (b, kv head) alone: written back once a group)."""
+    _, L, _, _, D, Dv = dims
+    grid, q_spec, o_spec, k_spec, v_spec, word_spec, row_spec = _q_major(
+        dims, heads, blk, by_kv=True)
+
+    def whole(width):
+        return pl.BlockSpec((None, L, width),
+                            lambda b, h, c, i, j, *_: (b, 0, h))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=grid,
+            in_specs=[q_spec, k_spec, v_spec, word_spec, o_spec, row_spec,
+                      row_spec],
+            out_specs=[q_spec, whole(D), whole(Dv)],
+            scratch_shapes=[pltpu.VMEM((heads[0] * D, blk), _F32),
+                            pltpu.VMEM((L, D), _F32),
+                            pltpu.VMEM((L, Dv), _F32)]),
+        out_shape=out_shape,
+        compiler_params=_params(2, 5, _bwd_vmem(
+            dims, heads, blk, out_shape[1].dtype.itemsize)),
+        interpret=interpret,
+        name="hvd_sparse_attention_bwd",
+    )(*walk, *args)
+
+
+def _two_kernels(dims, heads, blk, kw, out_shape, interpret, walk, walk_t,
+                 args):
+    """dQ over the forward's walk, then dK/dV over the transposed one:
+    each rebuilds the scores of every visited block."""
+    B, L, H, Hkv, D, Dv = dims
+    hs, _, per_kv = heads
+    n = L // blk
     grid, q_spec, o_spec, k_spec, v_spec, word_spec, row_spec = _q_major(
         dims, heads, blk)
     dq = pl.pallas_call(
@@ -488,11 +622,11 @@ def _backward(q, k, v, words, walk, walk_t, o, lse, do, spec,
                       row_spec],
             out_specs=q_spec,
             scratch_shapes=[pltpu.VMEM((blk, hs * D), _F32)]),
-        out_shape=jax.ShapeDtypeStruct((B, L, H * D), q.dtype, vma=vma),
+        out_shape=out_shape[0],
         compiler_params=_params(3, 4),
         interpret=interpret,
         name="hvd_sparse_attention_dq",
-    )(*walk, q3, k3, v3, words, do3, lse, di)
+    )(*walk, *args)
 
     # dK/dV walks the query blocks that visit a key block, the q heads
     # of its kv head in `per_kv` steps of `hs`.
@@ -525,14 +659,12 @@ def _backward(q, k, v, words, walk, walk_t, o, lse, do, spec,
             out_specs=[kvg_cols(D), kvg_cols(Dv)],
             scratch_shapes=[pltpu.VMEM((blk, D), _F32),
                             pltpu.VMEM((blk, Dv), _F32)]),
-        out_shape=[
-            jax.ShapeDtypeStruct((B, L, Hkv * D), k.dtype, vma=vma),
-            jax.ShapeDtypeStruct((B, L, Hkv * Dv), v.dtype, vma=vma)],
+        out_shape=out_shape[1:],
         compiler_params=_params(3, 5),
         interpret=interpret,
         name="hvd_sparse_attention_dkv",
-    )(*walk_t, q3, k3, v3, words, do3, lse, di)
-    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+    )(*walk_t, *args)
+    return dq, dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
@@ -547,6 +679,8 @@ def _attention_fwd(q, k, v, words, walk, walk_t, spec, scale, interpret):
 
 def _attention_bwd(spec, scale, interpret, residuals, do):
     q, k, v, words, walk, walk_t, o, lse = residuals
+    _m_backward.labels(
+        kernels="sparse_one" if walk_t is None else "sparse_two").inc()
     return (*_backward(q, k, v, words, walk, walk_t, o, lse, do, spec,
                        scale, interpret), None, None, None)
 
@@ -588,7 +722,8 @@ def selected_attention(q, k, v, chosen, spec: SparseSpec, *,
     if not kernels:
         return _masked_attention(q, k, v, chosen, spec, scale)
     words, walk, walk_t = block_tables(
-        chosen, kernel_block(q.shape[1], spec), spec)
+        chosen, kernel_block(q.shape[1], spec), spec,
+        transposed=not one_kernel_backward(q.shape, k.shape, v.shape))
     return _attention(q, k, v, words, walk, walk_t, spec, scale,
                       bool(interpret))
 

@@ -162,8 +162,8 @@ DEVICE_SCOPES: Dict[str, str] = {
                        "kernels read (fused with it or beside it)",
     "hvd.attn.sparse": "attention over the key blocks each query "
                        "selected: the kernels that walk the table of "
-                       "visited blocks (forward, dQ, dK/dV) with their "
-                       "token-level mask, or one masked softmax; the "
+                       "visited blocks (forward, one backward) with "
+                       "their token-level mask, or one masked softmax; the "
                        "layer at or under its dense length runs "
                        "hvd.attn.core",
     "hvd.ssm.proj": "a Mamba mixer around its scan (models/jamba.py): "

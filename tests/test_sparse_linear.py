@@ -11,6 +11,7 @@ of the long sequence."""
 
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,8 +20,10 @@ import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
+from horovod_tpu.metrics import snapshot
 from horovod_tpu.models import sparse_linear as sl
 from horovod_tpu.parallel import build_train_step
+from horovod_tpu.parallel import fused_attention as fa
 from horovod_tpu.parallel import linear_attention as la
 from horovod_tpu.parallel import sparse_attention as sa
 
@@ -389,11 +392,30 @@ def clustered_selection(B, Hkv, L, spec, blk, seed):
     return jnp.asarray(chosen)
 
 
+def backward_traces():
+    return {kernels: n for (kernels,), n in snapshot().get(
+        "hvd_attention_backward_traces_total", {}).items()
+        if kernels.startswith("sparse_")}
+
+
+def traced_since(before):
+    after = backward_traces()
+    return {k: after[k] - before.get(k, 0) for k in after
+            if after[k] != before.get(k, 0)}
+
+
+@pytest.mark.parametrize("backward", ["one", "two"])
 @pytest.mark.parametrize("block,blk", [(4, 128), (8, 256)],
                          ids=["32-bits-of-4", "32-bits-of-8"])
-def test_kernels_walk_the_table_and_mask_by_token(block, blk):
-    """The three kernels in Pallas's interpreter against the masked
-    softmax, forward and gradients, where the walk skips blocks."""
+def test_kernels_walk_the_table_and_mask_by_token(monkeypatch, block, blk,
+                                                  backward):
+    """The kernels in Pallas's interpreter against the masked softmax,
+    forward and gradients, where the walk skips blocks: the backward as
+    one kernel over the forward's walk (the rule's choice at these
+    shapes) and as the two kernels dQ and dK/dV, forced by a cap that
+    no kv group fits."""
+    if backward == "two":
+        monkeypatch.setattr(fa, "RESIDENT_KV_CAP", 0)
     spec = dataclasses.replace(SPEC, block=block, window=2 * block,
                                kernel_size=4, kernel_stride=2)
     B, L, H, Hkv, D = 2, 4 * blk, 8, 2, 128
@@ -422,10 +444,45 @@ def test_kernels_walk_the_table_and_mask_by_token(block, blk):
                                          kernels=kernels, interpret=True)
         return f(q, k, v), jax.grad(
             lambda *a: jnp.sum(f(*a) * weight), (0, 1, 2))(q, k, v)
+    before = backward_traces()
     (o, grads), (o_want, grads_want) = run(True), run(False)
+    assert traced_since(before) == {f"sparse_{backward}": 1.0}
     close(o, o_want, 2e-5)
     for a, b in zip(grads, grads_want):
         close(a, b, 5e-5)
+
+
+@pytest.mark.parametrize("L,one", [(32768, True), (65536, False)],
+                         ids=["cell", "past-the-cap"])
+def test_the_backward_is_one_kernel_where_a_kv_group_fits(L, one):
+    """The rule is `fused_attention.one_kernel_backward` on the call's
+    shapes: one kv head's f32 dK and dV over 32,768 positions of 128
+    are 32 MiB, `RESIDENT_KV_CAP`, and take one kernel over the
+    forward's walk, with no transposed table built; at 65,536 the
+    backward is dQ and dK/dV over the transposed table. What a trace of
+    the gradient at the cell's share holds, and what the counter
+    says."""
+    q = jax.ShapeDtypeStruct((1, L, 16, 128), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, L, 1, 128), jnp.bfloat16)
+    chosen = jax.ShapeDtypeStruct((1, 1, L, L // 64), jnp.bool_)
+    spec = sa.SparseSpec()
+    assert fa.one_kernel_backward(q.shape, k.shape, k.shape) is one
+    assert (L * (128 + 128) * 4 == fa.RESIDENT_KV_CAP) is one
+
+    def loss(q, k, v, chosen):
+        return jnp.sum(sa.selected_attention(
+            q, k, v, chosen, spec, kernels=True).astype(jnp.float32))
+    before = backward_traces()
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, k, chosen))
+    assert traced_since(before) == {
+        "sparse_one" if one else "sparse_two": 1.0}
+    assert set(re.findall(r"hvd_sparse_attention_[a-z]+", text)) == (
+        {"hvd_sparse_attention_fwd", "hvd_sparse_attention_bwd"} if one
+        else {"hvd_sparse_attention_fwd", "hvd_sparse_attention_dq",
+              "hvd_sparse_attention_dkv"})
+    words, walk, walk_t = jax.eval_shape(
+        lambda c: sa.block_tables(c, 512, spec, transposed=not one), chosen)
+    assert (walk_t is None) is one and walk[0].shape == ((L // 512) ** 2,)
 
 
 def test_kernels_on_a_real_selection_in_bf16():
@@ -663,8 +720,6 @@ def test_the_layers_trace_their_paths_and_scopes(cfg, params):
     """One period is traced for the scan: a sparse layer that selects
     at 128 positions and hands 32 to `attention()`, three linear
     cores; the selection counts 4 blocks a query from shapes."""
-    from horovod_tpu.metrics import snapshot
-
     def read():
         snap = snapshot()
         paths = snap.get("hvd_attention_traces_total", {})
