@@ -12,7 +12,8 @@ chip's share of an expert-parallel job.
     frequencies, arXiv:2309.00071), values narrower than the keys;
     the core is `parallel.ring_attention.attention`.
   * Expert FFN (arXiv:2412.19437): sigmoid scores, bias-corrected
-    top-k, normalised and scaled gates, a shared expert; the routed
+    top-k, normalised and scaled gates (or, as the caller says, a
+    softmax top-k renormalised), a shared expert; the routed
     part is `parallel.moe.expert_share_ffn`, told which experts this
     chip holds (`experts_first`, and as many as the weights stack)
     while the router keeps its full width. No pair is dropped.
@@ -43,7 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..parallel.moe import expert_share_ffn, topk_sigmoid_route
+from ..parallel.moe import (expert_share_ffn, topk_sigmoid_route,
+                             topk_softmax_route)
 from ..parallel.ring_attention import attention
 from ..tracing import device_scope
 from .transformer import embed_lookup, rmsnorm, vocab_parallel_xent
@@ -247,24 +249,21 @@ def out_of_streams(streams) -> jax.Array:
 # Latent attention
 # ---------------------------------------------------------------------------
 
-def yarn_inv_freq(cfg: LatentMoEConfig) -> np.ndarray:
-    """The rope dims' frequencies: the published ones where a dim
-    turns more than `rope_beta_fast` times over the original length,
-    those divided by `rope_factor` where it turns fewer than
-    `rope_beta_slow` times, a linear ramp between."""
-    dim, base = cfg.qk_rope_dim, cfg.rope_theta
-
+def yarn_inv_freq(dim: int, base: float, factor: float, original_len: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """The frequencies of `dim` rotary dims under YaRN (arXiv:2309.00071):
+    the plain ones at `base` where a dim turns more than `beta_fast`
+    times over `original_len` positions, those divided by `factor` where
+    it turns fewer than `beta_slow` times, a linear ramp between."""
     def correction_dim(rotations):
-        return dim * math.log(cfg.rope_original_len /
-                              (rotations * 2 * math.pi)) \
+        return dim * math.log(original_len / (rotations * 2 * math.pi)) \
             / (2 * math.log(base))
-    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
-    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), dim - 1)
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
     high = high + 0.001 if low == high else high
     plain = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
     ramp = np.clip((np.arange(dim // 2) - low) / (high - low), 0.0, 1.0)
-    return (plain / cfg.rope_factor * ramp
-            + plain * (1.0 - ramp)).astype(np.float32)
+    return (plain / factor * ramp + plain * (1.0 - ramp)).astype(np.float32)
 
 
 def softmax_scale(cfg: LatentMoEConfig) -> float:
@@ -274,11 +273,16 @@ def softmax_scale(cfg: LatentMoEConfig) -> float:
     return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5 * m * m
 
 
-def _rotated(x: jax.Array, inv_freq: np.ndarray) -> jax.Array:
-    """x: (B, L, H, rope dims), halves rotated, float32 arithmetic."""
+def _rotated(x: jax.Array, inv_freq: np.ndarray, scale: float = 1.0
+             ) -> jax.Array:
+    """x: (B, L, H, rope dims), halves rotated, float32 arithmetic; cos
+    and sin times `scale` where it is not 1 (YaRN's attention factor,
+    as HF's `attention_scaling` applies it)."""
     half = x.shape[-1] // 2
     angle = jnp.arange(x.shape[1], dtype=_F32)[:, None] * inv_freq
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half].astype(_F32), x[..., half:].astype(_F32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)
@@ -291,7 +295,9 @@ def latent_attention(cfg: LatentMoEConfig, p, u: jax.Array) -> jax.Array:
                      cfg.v_head_dim)
     eps = cfg.norm_eps
     with device_scope("hvd.attn.proj"):
-        inv_freq = yarn_inv_freq(cfg)
+        inv_freq = yarn_inv_freq(dr, cfg.rope_theta, cfg.rope_factor,
+                                 cfg.rope_original_len, cfg.rope_beta_fast,
+                                 cfg.rope_beta_slow)
         h = rmsnorm(u, p["attn_norm"], eps)
         q = (rmsnorm(h @ p["w_qa"], p["q_norm"], eps) @ p["w_qb"]
              ).reshape(B, L, H, dn + dr)
@@ -328,18 +334,25 @@ def dense_ffn(cfg: LatentMoEConfig, p, u: jax.Array) -> jax.Array:
 
 
 def expert_ffn(cfg: LatentMoEConfig, p, u: jax.Array, shared: bool = True,
-               tile_m: Optional[int] = None) -> jax.Array:
+               tile_m: Optional[int] = None, router: str = "sigmoid"
+               ) -> jax.Array:
     """The held experts' part of the routed sum plus the shared expert
     (`shared=False` leaves it out: another chip of the layer counts
-    it). `tile_m`: `expert_share_ffn`'s. u: (B, L, D) -> (B, L, D)."""
+    it, or the layer has none). `tile_m`: `expert_share_ffn`'s.
+    `router`: "sigmoid" (`topk_sigmoid_route` with the layer's
+    `router_bias` and `cfg.routed_scale`) or "softmax"
+    (`topk_softmax_route`). u: (B, L, D) -> (B, L, D)."""
     B, L, D = u.shape
     with device_scope("hvd.moe.route"):
         h = rmsnorm(u, p["mlp_norm"], cfg.norm_eps)
         tokens = h.reshape(B * L, D)
         logits = jnp.dot(tokens.astype(_F32), p["router"].astype(_F32),
                          precision=lax.Precision.HIGHEST)
-        experts, gates = topk_sigmoid_route(
-            logits, p["router_bias"], cfg.top_k, cfg.routed_scale)
+        if router == "softmax":
+            experts, gates = topk_softmax_route(logits, cfg.top_k)
+        else:
+            experts, gates = topk_sigmoid_route(
+                logits, p["router_bias"], cfg.top_k, cfg.routed_scale)
     y = expert_share_ffn(
         tokens, experts, gates, p["w_gate"], p["w_up"], p["w_down"],
         first=cfg.experts_first, tile_m=tile_m).reshape(B, L, D)

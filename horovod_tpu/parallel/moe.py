@@ -38,6 +38,13 @@ _m_moe_bound = _METRICS.gauge(
     "Rows of the buffer the last traced expert_share_ffn gathers its "
     "(token, expert) pairs into: a static bound, at most tokens x "
     "min(k, experts held), which no batch can exceed.")
+_m_moe_routers = _METRICS.counter(
+    "hvd_moe_router_traces_total",
+    "Times an expert layer's router was traced, by its kind: sigmoid "
+    "(topk_sigmoid_route: sigmoid scores, bias-corrected top-k, gates "
+    "normalised and scaled) or softmax (topk_softmax_route: a softmax "
+    "over the router's width, top-k, gates renormalised).",
+    ("router",))
 
 
 def top1_route(logits: jax.Array, n_experts: int, capacity: int
@@ -136,11 +143,27 @@ def topk_sigmoid_route(logits: jax.Array, bias: jax.Array, k: int,
     `noaux_tc` with one group). logits: (T, E) float32, bias: (E,),
     which only moves the choice and gets no gradient. Returns
     (experts (T, k) int32, gates (T, k) float32)."""
+    _m_moe_routers.labels(router="sigmoid").inc()
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, experts = lax.top_k(scores + lax.stop_gradient(bias), k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
     gates = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
     return experts.astype(jnp.int32), gates * scale
+
+
+def topk_softmax_route(logits: jax.Array, k: int
+                       ) -> Tuple[jax.Array, jax.Array]:
+    """A softmax over every expert of the router, in float32, the k
+    most probable experts a token, and their gates: the chosen
+    probabilities divided by their sum (Qwen-MoE's and DeepSeek-V2's
+    `norm_topk_prob`). logits: (T, E). Returns (experts (T, k) int32,
+    gates (T, k) float32); the gradient reaches the logits through the
+    gates."""
+    _m_moe_routers.labels(router="softmax").inc()
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    gates, experts = lax.top_k(probs, k)
+    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), gates
 
 
 def max_pairs(tokens: int, k: int, held: int) -> int:
@@ -269,15 +292,15 @@ def expert_share_ffn(tokens: jax.Array, experts: jax.Array,
     """The held experts' part of sum_i gate_i * E_i(token), with
     E(h) = (silu(h W_g) * (h W_u)) W_d.
 
-    tokens: (T, D); experts, gates: (T, k) from `topk_sigmoid_route`
-    over the router's full width; w_gate / w_up: (held, D, F), w_down:
-    (held, F, D): expert `first + i` of the router is row i. The
-    dispatch buffer takes `max_pairs` pairs, which no batch can
-    exceed: no pair is dropped. `tile_m`: rows of a tile of the buffer
-    (`grouped_matmul.TILE_M` if None); a group costs its whole tiles,
-    so a tile well above the rows an expert expects makes a step's
-    time follow the batch's routing less. Returns the output, (T, D)
-    float32."""
+    tokens: (T, D); experts, gates: (T, k) from a top-k router over its
+    full width (`topk_sigmoid_route`, `topk_softmax_route`); w_gate /
+    w_up: (held, D, F), w_down: (held, F, D): expert `first + i` of the
+    router is row i. The dispatch buffer takes `max_pairs` pairs, which
+    no batch can exceed: no pair is dropped. `tile_m`: rows of a tile
+    of the buffer (`grouped_matmul.TILE_M` if None); a group costs its
+    whole tiles, so a tile well above the rows an expert expects makes
+    a step's time follow the batch's routing less. Returns the output,
+    (T, D) float32."""
     from ..tracing import device_scope
     from . import grouped_matmul as gm
     T, D = tokens.shape

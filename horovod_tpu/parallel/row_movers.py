@@ -63,9 +63,9 @@ _F32, _I32, _U32 = jnp.float32, jnp.int32, jnp.uint32
 
 def supported(tokens: int, k: int, width: int) -> bool:
     """Shapes the movers take, which their caller checks: tokens in
-    whole 16-row tiles of a bf16 block, rows in whole chunks of packed
-    words, at most `MAX_CHOICES` choices."""
-    return (tokens % 16 == 0 and width % (2 * CHUNK) == 0
+    whole 16-row tiles of a bf16 block, rows in whole lanes of packed
+    words (the last chunk may be half full), at most `MAX_CHOICES`."""
+    return (tokens % 16 == 0 and width % (2 * LANES) == 0
             and 1 <= k <= MAX_CHOICES)
 
 
@@ -98,18 +98,18 @@ def _pack_kernel(x_ref, o_ref):
     # a bf16 is the high half of its float32
     bits = pltpu.bitcast(x_ref[...].astype(jnp.bfloat16).astype(_F32), _U32)
     word = (bits[:, :half] >> 16) | (bits[:, half:] & jnp.uint32(0xFFFF0000))
-    for c in range(half // CHUNK):
-        o_ref[:, c, :] = word[:, c * CHUNK:(c + 1) * CHUNK]
+    for c in range(0, half, CHUNK):          # the last may be half full
+        o_ref[:, c // CHUNK, :min(CHUNK, half - c)] = word[:, c:c + CHUNK]
 
 
 def pack_rows(src: jax.Array) -> jax.Array:
     """(T, D) bf16, or f32 to be rounded to it -> (T, 8 x, 256) uint32:
     column c of a row's first half in the low bits of word c, column c
-    of its second half in the high bits, D / 512 chunks of 256 words
-    (the chunks up to whole tiles of 8 are never written or read). A
-    row is then tiles of its own, which a DMA may fetch alone, and a
-    word unpacks into lanes that stay where they are. One pass over
-    the source: XLA's own pad and relayout took three."""
+    of its second half in the high bits, D / 512 chunks of 256 words,
+    the last half full if D / 256 is odd (padding chunks up to whole
+    tiles of 8, and a half-full chunk's high lanes, are never written
+    or read). A row is then tiles of its own, which a DMA fetches alone,
+    and a word unpacks into lanes that stay where they are."""
     tokens, width = src.shape
     rows = step_rows(tokens)
     chunks = -(-width // (2 * CHUNK) // GROUP) * GROUP
@@ -141,14 +141,14 @@ def _in_kernel(live_ref, count_ref, code_ref, src_ref, *refs):
         _loop(count, lambda e, c: (fetch(e).wait(), c)[1])
         # what the stage held before stays behind the mask
         filled = lax.broadcasted_iota(_I32, (rows, 1), 0) < count
-        for c in range(half // CHUNK):
+        for c in range(-(-half // CHUNK)):
             low, high = (jnp.where(filled, x, 0.0)
                          for x in _halves(stage[:, c, :]))
             if scale_ref is not None:
                 low, high = low * scale_ref[...], high * scale_ref[...]
-            o_ref[:, c * CHUNK:(c + 1) * CHUNK] = low.astype(o_ref.dtype)
-            o_ref[:, half + c * CHUNK:half + (c + 1) * CHUNK] = high.astype(
-                o_ref.dtype)
+            at, n = c * CHUNK, min(CHUNK, half - c * CHUNK)
+            o_ref[:, at:at + n] = low[:, :n].astype(o_ref.dtype)
+            o_ref[:, half + at:half + at + n] = high[:, :n].astype(o_ref.dtype)
 
 
 def _lane_sum(x):

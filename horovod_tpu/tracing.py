@@ -138,18 +138,19 @@ def ring_events(limit: Optional[int] = None) -> List[Tuple]:
 
 DEVICE_SCOPES: Dict[str, str] = {
     "hvd.embed": "token embedding gather, and its scatter-add in backward",
-    "hvd.attn.proj": "attention norm, q/k/v projections, rope, "
-                     "output projection and its tensor psum; where a "
-                     "model has them, the per-head q / k norms, the "
-                     "output gate and the sub-layer's post-norm",
+    "hvd.attn.proj": "attention norm, q/k/v projections, rope (plain "
+                     "or YaRN), output projection and its tensor psum; "
+                     "where a model has them, the per-head q / k norms, "
+                     "the output gate and the sub-layer's post-norm",
     "hvd.attn.core": "attention scores, mask, softmax and PV: the "
                      "fused kernels (forward, dQ, dK/dV), the dense "
                      "path or the ring, with the GQA repeat of K / V "
                      "where the path needs one; in a model that mixes "
                      "layer kinds, the full layers' core",
     "hvd.attn.window": "the same core in a sliding-window layer "
-                       "(models/window_moe.py): key blocks older than "
-                       "the window are skipped, not masked",
+                       "(models/window_moe.py: Trinity's 4,096 keys, "
+                       "Mellum 2's 1,024): key blocks older than the "
+                       "window are skipped, not masked",
     "hvd.attn.linear": "linear attention with a decay a head "
                        "(parallel/linear_attention.py): the products "
                        "inside a chunk, the state carried from chunk to "
@@ -177,7 +178,8 @@ DEVICE_SCOPES: Dict[str, str] = {
     "hvd.ffn": "dense FFN: norm and SwiGLU, and the sub-layer's "
                "post-norm where a model has one",
     "hvd.moe": "MoE FFN: router, dispatch, experts, combine",
-    "hvd.moe.route": "dropless expert layer: norm, router scores, "
+    "hvd.moe.route": "dropless expert layer: norm, router scores "
+                     "(sigmoid + bias, or a softmax renormalised), "
                      "top-k, the sort of the (token, expert) pairs held "
                      "here, their rows moved into the dispatch buffer "
                      "and the gated sum back (on the TPU the row "

@@ -350,13 +350,16 @@ def test_latent_attention_core_compiles_with_v_at_its_own_width(topo, B, L):
 
 @pytest.mark.parametrize("k, n, m, tile", [
     (3584, 1024, 34816, 256), (1024, 3584, 34816, 256),
-    (3072, 3072, 73728, 1024)], ids=["gate-up", "down", "trinity"])
+    (3072, 3072, 73728, 1024), (896, 2304, 135168, 256)],
+    ids=["gate-up", "down", "trinity", "mellum-down"])
 def test_grouped_matmul_kernels_compile(topo, k, n, m, tile):
     """The expert layer's grouped matmuls at the published experts'
     widths over their cells' dispatch buffers (`xing4`: 34,816 rows,
     8,192 tokens x 4 choices and a tile of 256 an expert; `trinity`:
-    73,728 rows, 16,384 x 4 and a tile of 1,024 an expert): forward,
-    dx and dw are one Mosaic kernel each."""
+    73,728 rows, 16,384 x 4 and a tile of 1,024 an expert; `mellum`'s
+    down projection: 135,168 rows, 16,384 x 8 and a tile of 256 an
+    expert, dw in 128-lane column blocks): forward, dx and dw are one
+    Mosaic kernel each."""
     from horovod_tpu.parallel import grouped_matmul as gm
     one = SingleDeviceSharding(topo.devices[0])
     x = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one)
@@ -375,22 +378,24 @@ def test_grouped_matmul_kernels_compile(topo, k, n, m, tile):
         assert name in hlo
 
 
-@pytest.mark.parametrize("T, D, n, tile", [
-    (8192, 3584, 34816, 256), (16384, 3072, 73728, 1024),
-    (8192, 3072, 40960, 1024)], ids=["xing4", "trinity", "trinity-sample"])
-def test_row_movers_compile(topo, T, D, n, tile):
-    """The expert layer's row movers and their pack at both cells' shapes (T
-    tokens of 4 choices, a buffer of n rows in tiles of `tile`): each
-    is one Mosaic kernel under its own name, within the VMEM the call
-    allows itself (a compile that passes: the fetch scratch, the f32
-    stage and the double-buffered blocks)."""
+@pytest.mark.parametrize("T, D, n, tile, k", [
+    (8192, 3584, 34816, 256, 4), (16384, 3072, 73728, 1024, 4),
+    (8192, 3072, 40960, 1024, 4), (16384, 2304, 135168, 256, 8)],
+    ids=["xing4", "trinity", "trinity-sample", "mellum"])
+def test_row_movers_compile(topo, T, D, n, tile, k):
+    """The expert layer's row movers and their pack at the cells' shapes
+    (T tokens of k choices, a buffer of n rows in tiles of `tile`;
+    `mellum`'s rows are 4.5 chunks of packed words): each is one
+    Mosaic kernel under its own name, within the VMEM the call allows
+    itself (a compile that passes: the fetch scratch, the f32 stage
+    and the double-buffered blocks)."""
     from horovod_tpu.parallel import row_movers as rm
     one = SingleDeviceSharding(topo.devices[0])
 
     def on(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
     bf16, f32, i32, u32 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.uint32
-    assert rm.supported(T, 4, D)
+    assert rm.supported(T, k, D)
     movers = {
         "pack": (rm.pack_rows, (on((T, D), f32),), "hvd_moe_rows_pack"),
         "dispatch": (lambda tok, index, live: rm.rows_in(
@@ -402,14 +407,14 @@ def test_row_movers_compile(topo, T, D, n, tile):
             (on((T, 8, 256), u32), on((n,), i32), on((1,), i32),
              on((n,), f32)), "hvd_moe_rows_in"),
         "combine-backward-gates": (rm.rows_dot,
-                                   (on((n, D), bf16), on((T, 4), i32),
+                                   (on((n, D), bf16), on((T, k), i32),
                                     on((T, D), f32)), "hvd_moe_rows_dot"),
         "combine": (lambda ys, code, gates: rm.rows_out(
             ys, code, gates, out_dtype=f32),
-            (on((n, D), bf16), on((T, 4), i32), on((T, 4), f32)),
+            (on((n, D), bf16), on((T, k), i32), on((T, k), f32)),
             "hvd_moe_rows_out"),
         "dispatch-backward": (lambda d_xs, code: rm.rows_out(d_xs, code),
-                              (on((n, D), bf16), on((T, 4), i32)),
+                              (on((n, D), bf16), on((T, k), i32)),
                               "hvd_moe_rows_out"),
     }
     for fn, args, name in movers.values():
@@ -422,10 +427,10 @@ def test_row_movers_compile(topo, T, D, n, tile):
 
 
 @pytest.mark.parametrize("m, k, n, tile", [
-    (34816, 3584, 1024, 256), (73728, 3072, 3072, 1024)],
-    ids=["xing4", "trinity"])
+    (34816, 3584, 1024, 256), (73728, 3072, 3072, 1024),
+    (135168, 2304, 896, 256)], ids=["xing4", "trinity", "mellum"])
 def test_grouped_swiglu_kernels_compile(topo, m, k, n, tile):
-    """Gate and up with the SwiGLU inside at both cells' shapes:
+    """Gate and up with the SwiGLU inside at the cells' shapes:
     forward (both products and the activation), the two cotangents,
     dx as one sum and dw twice are five Mosaic kernels, each within
     the VMEM the call allows itself, and the cotangents take the
@@ -459,14 +464,17 @@ def test_grouped_swiglu_kernels_compile(topo, m, k, n, tile):
         next(line for line in kernels if "swiglu_dh" in line)
 
 
-@pytest.mark.parametrize("T, D, F, tile, n_rows", [
-    (8192, 3584, 1024, None, 34816), (16384, 3072, 3072, 1024, 73728)],
-    ids=["xing4", "trinity"])
+@pytest.mark.parametrize("T, D, F, tile, n_rows, held, k", [
+    (8192, 3584, 1024, None, 34816, 8, 4),
+    (16384, 3072, 3072, 1024, 73728, 8, 4),
+    (16384, 2304, 896, None, 135168, 16, 8)],
+    ids=["xing4", "trinity", "mellum"])
 def test_expert_layer_on_the_tpu_takes_the_live_tiles(topo, T, D, F, tile,
-                                                      n_rows):
-    """What a TPU trace of `expert_share_ffn` takes at both cells'
-    shapes: the counter says `sorted_live_tiles`, and forward and
-    backward are fifteen Mosaic kernels (the pack, rows in and rows
+                                                      n_rows, held, k):
+    """What a TPU trace of `expert_share_ffn` takes at the cells'
+    shapes (`mellum`: hidden 2304, 4.5 chunks of packed words, and 8
+    choices a token): the counter says `sorted_live_tiles`, and forward
+    and backward are fifteen Mosaic kernels (the pack, rows in and rows
     out twice each, the gates' products once; gate and up with the
     SwiGLU inside: forward, cotangents, dx; the down projection's
     forward and dx; dw three times) with no gather of a row of the
@@ -480,7 +488,6 @@ def test_expert_layer_on_the_tpu_takes_the_live_tiles(topo, T, D, F, tile,
 
     def on(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-    held, k = 8, 4
     bf16 = jnp.bfloat16
 
     def both(tokens, experts, gates, w_gate, w_up, w_down):

@@ -187,6 +187,50 @@ def test_jamba_scopes_reach_the_compiled_program(jamba_names, scope, want):
     assert found <= set(tracing.DEVICE_SCOPES)
 
 
+@pytest.fixture(scope="module")
+def mellum_names():
+    """Mellum 2's layer (`window_moe` with no gate, no post-norm, no
+    shared expert, plain rope and YaRN, a softmax router) over one
+    period, every layer checkpointed, through `build_train_step`."""
+    from horovod_tpu.models import window_moe as wm
+    cfg = wm.WindowMoEConfig(
+        vocab=64, d_model=16, layer_kinds=("window",) * 3 + ("full",),
+        n_dense_layers=0, window=4, n_heads=4, n_kv_heads=1, head_dim=8,
+        d_ff_expert=8, d_ff_shared=0, n_experts=8, experts_held=4,
+        top_k=4, router="softmax", output_gate=False, post_norms=False,
+        rope_theta=1000.0, full_rope=wm.Rope(
+            1000.0, factor=4.0, original_len=8, attention_factor=1.1),
+        embed_scale=1.0, dtype=jnp.float32, remat=True)
+    mesh = Mesh(np.array(jax.devices()[:1]), axis_names=("data",))
+    params = wm.init_params(cfg, jax.random.PRNGKey(0))
+    tx = optax.sgd(0.1)
+    step = build_train_step(
+        lambda p, b: wm.loss_fn(cfg, p, b), tx, mesh,
+        batch_spec={"tokens": P("data")}, donate=False)
+    batch = {"tokens": jnp.zeros((2, 16), jnp.int32)}
+    return op_names(step.lower(params, tx.init(params), batch).compile())
+
+
+@pytest.mark.parametrize("scope, want", [
+    ("hvd.attn.proj", {"forward", "backward", "recompute"}),
+    ("hvd.attn.window", {"forward", "backward", "recompute"}),
+    ("hvd.attn.core", {"forward", "backward", "recompute"}),
+    ("hvd.moe.route", {"forward", "backward", "recompute"}),
+    ("hvd.moe.experts", {"forward", "backward", "recompute"}),
+    ("hvd.embed", {"forward", "backward"}),
+    ("hvd.head_loss", {"forward", "backward"}),
+    ("hvd.moe.shared", set()),
+    ("hvd.ffn", set()),
+])
+def test_mellum_scopes_reach_the_compiled_program(mellum_names, scope, want):
+    """Every scope the layer runs, in all its passes; none of the
+    shared expert or of a dense FFN, which the family has not."""
+    got = variants(mellum_names, scope)
+    assert want <= got and (want or not got)
+    found = {s for n in mellum_names for s in SCOPE.findall(n)}
+    assert found <= set(tracing.DEVICE_SCOPES)
+
+
 def test_every_name_in_a_program_is_registered(transformer_names,
                                                resnet_names,
                                                latent_moe_names):

@@ -133,7 +133,10 @@ def test_latent_attention(cfg, layer, reference):
 
 
 def test_yarn_frequencies_and_scale(cfg, reference):
-    close(lm.yarn_inv_freq(cfg), reference.yarn_inv_freq(CONFIG), 1e-7)
+    close(lm.yarn_inv_freq(cfg.qk_rope_dim, cfg.rope_theta, cfg.rope_factor,
+                           cfg.rope_original_len, cfg.rope_beta_fast,
+                           cfg.rope_beta_slow),
+          reference.yarn_inv_freq(CONFIG), 1e-7)
     assert lm.softmax_scale(cfg) == pytest.approx(
         reference.softmax_scale(CONFIG))
     # the published model: 192^-0.5 * (0.1 ln 64 + 1)^2

@@ -198,11 +198,73 @@ def test_rows_dot_multiplies_with_the_unrounded_row(k, tokens):
     assert not f32(got)[np.asarray(code) < 0].any()
 
 
+@pytest.mark.parametrize("mover", ["pack", "in", "in-scaled", "out",
+                                   "out-ones", "dot"])
+def test_the_movers_take_four_and_a_half_chunks(mover):
+    """Rows of 2,304 columns (Mellum 2's hidden size: four whole chunks
+    of packed words and a half-full fifth) and 8 choices a token,
+    against the `jnp` gathers: the half chunk's columns land where
+    they belong, and its empty high lanes reach no output."""
+    width, k, tokens, tile = 2304, 8, 256, 256
+    if mover == "pack":
+        src = rows(20, (32, width), BF16)
+        with interpreted():
+            packed = rm.pack_rows(src)
+        assert packed.shape == (32, 8, 256)
+        half = width // 2
+        words = np.asarray(packed[:, :5].reshape(32, 5 * 256))[:, :half]
+        bits = np.asarray(jax.lax.bitcast_convert_type(src, jnp.uint16),
+                          np.uint32)
+        np.testing.assert_array_equal(words & 0xFFFF, bits[:, :half])
+        np.testing.assert_array_equal(words >> 16, bits[:, half:])
+    elif mover.startswith("in"):
+        src = rows(21, (tokens, width), F32 if mover == "in-scaled" else BF16)
+        n, live = 3 * tile, 2 * tile
+        index = prefix_codes(22, n, tokens, rm.step_rows(tile))
+        scale = jax.random.uniform(jax.random.PRNGKey(23), (n,), F32) \
+            if mover == "in-scaled" else None
+        with interpreted():
+            got = rm.rows_in(rm.pack_rows(src), index,
+                             jnp.asarray([live], I32), width=width,
+                             tile_m=tile, scale=scale)
+        want = gathered(src.astype(BF16), index)
+        if scale is not None:
+            want = (want.astype(F32) * scale[:, None]).astype(BF16)
+        np.testing.assert_array_equal(f32(got[:live]), f32(want[:live]))
+        assert np.isnan(f32(got[live:])).all()
+    else:
+        buf = rows(24, (768, width), BF16)
+        code = landing_codes(k, tokens, 768)
+        gates = jax.random.uniform(jax.random.PRNGKey(25), (tokens, k), F32)
+        against = rows(26, (tokens, width), F32)
+        picked = [jnp.where(code[:, j, None] >= 0,
+                            buf[jnp.maximum(code[:, j], 0)], 0).astype(F32)
+                  for j in range(k)]
+        with interpreted():
+            if mover == "dot":
+                got = rm.rows_dot(buf, code, against)
+                want = jnp.stack([jnp.sum(r * against, axis=-1)
+                                  for r in picked], axis=1)
+            elif mover == "out":
+                got = rm.rows_out(buf, code, gates, out_dtype=F32)
+                want = sum(gates[:, j, None] * r for j, r in enumerate(picked))
+            else:
+                got = rm.rows_out(buf, code)
+                want = sum(picked).astype(BF16)
+        if mover == "out-ones":
+            np.testing.assert_array_equal(f32(got), f32(want))
+        else:
+            np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6 * float(
+                jnp.max(jnp.abs(want))))
+
+
 def test_shapes_the_movers_take():
     assert rm.supported(8192, 4, 3584) and rm.supported(256, 4, 3072)
+    assert rm.supported(16384, 8, 2304)             # 4.5 packed chunks
+    assert rm.supported(8192, 4, 768) and rm.supported(64, 2, 256)
     assert not rm.supported(8190, 4, 3584)          # no whole bf16 tiles
     assert not rm.supported(8192, 9, 3584)          # too many choices
-    assert not rm.supported(8192, 4, 768)           # no whole packed chunks
+    assert not rm.supported(8192, 4, 640)           # half a chunk in halves
     assert rm.step_rows(256) == 256 and rm.step_rows(1024) == 256
     assert rm.step_rows(128) == 128 and rm.step_rows(8192, 8) == 128
 
@@ -240,7 +302,8 @@ def layer_case(name):
         "expert-unused": (256, 512, 128, 4, 16, 4, 4, 128),
         "tile1024":      (256, 512, 128, 2, 8, 2, 0, 1024),
         "filled":        (64, 512, 128, 4, 4, 4, 0, 128),
-        "narrow":        (64, 256, 128, 2, 4, 4, 0, 128),
+        "narrow":        (64, 384, 128, 2, 4, 4, 0, 128),
+        "top8-width2304": (64, 2304, 128, 8, 64, 16, 16, 128),
     }[name]
     key = jax.random.split(jax.random.PRNGKey(11), 6)
     logits = jax.random.normal(key[0], (T, E), F32)
@@ -277,7 +340,7 @@ def traces(label):
 
 
 @pytest.mark.parametrize("case", ["typical", "expert-unused", "tile1024",
-                                  "filled"])
+                                  "filled", "top8-width2304"])
 def test_expert_share_ffn_with_the_movers_against_its_gathers(case):
     """Output, d_tokens, d_gates and the three dW of the layer with
     the row movers against the same layer with `jnp` gathers, the
@@ -341,18 +404,19 @@ def test_off_the_tpu_the_gathers_stay():
 
 def test_one_rule_engages_matmuls_and_movers_together():
     """A width the grouped matmuls would take and the movers do not
-    (whole lanes, no whole packed chunks): on a TPU the layer takes
-    neither, `sorted_ragged`, and no Pallas call is traced."""
+    (whole lanes, an odd number of them: no whole half chunks of
+    packed words): on a TPU the layer takes neither, `sorted_ragged`,
+    and no Pallas call is traced."""
     args = layer_case("narrow")
-    assert gm.kernels_engage(jax.ShapeDtypeStruct((768, 256), BF16),
+    assert gm.kernels_engage(jax.ShapeDtypeStruct((768, 384), BF16),
                              args[3][0], 128) is False   # off the TPU
     before = traces("sorted_ragged"), traces("sorted_live_tiles")
     want = layer_and_gradients(*args)
     with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
             mock.patch.object(pl, "pallas_call", side_effect=AssertionError):
-        assert gm.kernels_engage(jax.ShapeDtypeStruct((768, 256), BF16),
+        assert gm.kernels_engage(jax.ShapeDtypeStruct((768, 384), BF16),
                                  args[3][0], 128)
-        assert not rm.supported(64, 2, 256)
+        assert not rm.supported(64, 2, 384)
         got = layer_and_gradients(*args)
     assert (traces("sorted_ragged"), traces("sorted_live_tiles")) == (
         before[0] + 2, before[1])
